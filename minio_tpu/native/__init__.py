@@ -1,33 +1,64 @@
 """Build-on-first-import loader for the native kernel library.
 
-Compiles native.cc with g++ -O3 -march=native into _native.so next to
-this file (rebuilt when the source is newer) and exposes it via ctypes.
-Falls back to None if no compiler is available — pure-Python/numpy paths
-take over, slower but byte-identical.
+Compiles native.cc with g++ -O3 -march=native next to this file and
+exposes it via ctypes. The artifact's NAME carries a digest of what it
+was built from and for — the source bytes, the flags, and this host's
+CPU (model and feature flags from /proc/cpuinfo: -march=native bakes
+them in) — so a tree copied to another machine, or a source edit with
+an older mtime, can never load a stale or foreign .so (which would
+SIGILL, not fail politely); it just builds its own. Falls back to None
+if no compiler is available — pure-Python/numpy paths take over,
+slower but byte-identical; `load() is not None` is reported in admin
+info (`device.native_lib`) so that fallback is visible.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "native.cc")
-_SO = os.path.join(_DIR, "_native.so")
+_FLAGS = ["-O3", "-march=native", "-shared", "-fPIC"]
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _host_cpu() -> str:
+    """What -march=native resolved against: the first model/flags lines
+    of /proc/cpuinfo (x86 `flags`, arm `Features`)."""
+    seen: dict[str, str] = {}
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features") \
+                        and key not in seen:
+                    seen[key] = line
+    except OSError:
+        pass
+    return platform.machine() + "".join(sorted(seen.values()))
+
+
+def _so_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_host_cpu().encode())
+    return os.path.join(_DIR, f"_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
     # Per-process tmp name: concurrent builders must not interleave into
-    # one tmp file (a corrupt .so with a fresh mtime would permanently
-    # disable the native path).
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    base = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-o", tmp,
-            _SRC]
+    # one tmp file; os.replace publishes the finished artifact atomically.
+    tmp = f"{so}.{os.getpid()}.tmp"
+    base = ["g++", *_FLAGS, "-o", tmp, _SRC]
     # zlib backs the fused transform's block compression; a container
     # without the headers still gets every other kernel (the deflate/
     # inflate entry points then answer -2 and Python keeps its own
@@ -35,7 +66,7 @@ def _build() -> bool:
     for cmd in (base + ["-lz"], base + ["-DMTPU_NO_ZLIB"]):
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            os.replace(tmp, _SO)
+            os.replace(tmp, so)
             return True
         except Exception:
             try:
@@ -55,27 +86,13 @@ def load():
             return _lib
         _tried = True
         try:
-            stale = not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-            if stale and not _build():
+            so = _so_path()
+            if not os.path.exists(so) and not _build(so):
                 return None
-            lib = ctypes.CDLL(_SO)
-        except Exception:
-            return None
-        # A stale .so can predate newer symbols (e.g. mtpu_put_frame)
-        # even when mtimes look fresh: declare, and on a missing
-        # symbol rebuild once and re-declare.
-        try:
+            lib = ctypes.CDLL(so)
             _declare(lib)
-        except AttributeError:
-            if not _build():
-                return None
-            lib = ctypes.CDLL(_SO)
-            try:
-                _declare(lib)
-            except AttributeError:
-                return None
+        except (OSError, AttributeError):
+            return None
         _lib = lib
         return _lib
 

@@ -342,12 +342,18 @@ class DriveHealManager:
                                         name="drive-heal-manager")
         self._thread.start()
 
-    def stop(self) -> None:
+    def stop(self, timeout: float = 2.0) -> bool:
+        """True when the manager and every bulk heal ended, each within
+        `timeout` (a heal checkpoints at the stop event and returns) —
+        False means one is still writing to its drive."""
         self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=2)
-            self._thread = None
+        mgr, self._thread = self._thread, None
+        if mgr is not None:
+            mgr.join(timeout=timeout)   # first: it is what starts heals
         with self._mu:
             threads = [v["thread"] for v in self._active.values()]
         for t in threads:
-            t.join(timeout=2)
+            t.join(timeout=timeout)
+        if mgr is not None:
+            threads.append(mgr)
+        return not any(t.is_alive() for t in threads)

@@ -37,6 +37,7 @@ import numpy as np
 from minio_tpu.erasure.codec import CodecError, Erasure, ceil_frac
 from minio_tpu.io.bufpool import global_pool
 from minio_tpu.io.engine import EngineSaturated, IOEngine
+from minio_tpu.ops import device
 from minio_tpu.ops.batcher import batch_force_mode
 from minio_tpu.utils import deadline as deadline_mod
 from minio_tpu.utils import tracing
@@ -141,15 +142,6 @@ class _Md5Stream:
             self._lib.mtpu_digest_final(0, self._ctx, out)
             return bytes(out).hex()
         return self._h.hexdigest()
-
-
-@functools.lru_cache(maxsize=1)
-def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax always present in-tree
-        return False
 
 
 @functools.lru_cache(maxsize=64)
@@ -434,19 +426,23 @@ class ErasureSet:
         """Release the set's background resources (fan-out executor,
         MRF worker). Repeated boot/stop cycles — sidecars, tests —
         would otherwise leak 8+ threads per lifecycle (caught by the
-        leak harness, tests/test_leak_race.py). Under _mrf_lock with a
-        closed sentinel: a racing lazy `mrf` access must not start a
-        fresh worker after close() looked."""
-        with self._mrf_lock:
-            self._mrf_closed = True
-            if self._mrf is not None:
-                self._mrf.stop()
+        leak harness, tests/test_leak_race.py)."""
+        self.stop_mrf()
         if self.group_commit is not None:
             # Final WAL checkpoint rides along: graceful stops leave no
             # group-commit WALs for the next boot to replay.
             self.group_commit.close()
         self.pool.shutdown(wait=False)
         self.io.close()
+
+    def stop_mrf(self, timeout: float = 2.0) -> bool:
+        """Stop the MRF heal worker while the set still works under it;
+        True when no heal is in flight any more (MRFQueue.stop). Under
+        _mrf_lock with a closed sentinel: a racing lazy `mrf` access
+        must not start a fresh worker after this looked."""
+        with self._mrf_lock:
+            self._mrf_closed = True
+            return self._mrf is None or self._mrf.stop(timeout)
 
     @property
     def mrf(self):
@@ -1092,8 +1088,7 @@ class ErasureSet:
         batcher_for = _batcher_for if route == "put" \
             else _transform_batcher_for
         use_device = (full >= 1 and m > 0
-                      and (_on_tpu() or batch_force_mode(route) == "device")
-                      and hasattr(self.backend, "apply_matrix_device")
+                      and self._wants_device_route(route)
                       and BLOCK_SIZE % k == 0 and shard_size % 1024 == 0
                       # Once the batcher's calibration resolves to
                       # host, skip its queue entirely: the pooled
@@ -1201,9 +1196,7 @@ class ErasureSet:
                 or plen == 0:
             return self._transform_staged(data, k, m, spec)
         use_device = (m > 0
-                      and (_on_tpu()
-                           or batch_force_mode("transform") == "device")
-                      and hasattr(self.backend, "apply_matrix_device")
+                      and self._wants_device_route("transform")
                       and BLOCK_SIZE % k == 0 and shard_size % 1024 == 0
                       and _transform_batcher_for(k, m).wants_device())
         frame_native = not use_device and k * shard_size == BLOCK_SIZE
@@ -2380,8 +2373,7 @@ class ErasureSet:
         # (read-side counterpart of the fused PUT pipeline; the
         # reference hashes per block in ReadAt,
         # cmd/bitrot-streaming.go:161-200).
-        use_device = _on_tpu() and hasattr(self.backend,
-                                           "apply_matrix_device")
+        use_device = self._device_capable() and device.on_tpu()
 
         def verify(blobs):
             return bitrot.read_framed_blocks_many(
@@ -2486,14 +2478,24 @@ class ErasureSet:
         with self._gk_mu:
             self.get_kernel[path] += 1
 
+    def _device_capable(self) -> bool:
+        """The set was configured with a device backend. Checked FIRST
+        at every device gate: a host-codec process (every pre-forked
+        worker is one) must never import JAX just to learn the
+        platform — on a TPU host that alone would fight the owner
+        process for the chip."""
+        return hasattr(self.backend, "apply_matrix_device")
+
     def _wants_device_route(self, route: str) -> bool:
-        """Platform gate for a decode-route device dispatch: the set
-        must run a device-capable backend, and either this host is a
-        TPU host or MTPU_BATCH_FORCE pins the route (the
+        """Platform gate for a batched device dispatch on `route`: the
+        set must run a device-capable backend, and either this host is
+        a TPU host or MTPU_BATCH_FORCE pins the route (the
         reproducibility knob must reach the REAL batched device route
-        on any host — see _frame_windows' identical PUT gate)."""
-        return (hasattr(self.backend, "apply_matrix_device")
-                and (_on_tpu() or batch_force_mode(route) == "device"))
+        on any host: CI plumbing proofs and the scaling sweeps run it
+        on virtual CPU devices)."""
+        return (self._device_capable()
+                and (batch_force_mode(route) == "device"
+                     or device.on_tpu()))
 
     def _device_get_window(self, results, k: int, m: int,
                            shard_size: int, win_len: int, start_b: int,
@@ -2546,6 +2548,11 @@ class ErasureSet:
         except DeadlineExceeded:
             raise
         except Exception:  # noqa: BLE001 - device trouble != corruption
+            # Counted and logged by the batcher (device.record_fault);
+            # absorbed into the native path only where nobody asked
+            # for the device.
+            if device.required():
+                raise
             return None
         route = sb.last_route()
         bad = 0
@@ -2625,6 +2632,8 @@ class ErasureSet:
         except DeadlineExceeded:
             raise
         except Exception:  # noqa: BLE001 - device trouble -> host codec
+            if device.required():
+                raise
             e.decode_data_blocks(shards)
             return
         tail = shard_len - full * shard_size
@@ -2658,7 +2667,7 @@ class ErasureSet:
         nb = (data_size + shard_size - 1) // shard_size if shard_size \
             else 0
         full = nb if data_size == nb * shard_size else nb - 1
-        use_device = hasattr(self.backend, "apply_matrix_device")
+        use_device = self._device_capable()
         if bitrot.DEFAULT_ALGORITHM != bitrot.HIGHWAYHASH256S \
                 or full < 1 or not self._wants_device_route("get") \
                 or len(blob) != bitrot.shard_file_size(data_size,
@@ -2678,6 +2687,8 @@ class ErasureSet:
         except DeadlineExceeded:
             raise
         except Exception:  # noqa: BLE001 - device trouble -> host path
+            if device.required():
+                raise
             arr, = bitrot.read_framed_blocks_many(
                 [blob], shard_size, data_size, device=use_device)
             return arr

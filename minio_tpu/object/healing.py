@@ -547,11 +547,15 @@ class MRFQueue:
                 return
             time.sleep(0.02)
 
-    def stop(self) -> None:
+    def stop(self, timeout: float = 2.0) -> bool:
+        """Take no more work; the heal in flight, if any, runs to its
+        end. True when the worker ended within `timeout` — False means
+        a heal is still writing to the drives."""
         self._stop.set()
-        self._worker.join(timeout=2)
+        self._worker.join(timeout=timeout)
         if self._persist:
             try:
                 self._save()
             except Exception:  # noqa: BLE001 - shutdown best effort
                 pass
+        return not self._worker.is_alive()

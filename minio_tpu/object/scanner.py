@@ -429,8 +429,12 @@ class Scanner:
             except Exception:  # noqa: BLE001 - scanner must survive
                 continue
 
-    def stop(self) -> None:
+    def stop(self, timeout: float = 2.0) -> bool:
+        """True when the scan thread ended within `timeout` — False
+        means a cycle (and any heal it started) is still running."""
         self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=2)
-            self._thread = None
+        t, self._thread = self._thread, None
+        if t is None:
+            return True
+        t.join(timeout=timeout)
+        return not t.is_alive()

@@ -26,9 +26,12 @@ a drive), which also yields wait-vs-service attribution for free.
 Dispatch policy is MEASURED, not assumed: a one-time background probe
 times the device round trip (host->HBM transfer + fused kernel +
 readback) against the host codec for the same bytes. Where the device
-link is fast (PCIe-local TPU) batches beat the host and route to the
-device; where it is slow (e.g. a tunneled remote chip) everything stays
-on the host codec and the batcher degrades to a pass-through. A lone
+round trip wins, batches route to the device; where it loses,
+everything stays on the host codec and the batcher degrades to a
+pass-through. Losing on time is a verdict; a device function that
+RAISES is a fault — counted and logged with its traceback
+(ops/device.record_fault), and an error rather than a route when the
+operator asked for the device (device.required()). A lone
 PUT with no concurrency never waits: frame() bypasses the queue
 entirely unless other requests are already in flight. The accumulation
 window is ADAPTIVE: it opens at the measured base wait, stretches while
@@ -94,6 +97,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from minio_tpu.io.engine import EngineSaturated, kernel_lane
+from minio_tpu.ops import device
 from minio_tpu.utils import deadline as deadline_mod
 from minio_tpu.utils import tracing
 from minio_tpu.utils.deadline import DeadlineExceeded
@@ -156,6 +160,11 @@ def batch_force_mode(route: str = "put") -> str:
     return v if v in ("device", "host") else "auto"
 
 
+class DeviceRouteError(RuntimeError):
+    """The device was asked for and this route's device function
+    raised: the request fails instead of quietly riding the host."""
+
+
 def _default_concat(rows, chunk):
     """Oversized-window splice for the PUT rows contract: per-drive
     lists of per-block piece tuples concatenate drive-wise."""
@@ -212,12 +221,16 @@ def aggregate_stats() -> dict:
         "mesh_devices": 0,
         "forced": {r: batch_force_mode(r) for r in ROUTES},
         "decode_lane_hist": None,
+        # One entry per live batcher: what its probe decided and why.
+        "calibration": [],
     }
     hists: dict[str, list] = {r: [] for r in ROUTES}
     decode_lane = []
     for sb in list(_REGISTRY):
         st = sb.stats()
         route = st.get("route", "put")
+        out["calibration"].append({"name": st["name"], "route": route,
+                                   **st["calibration"]})
         agg = out["routes"].setdefault(route, _route_zero())
         for key in ("device", "host"):
             agg["dispatches"][key] += st["dispatches"][key]
@@ -290,8 +303,15 @@ class StripeBatcher:
         self._device_ok: Optional[bool] = None
         self._probe_fn = probe_fn
         self._probe_started = False
+        # What the probe measured: (device_s, host_s) once it ran, and
+        # the exception when the device function raised instead.
+        self._probe_times: Optional[tuple[float, float]] = None
+        self.probe_error: Optional[BaseException] = None
         forced = batch_force_mode(route)
-        if forced != "auto":
+        # Pinned = the verdict came from MTPU_BATCH_FORCE or force(),
+        # not from a probe.
+        self._pinned = forced != "auto"
+        if self._pinned:
             self._probe_started = True
             self._device_ok = forced == "device"
         # Occupancy stats (own lock: the dispatcher holds _mu at the
@@ -326,20 +346,19 @@ class StripeBatcher:
     def _default_probe(self, sample: np.ndarray) -> bool:
         """Time device vs host on one representative batch (the first
         request's config, widened to a device-worthy block count);
-        True when the device round trip wins."""
+        True when the device round trip wins. A device function that
+        raises propagates — that is a fault, not a slow device."""
         stacked = np.zeros(
             (_bucket(max(self._min_device_blocks, self.mesh_devices)),)
             + sample.shape[1:], dtype=np.uint8)
-        try:
-            self._device_fn(stacked)           # compile
-            t0 = time.perf_counter()
-            self._device_fn(stacked)
-            t_dev = time.perf_counter() - t0
-        except Exception:  # noqa: BLE001 - no device -> host
-            return False
+        self._device_fn(stacked)               # compile
+        t0 = time.perf_counter()
+        self._device_fn(stacked)
+        t_dev = time.perf_counter() - t0
         t0 = time.perf_counter()
         self._host_fn(stacked)
         t_host = time.perf_counter() - t0
+        self._probe_times = (t_dev, t_host)
         return t_dev < t_host
 
     def _ensure_probe(self, sample: np.ndarray) -> None:
@@ -352,14 +371,17 @@ class StripeBatcher:
             self._probe_started = True
 
         def probe():
+            err = None
             try:
                 if self._probe_fn is not None:
                     ok = bool(self._probe_fn())
                 else:
                     ok = self._default_probe(sample)
-            except Exception:  # noqa: BLE001 - probe failure -> host
-                ok = False
+            except Exception as e:  # noqa: BLE001 - thread boundary
+                device.record_fault(f"probe:{self.route}", e)
+                ok, err = False, e
             with self._mu:
+                self.probe_error = err
                 self._device_ok = ok
 
         # Non-daemon: a daemon probe mid-device-call at interpreter
@@ -368,12 +390,24 @@ class StripeBatcher:
         threading.Thread(target=probe, daemon=False,
                          name="stripe-batcher-probe").start()
 
+    def _check_probe_fault(self) -> None:
+        """A probe that raised resolves to host only where nobody asked
+        for the device; where the operator did, every window that would
+        have consulted this route fails with the cause attached."""
+        err = self.probe_error
+        if err is not None and device.required():
+            raise DeviceRouteError(
+                f"{self.route} route {self.name!r}: device function "
+                f"raised during calibration: {type(err).__name__}: "
+                f"{err}") from err
+
     def wants_device(self) -> bool:
         """False only once calibration has RESOLVED to host — the
         caller can then skip the batcher entirely (its own host path is
         at least as good, without the queue/lock hop). Unprobed (None)
         answers True so traffic keeps flowing through frame() until the
         probe settles."""
+        self._check_probe_fault()
         return self._device_ok is not False
 
     def worth_batching(self, blocks: int) -> bool:
@@ -386,6 +420,7 @@ class StripeBatcher:
         should ride the native kernel, not the batcher's generic host
         fallback."""
         if self._device_ok is False:
+            self._check_probe_fault()
             return False
         return blocks >= self._min_device_blocks or self._inflight > 0 \
             or bool(self._pending)
@@ -398,6 +433,8 @@ class StripeBatcher:
         not silently degrade a measured run to pass-through)."""
         with self._mu:
             self._probe_started = True
+            self._pinned = True
+            self.probe_error = None
             self._device_ok = bool(device_ok)
 
     def reset_calibration(self) -> None:
@@ -405,7 +442,10 @@ class StripeBatcher:
         force()): unprobed under auto, re-pinned under a
         MTPU_BATCH_FORCE override."""
         with self._mu:
+            self.probe_error = None
+            self._probe_times = None
             forced = batch_force_mode(self.route)
+            self._pinned = forced != "auto"
             if forced != "auto":
                 self._probe_started = True
                 self._device_ok = forced == "device"
@@ -414,6 +454,28 @@ class StripeBatcher:
                 self._device_ok = None
 
     # -- observability --------------------------------------------------
+
+    def calibration(self) -> dict:
+        """The route's verdict and what it rests on: "device"/"host"
+        from a timed probe (times attached), "raised" when the device
+        function threw, "forced-*" under a pin, "probing" while the
+        probe runs, "unprobed" before any device-worthy traffic."""
+        forced = batch_force_mode(self.route)
+        ok, err, times = self._device_ok, self.probe_error, self._probe_times
+        if err is not None:
+            verdict = "raised"
+        elif ok is None:
+            verdict = "probing" if self._probe_started else "unprobed"
+        else:
+            verdict = ("forced-" if self._pinned else "") \
+                + ("device" if ok else "host")
+        out = {"verdict": verdict, "pin": forced}
+        if times is not None:
+            out["device_ms"] = round(times[0] * 1e3, 3)
+            out["host_ms"] = round(times[1] * 1e3, 3)
+        if err is not None:
+            out["error"] = f"{type(err).__name__}: {err}"[:300]
+        return out
 
     def stats(self) -> dict:
         with self._stat_mu:
@@ -432,6 +494,7 @@ class StripeBatcher:
                 "wait_hist": self._wait_hist.state(),
                 "lane_hist": self._lane_hist.state(),
                 "window_s": self._cur_wait,
+                "calibration": self.calibration(),
             }
 
     def _note_request(self, route: str, n: int = 1) -> None:
@@ -453,6 +516,7 @@ class StripeBatcher:
             # verdict transitions once, None -> True/False). The counter
             # bump is unlocked too — approximate under races, and the
             # only shared state this path touches.
+            self._check_probe_fault()
             self._bypass_approx += 1
             self._local.route = "bypass"
             return self._host_fn(stacked)
@@ -757,6 +821,8 @@ class StripeBatcher:
                 # full window forever.
                 self._adapt_window(total / bucket)
         except BaseException as e:  # noqa: BLE001 - deliver to waiters
+            if route == "device" and isinstance(e, Exception):
+                device.record_fault(f"dispatch:{self.route}", e)
             for p in live:
                 p.exc = e
         finally:

@@ -41,6 +41,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from minio_tpu.ops import device
 from minio_tpu.utils.highwayhash import MAGIC_KEY
 
 _U32 = jnp.uint32
@@ -616,23 +617,30 @@ def hash_blocks_device(key: bytes, blocks, mode: str = "auto") -> np.ndarray:
 
     blocks: uint8 [S, L] (numpy or device array) -> uint8 [S, 32] numpy.
     mode: "auto" (Pallas kernel on TPU when eligible, else the portable
-    jnp path), "pallas" (forced; interpreted off-TPU), or "xla".
+    jnp path), "pallas" (forced; needs a TPU), "interpret" (the Pallas
+    kernel through the interpreter — tests, never serving), or "xla".
     """
     if len(key) != 32:
         raise ValueError("HighwayHash-256 requires a 32-byte key")
     blocks = jnp.asarray(blocks, dtype=jnp.uint8)
     s, l = blocks.shape
-    on_tpu = jax.default_backend() == "tpu"
-    if mode == "pallas" and l % 32 != 0:
+    on_tpu = device.on_tpu()
+    if mode in ("pallas", "interpret") and l % 32 != 0:
         raise ValueError(
             f"pallas HH kernel requires whole 32-byte packets (L % 32 == 0), "
             f"got L={l}; use mode='auto' or 'xla' for ragged lengths")
-    if mode == "pallas" or (mode == "auto" and on_tpu
-                            and _pallas_eligible(s, l)):
+    if mode == "pallas" and not on_tpu:
+        raise RuntimeError("mode='pallas' needs a TPU; the interpreter is "
+                           "mode='interpret'")
+    if mode in ("pallas", "interpret") or (mode == "auto" and on_tpu
+                                           and _pallas_eligible(s, l)):
         init = jnp.asarray(_init_smem_np(key))
+        device.note_kernel("digest", "interpret" if mode == "interpret"
+                           else "pallas")
         return np.asarray(hash_blocks_pallas(blocks, init,
-                                             interpret=not on_tpu))
+                                             interpret=mode == "interpret"))
     init = jnp.asarray(_init_state_np(key))
+    device.note_kernel("digest", "xla")
     return np.asarray(_hash_jit(blocks, init, l))
 
 
@@ -681,6 +689,7 @@ def framed_digests_device(blobs: list[np.ndarray],
     w = fw - 8
     pchunk = _pick_pchunk(w // 8)
     init = jnp.asarray(_init_smem_np(MAGIC_KEY))
+    device.note_kernel("digest", "interpret" if interpret else "pallas")
     parts: list[tuple[int, int, np.ndarray]] = []  # (out_off, rows, view)
     rem: list[tuple[int, np.ndarray]] = []         # (out_off, view)
     off = 0
@@ -720,7 +729,7 @@ def framed_digests_device(blobs: list[np.ndarray],
 def framed_digests_eligible(n_blocks: int, shard_size: int) -> bool:
     """Worth dispatching to the device: enough streams to fill vector
     tiles and a whole-packet block length."""
-    return (jax.default_backend() == "tpu" and shard_size % 1024 == 0
+    return (device.on_tpu() and shard_size % 1024 == 0
             and n_blocks >= 256 and _pick_pchunk(shard_size // 4 // 8) >= 8)
 
 
@@ -749,7 +758,7 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
     n = matrix.shape[1] + matrix.shape[0]
     encode = make_encoder(matrix, mode=mode)
     encode32 = make_encoder32(matrix, mode=mode)
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = device.on_tpu()
 
     @functools.partial(jax.jit, static_argnames=("pchunk",))
     def fused32(data32, init, pchunk: int):
@@ -789,6 +798,7 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
         b, k, l = data.shape
         pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
         if on_tpu and l % 1024 == 0 and pchunk >= 8:
+            device.note_kernel("frame", "pallas")
             data32 = jnp.asarray(data.view(np.uint32))
             parity, dig_d, dig_p = fused32(
                 data32, jnp.asarray(_init_smem_np(MAGIC_KEY)), pchunk)
@@ -805,6 +815,7 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
                      for i in range(k)]
                     + [[(dig_p[bi, j], parity[bi, j]) for bi in range(b)]
                        for j in range(parity.shape[1])])
+        device.note_kernel("frame", "xla")
         parity, digests = fused8(jnp.asarray(data, dtype=jnp.uint8),
                                  jnp.asarray(_init_state_np(MAGIC_KEY)))
         parity = np.asarray(parity)
@@ -857,7 +868,7 @@ def make_deframer(k: int, mode: str = "auto"):
     the same verdict mtpu_get_frame's bad-mask encodes, batched.
     """
     del k  # shape-generic: the stream count is B*k either way
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = device.on_tpu()
 
     @functools.partial(jax.jit, static_argnames=("pchunk",))
     def verify32(framed32, init, pchunk: int):
@@ -883,10 +894,12 @@ def make_deframer(k: int, mode: str = "auto"):
         s = f - 32
         pchunk = _pick_pchunk(s // 32) if s and s % 32 == 0 else 0
         if on_tpu and f % 4 == 0 and s % 1024 == 0 and pchunk >= 8:
+            device.note_kernel("deframe", "pallas")
             f32 = jnp.asarray(framed.view(np.uint32))
             ok = verify32(f32, jnp.asarray(_init_smem_np(MAGIC_KEY)),
                           _pick_pchunk(s // 4 // 8))
         else:
+            device.note_kernel("deframe", "xla")
             ok = verify8(jnp.asarray(framed),
                          jnp.asarray(_init_state_np(MAGIC_KEY)))
         return np.asarray(ok)
@@ -899,47 +912,11 @@ def make_deframer(k: int, mode: str = "auto"):
 # Mesh-sharded cross-request framer
 # ---------------------------------------------------------------------------
 
-def mesh_batch_devices(devices=None) -> list:
-    """The largest power-of-two prefix of the visible devices: padding
-    buckets are powers of two (ops/batcher._BUCKETS), so a power-of-two
-    mesh keeps every bucketed batch evenly divisible across chips with
-    zero per-chip remainder shapes (one compile per bucket, not per
-    (bucket, remainder) pair). MTPU_MESH_DEVICES caps the prefix — the
-    chip-count scaling sweep (bench.py put_scaling) uses it to measure
-    1/2/4/8-chip aggregates on one host."""
-    import os as _os
-    devs = list(devices if devices is not None else jax.devices())
-    try:
-        cap = int(_os.environ.get("MTPU_MESH_DEVICES", "") or len(devs))
-    except ValueError:
-        cap = len(devs)
-    devs = devs[:max(1, cap)]
-    p = 1
-    # Cap at the largest padding bucket (ops/batcher._BUCKETS[-1]): a
-    # mesh wider than the biggest batch shape could never be fed a
-    # divisible batch.
-    while p * 2 <= len(devs) and p * 2 <= 256:
-        p *= 2
-    return devs[:p]
-
-
-def _shard_map_compat():
-    """shard_map under its jax 0.6 top-level or 0.4 experimental home,
-    with the replication check disabled under whichever kwarg name
-    (check_rep -> check_vma rename) this jax spells."""
-    try:                                       # jax >= 0.6 top-level
-        from jax import shard_map as _shard_map
-    except ImportError:                        # 0.4.x experimental home
-        from jax.experimental.shard_map import shard_map as _shard_map
-    import inspect as _inspect
-    _sm_params = _inspect.signature(_shard_map).parameters
-    _sm_kw = {"check_vma": False} if "check_vma" in _sm_params \
-        else ({"check_rep": False} if "check_rep" in _sm_params else {})
-
-    def shard_map(body, mesh, in_specs, out_specs):
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **_sm_kw)
-    return shard_map
+def _shard_map(body, mesh, in_specs, out_specs):
+    """jax.shard_map with the varying-manual-axes check off: the Pallas
+    calls inside the bodies carry no vma annotations."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
@@ -963,19 +940,18 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
     On one device (CPU tests, MTPU_MESH_DEVICES=1) this degrades to the
     single-chip fused framer — same bytes, no mesh machinery.
     """
-    devs = mesh_batch_devices(devices)
+    devs = device.mesh_batch_devices(devices)
     ndev = len(devs)
     if ndev <= 1:
         return make_encode_framer(matrix, mode=mode)
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    shard_map = _shard_map_compat()
     from minio_tpu.ops.rs_device import make_encoder, make_encoder32
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     m, k = matrix.shape
     n = k + m
     mesh = Mesh(np.asarray(devs), ("stripe",))
     sharding = NamedSharding(mesh, P("stripe"))
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = device.on_tpu()
     # Donation is a TPU-memory contract; the CPU backend ignores it
     # with a compile warning, so only declare it where it buys the copy.
     donate = (0,) if on_tpu else ()
@@ -994,7 +970,7 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
             dig_p = _hash_words_pallas(parity, ini,
                                        pchunk=pchunk).reshape(b, m, 8)
             return parity, dig_d, dig_p
-        return shard_map(
+        return _shard_map(
             body, mesh=mesh, in_specs=(P("stripe"), P()),
             out_specs=(P("stripe"), P("stripe"), P("stripe")))(data32, init)
 
@@ -1007,7 +983,7 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
             shards = jnp.concatenate([d, parity], axis=1)
             digests = _hash_impl(shards.reshape(b * n, l), ini, l)
             return parity, digests.reshape(b, n, 32)
-        return shard_map(
+        return _shard_map(
             body, mesh=mesh, in_specs=(P("stripe"), P()),
             out_specs=(P("stripe"), P("stripe")))(data, init)
 
@@ -1018,6 +994,7 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
             f"batch {b} not divisible by {ndev}-chip mesh (pad buckets)"
         pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
         if on_tpu and l % 1024 == 0 and pchunk >= 8:
+            device.note_kernel("frame", "pallas")
             d32 = jax.device_put(data.view(np.uint32), sharding)
             parity, dig_d, dig_p = mesh32(
                 d32, jnp.asarray(_init_smem_np(MAGIC_KEY)), pchunk)
@@ -1029,6 +1006,7 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
                      for i in range(k)]
                     + [[(dig_p[bi, j], parity[bi, j]) for bi in range(b)]
                        for j in range(m)])
+        device.note_kernel("frame", "xla")
         d8 = jax.device_put(data, sharding)
         parity, digests = mesh8(d8,
                                 jnp.asarray(_init_state_np(MAGIC_KEY)))
@@ -1058,15 +1036,14 @@ def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
     (CPU tests, MTPU_MESH_DEVICES=1) this degrades to the single-chip
     fused verifier — same verdicts, no mesh machinery.
     """
-    devs = mesh_batch_devices(devices)
+    devs = device.mesh_batch_devices(devices)
     ndev = len(devs)
     if ndev <= 1:
         return make_deframer(k, mode=mode)
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    shard_map = _shard_map_compat()
     mesh = Mesh(np.asarray(devs), ("stripe",))
     sharding = NamedSharding(mesh, P("stripe"))
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = device.on_tpu()
     donate = (0,) if on_tpu else ()
 
     @functools.partial(jax.jit, static_argnames=("pchunk",),
@@ -1078,7 +1055,7 @@ def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
             digs = _hash_words_pallas(words, ini, pchunk=pchunk)
             stored = fr[:, :, :8].reshape(b * kk, 8)
             return jnp.all(digs == stored, axis=1).reshape(b, kk)
-        return shard_map(body, mesh=mesh, in_specs=(P("stripe"), P()),
+        return _shard_map(body, mesh=mesh, in_specs=(P("stripe"), P()),
                          out_specs=P("stripe"))(framed32, init)
 
     @functools.partial(jax.jit, donate_argnums=donate)
@@ -1089,7 +1066,7 @@ def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
             digs = _hash_impl(blocks, ini, f - 32)
             stored = fr[:, :, :32].reshape(b * kk, 32)
             return jnp.all(digs == stored, axis=1).reshape(b, kk)
-        return shard_map(body, mesh=mesh, in_specs=(P("stripe"), P()),
+        return _shard_map(body, mesh=mesh, in_specs=(P("stripe"), P()),
                          out_specs=P("stripe"))(framed, init)
 
     def run(framed) -> np.ndarray:
@@ -1100,10 +1077,12 @@ def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
         s = f - 32
         pchunk = _pick_pchunk(s // 32) if s and s % 32 == 0 else 0
         if on_tpu and f % 4 == 0 and s % 1024 == 0 and pchunk >= 8:
+            device.note_kernel("deframe", "pallas")
             f32 = jax.device_put(framed.view(np.uint32), sharding)
             ok = mesh_verify32(f32, jnp.asarray(_init_smem_np(MAGIC_KEY)),
                                _pick_pchunk(s // 4 // 8))
         else:
+            device.note_kernel("deframe", "xla")
             f8 = jax.device_put(framed, sharding)
             ok = mesh_verify8(f8, jnp.asarray(_init_state_np(MAGIC_KEY)))
         return np.asarray(ok)

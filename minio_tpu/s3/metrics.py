@@ -775,6 +775,35 @@ class Metrics:
                     "Kernel-lane service time of decode-route "
                     "(get/reconstruct) device dispatches",
                     [({}, bst["decode_lane_hist"])])
+        # -- the device as the process that owns it sees it -------------
+        # (ops/device): what JAX reported, which kernel implementation
+        # served each dispatch, and every exception a device call
+        # raised. A host-codec process exports backend="host" and
+        # never imports JAX to say so.
+        dev = device_section(server, bst["calibration"])
+        metric("minio_tpu_device_info",
+               "Visible devices, labelled with the EC backend serving "
+               "(tpu|portable|host) and the platform, device kind and "
+               "mesh width jax.devices() reported in this process",
+               "gauge",
+               [({"backend": dev["ec_backend"],
+                  "platform": dev.get("platform", ""),
+                  "device_kind": dev.get("device_kind", ""),
+                  "mesh_devices": dev.get("mesh_devices", 0)},
+                 dev.get("devices", 0))])
+        metric("minio_tpu_device_kernel_calls_total",
+               "Host-level device dispatches by kernel (frame|deframe|"
+               "matrix|digest) and the implementation that ran "
+               "(pallas|xla|interpret)", "counter",
+               [({"kernel": ki.split("/")[0], "impl": ki.split("/")[1]},
+                 v) for ki, v in sorted(dev["kernel_calls"].items())])
+        metric("minio_tpu_device_errors_total",
+               "Exceptions raised by device calls, by site "
+               "(probe:<route>|dispatch:<route>|framed_digests)",
+               "counter",
+               [({"site": site}, v)
+                for site, v in sorted(dev["faults"].items())]
+               or [({"site": "none"}, 0)])
         # -- fused transform plane (object/transform) -------------------
         # Path split is the conformance signal: with fusion on, the
         # legacy counters must stay ZERO for buffered traffic — any
@@ -1238,6 +1267,28 @@ class Metrics:
         return "\n".join(lines) + "\n"
 
 
+def device_section(server, calibration=None) -> dict:
+    """The `device` block of admin info (and the source of the
+    minio_tpu_device_* metrics): the EC backend label the boot line
+    printed, what jax.devices() reported in this process when a device
+    backend serves, the native library's state, and the device's own
+    counters. A host-codec process answers without importing JAX."""
+    from minio_tpu import native
+    from minio_tpu.ops import device as device_mod
+    label = getattr(server, "ec_backend", "host")
+    out = {"ec_backend": label, "native_lib": native.load() is not None,
+           "pid": os.getpid()}
+    if label == "host":
+        out.update(device_mod.stats())
+    else:
+        out.update(device_mod.report())
+        if calibration is None:
+            from minio_tpu.ops import batcher as batcher_mod
+            calibration = batcher_mod.aggregate_stats()["calibration"]
+        out["calibration"] = calibration
+    return out
+
+
 def layer_sets(object_layer) -> list:
     """Erasure sets behind any object-layer shape (set / sets / pools)."""
     pools = getattr(object_layer, "pools", None)
@@ -1490,6 +1541,7 @@ def node_info(server) -> dict:
     from minio_tpu.storage import meta_scan as _ms
     info["metacache"] = {"sets": metacache, "scan": dict(_ms.counters)}
     info["get_kernel"] = get_kernel
+    info["device"] = device_section(server)
     # Distributed plane: per-peer breaker states, notify fan-out
     # outcomes, and the coherence protocol's arm/generation state.
     from minio_tpu.grid import client as _grid_client

@@ -24,11 +24,17 @@ from __future__ import annotations
 
 import argparse
 import os
+import signal
 import socket as socket_mod
 import sys
+import threading
 import time
 
 GRID_PORT_OFFSET = 1000
+# Graceful stop: how long, in all, the background healers get to finish
+# the object they are on before the stop is called unclean (below a
+# supervisor's usual 90 s kill timeout, with the request drain after).
+_QUIESCE_S = 45.0
 
 
 def main(argv=None) -> int:
@@ -122,24 +128,40 @@ def main(argv=None) -> int:
             ap.error(f"--parity must be in [0, {ss // 2}] for "
                      f"{ss}-drive sets")
 
+    # Where the GF(2^8) math runs is settled HERE, before any fork and
+    # without importing JAX in this process. A chip belongs to one
+    # process: a pre-forked fleet in which "every child does its own
+    # detection" hands the chip to whichever worker initialises JAX
+    # first and leaves the rest to crash or to serve some other codec
+    # under the same label. So: `auto` asks a short-lived child what
+    # platform JAX comes up on (ops/device.probe_platform — it exits
+    # and frees the chip); a device-backed boot — `tpu`, or `auto` that
+    # found one — does NOT pre-fork: this process alone initialises JAX
+    # and serves; and the fleet, when there is one, is told `host`
+    # outright so no worker ever imports JAX to find out. (ROADMAP A3's
+    # measured choice between an owner process fed by workers, a worker
+    # per chip, and this single process stays open.)
+    from minio_tpu.io import workers as workers_mod
+    from minio_tpu.ops import device as device_mod
+    worker_id = os.environ.get("MTPU_WORKER_ID", "")
+    want_device = args.ec_backend == "tpu" or (
+        args.ec_backend == "auto" and device_mod.probe_platform() == "tpu")
     # Pre-forked SO_REUSEPORT front-end (io/workers.py): N worker
     # processes each run this whole boot (MTPU_HTTP_WORKERS=1 in the
-    # children prevents recursion). MUST run before self-tests and
-    # ec-backend detection: those may import and initialize JAX, and
-    # forking a process with a live XLA runtime (its thread pools, a
-    # claimed TPU device) is undefined — every child does its own
-    # detection instead. Default = cores. Distributed topologies
-    # pre-fork too (N nodes x M workers): the node's SINGLE grid port
-    # is owned by worker 0, and sibling workers reach the node's lock
-    # authority / coherence singleton over loopback — see the
-    # worker-topology wiring below.
-    from minio_tpu.io import workers as workers_mod
-    worker_id = os.environ.get("MTPU_WORKER_ID", "")
+    # children prevents recursion). Default = cores. Distributed
+    # topologies pre-fork too (N nodes x M workers): the node's SINGLE
+    # grid port is owned by worker 0, and sibling workers reach the
+    # node's lock authority / coherence singleton over loopback — see
+    # the worker-topology wiring below.
     if not worker_id:
         n_workers = workers_mod.worker_count_from_env()
-        if n_workers > 1:
+        if n_workers > 1 and want_device:
+            print(f"ec-backend on the device: one process owns the chip, "
+                  f"not pre-forking {n_workers} workers", flush=True)
+        elif n_workers > 1:
             return workers_mod.serve_cli(
-                list(argv) if argv is not None else sys.argv[1:],
+                (list(argv) if argv is not None else sys.argv[1:])
+                + ["--ec-backend", "host"],
                 args.address, n_workers, main)
     # Worker identity: "" = plain single-process boot; "0" = the
     # pre-forked worker that owns node-singleton duties (grid listener,
@@ -160,26 +182,27 @@ def main(argv=None) -> int:
     bitrot_self_test()
 
     backend = None
-    if args.ec_backend == "tpu":
-        from minio_tpu.ops.rs_device import DeviceBackend
-        backend = DeviceBackend()
-    elif args.ec_backend == "auto":
+    dev = None              # ops/device.DeviceInfo when a device serves
+    if want_device:
+        # One rule for every process that serves with a device backend,
+        # asked for by name or found by `auto`'s probe: it gets a TPU —
+        # or the portable path chosen on purpose (JAX_PLATFORMS=cpu,
+        # `tpu` only) and labelled as such below — or it refuses to
+        # boot, and from here on no device fault is absorbed into
+        # another codec under the device's label (device.required()).
         try:
-            import jax
-            if jax.default_backend() == "tpu":
-                from minio_tpu.ops.rs_device import DeviceBackend
-                backend = DeviceBackend()
-        except Exception as e:  # noqa: BLE001 - no JAX device -> host math
-            print(f"ec-backend auto-detect: no TPU ({type(e).__name__}: {e}); "
-                  "using host GF kernels", file=sys.stderr)
-            backend = None
-    if backend is not None:
+            dev = device_mod.require()
+        except device_mod.DeviceUnavailable as e:
+            print(f"FATAL: --ec-backend {args.ec_backend}: {e}",
+                  file=sys.stderr)
+            return 1
         # Boot gate for the DEVICE kernels too: the golden-vector sweep
         # with the host cutover disabled, so the Pallas/XLA GF path that
         # large PUTs will actually run is what gets verified (the plain
         # erasure_self_test above covers the host core only — its
         # 256-byte vectors are all below HOST_CUTOVER_BYTES).
         from minio_tpu.ops.rs_device import DeviceBackend
+        backend = DeviceBackend()
         erasure_self_test(DeviceBackend(host_cutover=0))
 
     # -- grid mesh up BEFORE the object layer (reference: initGlobalGrid
@@ -780,18 +803,60 @@ def main(argv=None) -> int:
     # pipes, divided admission budgets, cross-process locks and cache
     # generations, SIGTERM drain.
     workers_mod.maybe_attach_worker(srv)
-    print(f"minio-tpu serving S3 on {srv.address} "
-          f"({len(pools)} pools, {n_sets} sets, {n_drives} drives, "
-          f"{'distributed, ' if distributed else ''}"
-          f"{'worker ' + worker_id + ', ' if worker_id else ''}"
-          f"ec-backend={'tpu' if backend else 'host'})", flush=True)
+    # The label is what JAX reported in THIS process, never what was
+    # asked for: "tpu" only on a TPU; the explicit-CPU portable path
+    # says so.
+    if dev is None:
+        srv.ec_backend = ec_label = "host"
+    else:
+        srv.ec_backend = "tpu" if dev.platform == "tpu" else "portable"
+        ec_label = (f"{srv.ec_backend}, platform={dev.platform}, "
+                    f"device_kind={dev.device_kind!r}, "
+                    f"devices={dev.devices}, mesh={dev.mesh_devices}")
+        if dev.platform != "tpu":
+            ec_label += (", JAX_PLATFORMS="
+                         f"{os.environ.get('JAX_PLATFORMS', '')}: "
+                         "XLA reference path, no TPU")
     srv.start()
+    if not worker_id and \
+            threading.current_thread() is threading.main_thread():
+        # Single-process boot (every device-backed boot is one): SIGTERM
+        # takes the graceful path below — drain, stamp the drives clean,
+        # exit 0 — and the chip frees with the process. Workers install
+        # their own drain handler (io/workers.WorkerContext.attach).
+        def _on_sigterm(signum, frame):
+            raise KeyboardInterrupt
+        signal.signal(signal.SIGTERM, _on_sigterm)
     try:
+        # Said once it is true: accepting, and a SIGTERM from whoever
+        # waited for this line already stops gracefully.
+        print(f"minio-tpu serving S3 on {srv.address} "
+              f"({len(pools)} pools, {n_sets} sets, {n_drives} drives, "
+              f"{'distributed, ' if distributed else ''}"
+              f"{'worker ' + worker_id + ', ' if worker_id else ''}"
+              f"ec-backend={ec_label})", flush=True)
         while True:
             time.sleep(3600)
     except KeyboardInterrupt:
-        scanner.stop()
-        drive_heal.stop()
+        # Background healers first, JOINED with the object layer still
+        # whole under them: the scanner, the drive-heal manager and
+        # every set's MRF worker take no new work and finish the heal
+        # in flight (one object; a 64 MiB deep heal outlasts the 2 s
+        # their stop() waits by default). Two things hang on it. The
+        # clean stamp below tells the next boot to skip the recovery
+        # sweep, so it must not be written while a heal is between
+        # rename_data's steps. And a heal's device call runs on the
+        # kernel lane's daemon thread: finalising the interpreter under
+        # it aborts from inside the runtime (SIGABRT, seen after a
+        # degraded read). Calibration probes need no mention here —
+        # they write nothing to the drives and are non-daemon threads,
+        # so returning from main() waits for them.
+        deadline = time.monotonic() + _QUIESCE_S
+        busy = [what for what, stop in (
+            ("scanner", scanner.stop), ("drive heal", drive_heal.stop),
+            *((f"MRF heal (set {i})", s.stop_mrf)
+              for i, s in enumerate(all_sets)))
+            if not stop(max(0.0, deadline - time.monotonic()))]
         layer.stop_elastic_janitor()
         if getattr(srv, "coherence", None) is not None:
             srv.coherence.stop()
@@ -802,6 +867,13 @@ def main(argv=None) -> int:
         srv.stop()
         if grid_srv is not None:
             grid_srv.stop()
+        if busy:
+            # Not a clean stop, and not called one: no stamp, so the
+            # next boot runs the deep recovery sweep, and exit 1.
+            print(f"WARN: shutdown: {', '.join(busy)} still running "
+                  f"after {_QUIESCE_S:.0f} s; drives NOT stamped clean",
+                  file=sys.stderr, flush=True)
+            return 1
         # Graceful exit: stamp every local drive so the next boot skips
         # the deep crash-recovery sweep (storage/local.recovery_sweep).
         from minio_tpu.storage.local import mark_clean_shutdown

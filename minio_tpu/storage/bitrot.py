@@ -253,8 +253,14 @@ def read_framed_blocks_many(blobs, shard_size: int, data_size: int,
                 try:
                     got_dev = hh_device.framed_digests_device(u32) \
                         .reshape(len(oks), full, hsize)
-                except Exception:  # noqa: BLE001 - device trouble is not
-                    got_dev = None  # corruption; fall back to host hashing
+                except Exception as e:  # noqa: BLE001 - device trouble
+                    # is not corruption: counted and logged, then host
+                    # hashing — unless the device was asked for.
+                    from minio_tpu.ops import device as device_mod
+                    device_mod.record_fault("framed_digests", e)
+                    if device_mod.required():
+                        raise
+                    got_dev = None
         if got_dev is not None:
             for j, i in enumerate(oks):
                 if not np.array_equal(got_dev[j], wants[i]):

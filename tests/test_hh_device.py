@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from minio_tpu.erasure.codec import Erasure
-from minio_tpu.ops import gf256
+from minio_tpu.ops import device, gf256
 from minio_tpu.ops.hh_device import (_hash_words_pallas, _init_smem_np,
                                      _init_state_np, _pick_pchunk,
                                      hash_blocks_device, hash_blocks_pallas,
@@ -22,7 +22,7 @@ from minio_tpu.ops.hh_device import (_hash_words_pallas, _init_smem_np,
 from minio_tpu.storage import bitrot
 from minio_tpu.utils.highwayhash import MAGIC_KEY, highwayhash256_many
 
-_ON_TPU = jax.default_backend() == "tpu"
+_ON_TPU = device.on_tpu()
 
 
 # ---------------------------------------------------------------------------

@@ -6,24 +6,21 @@ import pytest
 
 from minio_tpu.erasure.codec import Erasure, HostBackend
 from minio_tpu.erasure.selftest import erasure_self_test
-from minio_tpu.ops import gf256
+from minio_tpu.ops import device, gf256
 from minio_tpu.ops.rs_device import DeviceBackend
 
 CONFIGS = [(2, 2), (4, 2), (8, 4), (5, 3), (12, 4), (16, 4)]
 
-# Pallas runs in (slow) interpret mode off-TPU, so CI keeps a reduced sweep
-# for it; the full sweep runs on the XLA path, which lowers the exact same
-# bit-matrix math. On real TPU hardware bench.py exercises the compiled
-# Pallas kernel and cross-checks bytes against the host backend.
-_ON_TPU = False
-try:  # pragma: no cover - conftest pins CPU; real chip in bench runs
-    import jax
-    _ON_TPU = jax.default_backend() == "tpu"
-except Exception:
-    pass
+# Off-TPU the Pallas kernel runs through the (slow) interpreter, asked
+# for by name, so CI keeps a reduced sweep for it; the full sweep runs
+# on the XLA path, which lowers the exact same bit-matrix math. On the
+# chip (`JAX_PLATFORMS=tpu python -m pytest tests/test_rs_device.py`)
+# the same tests run the compiled kernel in full.
+_ON_TPU = device.on_tpu()
 
 
-@pytest.fixture(scope="module", params=["xla", "pallas"])
+@pytest.fixture(scope="module",
+                params=["xla", "pallas" if _ON_TPU else "interpret"])
 def backend(request):
     # host_cutover=0: these tests exist to exercise the DEVICE kernels;
     # the production small-input host reroute would make them vacuous.
