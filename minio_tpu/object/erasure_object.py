@@ -571,15 +571,16 @@ class ErasureSet:
                 try:
                     with deadline_mod.bind(dl), \
                             tracing.bind(tctx, tparent):
+                        tags = None
                         if tctx is not None:
                             wait_ms = (_time_mod.perf_counter() - t_sub) \
                                 * 1000.0
-                            with tracing.span(
-                                    "storage", "engine.op",
-                                    {"drive": i,
-                                     "queue_wait_ms": round(wait_ms, 3)}):
-                                results[i] = fn()
-                        else:
+                            tags = {"drive": i,
+                                    "queue_wait_ms": round(wait_ms, 3)}
+                        # No stage counter: drive_op_duration_seconds
+                        # already counts the op.
+                        with tracing.stage("engine.op", tags,
+                                           type_="storage", count=False):
                             results[i] = fn()
                 except BaseException as e:  # noqa: BLE001 - per-disk isolation
                     errors[i] = e
@@ -1024,9 +1025,9 @@ class ErasureSet:
         out = (ctypes.c_uint8 * (n * span)).from_buffer(lease.raw)
         md5_ctx = md5.native_ctx if md5 is not None else None
         try:
-            with tracing.span("kernel", "mtpu_put_frame",
-                              {"blocks": full, "k": k, "m": m}) \
-                    if tracing.ACTIVE else tracing.NOOP:
+            with tracing.stage("mtpu_put_frame",
+                               {"blocks": full, "k": k, "m": m},
+                               type_="kernel", count=False):
                 if md5_ctx is not None:
                     lib.mtpu_put_frame_md5(
                         md5_ctx, native._u8(MAGIC_KEY), native._u8(pm),
@@ -1819,8 +1820,11 @@ class ErasureSet:
         import threading
         threads = [threading.Thread(target=writer, args=(i,), daemon=True)
                    for i in range(n)]
-        for t in threads:
-            t.start()
+        with tracing.stage("put.writers_start", type_="storage"):
+            # start() returns once the new thread has run: one wait
+            # for the GIL per drive
+            for t in threads:
+                t.start()
         # Streaming etag: a native md5 context that the pooled frame
         # call extends INSIDE the same GIL-free native pass as the
         # encode+frame (mtpu_put_frame_md5); windows that take the
@@ -1830,30 +1834,39 @@ class ErasureSet:
         write_quorum = k + (1 if k == m else 0)
         stream_error: Optional[Exception] = None
         try:
-            while True:
+            # The request thread's stages (utils/tracing.stage), one
+            # after the other and never nested: their seconds add up
+            # to this call's.
+            while payload.remaining > 0:
                 if dl is not None:
                     dl.check()
-                window = payload.read_exact(window_bytes)
-                if not window:
-                    break
+                with tracing.stage("put.body_read"):
+                    # socket recv + SHA-256 / chunk-signature check
+                    window = payload.read_exact(window_bytes)
                 window_lease = None
                 try:
-                    framed, window_lease = self._frame_windows(
-                        window, k, m, md5=md5)
+                    with tracing.stage("put.frame", type_="kernel"):
+                        framed, window_lease = self._frame_windows(
+                            window, k, m, md5=md5)
                     if not md5.take_folded():
-                        md5.update(window)
+                        with tracing.stage("put.md5"):
+                            md5.update(window)
                     if n - sum(dead) < write_quorum:
                         raise WriteQuorumError(
                             "", "",
                             f"{sum(dead)}/{n} writers failed mid-stream")
-                    for i in range(n):
-                        if dead[i]:
-                            continue
-                        cb = None
-                        if window_lease is not None:
-                            window_lease.retain()
-                            cb = window_lease.release
-                        qs[i].put((framed[distribution[i] - 1], cb))
+                    with tracing.stage("put.shard_enqueue",
+                                       type_="storage"):
+                        # blocks while a drive's writer is two windows
+                        # behind (queue depth 2)
+                        for i in range(n):
+                            if dead[i]:
+                                continue
+                            cb = None
+                            if window_lease is not None:
+                                window_lease.retain()
+                                cb = window_lease.release
+                            qs[i].put((framed[distribution[i] - 1], cb))
                 finally:
                     # The producer's own reference; per-writer refs are
                     # returned by each consumer.
@@ -1862,10 +1875,11 @@ class ErasureSet:
         except Exception as exc:  # noqa: BLE001 - unwind writers first
             stream_error = exc
         finally:
-            for i in range(n):
-                qs[i].put(_SENTINEL)
-            for t in threads:
-                t.join()
+            with tracing.stage("put.shard_drain", type_="storage"):
+                for i in range(n):
+                    qs[i].put(_SENTINEL)
+                for t in threads:
+                    t.join()
         if stream_error is not None:
             raise stream_error
         return md5.hexdigest(), errors
@@ -1877,7 +1891,9 @@ class ErasureSet:
         quorum-commit with atomic renames under the namespace lock —
         encode and IO run unlocked, only the commit serializes (the
         reference's tmp-write + renameData commit discipline)."""
-        self._check_bucket(bucket)
+        with tracing.stage("put.prepare", type_="storage"):
+            # a stat_vol fan-out when the bucket's TTL entry has run out
+            self._check_bucket(bucket)
         n = len(self.disks)
         m = self.default_parity
         if opts.storage_class == "REDUCED_REDUNDANCY" and n > 1:
@@ -1939,7 +1955,8 @@ class ErasureSet:
                                       make_fi(distribution[i] - 1),
                                       bucket, object_)
 
-        with self.ns.write(bucket, object_):
+        with tracing.stage("put.commit", type_="storage"), \
+                self.ns.write(bucket, object_):
             _, cerrors = self._fanout(
                 [lambda i=i: commit_one(i) for i in range(n)])
         ok = sum(e2 is None for e2 in cerrors)

@@ -762,7 +762,8 @@ class StripeBatcher:
         try:
             if total >= self._min_device_blocks and self._device_ok:
                 route = "device"
-                lease, stacked = self._stage(live, bucket)
+                with tracing.stage("batcher.stage", type_="kernel"):
+                    lease, stacked = self._stage(live, bucket)
                 t_lane = time.perf_counter()
                 try:
                     rows_all = self._lane_dispatch(stacked)
@@ -773,33 +774,35 @@ class StripeBatcher:
                     self._lane_hist.observe(time.perf_counter() - t_lane)
                     if lease is not None:
                         lease.release()
-                if self._split_fn is not None:
-                    # Route-specific demux (get: verdict slices + data
-                    # views of the member's OWN window; reconstruct:
-                    # rebuilt-row slices).
-                    off = 0
-                    for p, c in zip(live, counts):
-                        p.rows = self._split_fn(rows_all, off, c,
-                                                p.stacked)
-                        off += c
-                else:
-                    k = live[0].stacked.shape[1]
-                    staged = lease is not None or len(live) > 1
-                    off = 0
-                    for p, c in zip(live, counts):
-                        rows = [drive[off:off + c] for drive in rows_all]
-                        if staged:
-                            # Demultiplex data drives back onto each
-                            # member's OWN window: device rows view the
-                            # shared staging buffer whose lease just
-                            # returned to the pool; digests/parity are
-                            # fresh device output and stay as-is.
-                            for i in range(k):
-                                rows[i] = [(dig, p.stacked[bi, i])
-                                           for bi, (dig, _blk)
-                                           in enumerate(rows[i])]
-                        p.rows = rows
-                        off += c
+                with tracing.stage("batcher.demux", type_="kernel",
+                                   count=False):
+                    if self._split_fn is not None:
+                        # Route-specific demux (get: verdict slices + data
+                        # views of the member's OWN window; reconstruct:
+                        # rebuilt-row slices).
+                        off = 0
+                        for p, c in zip(live, counts):
+                            p.rows = self._split_fn(rows_all, off, c,
+                                                    p.stacked)
+                            off += c
+                    else:
+                        k = live[0].stacked.shape[1]
+                        staged = lease is not None or len(live) > 1
+                        off = 0
+                        for p, c in zip(live, counts):
+                            rows = [drive[off:off + c] for drive in rows_all]
+                            if staged:
+                                # Demultiplex data drives back onto each
+                                # member's OWN window: device rows view the
+                                # shared staging buffer whose lease just
+                                # returned to the pool; digests/parity are
+                                # fresh device output and stay as-is.
+                                for i in range(k):
+                                    rows[i] = [(dig, p.stacked[bi, i])
+                                               for bi, (dig, _blk)
+                                               in enumerate(rows[i])]
+                            p.rows = rows
+                            off += c
                 with self._stat_mu:
                     self._dispatches["device"] += 1
                     self._requests["device"] += len(live)

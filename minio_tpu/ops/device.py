@@ -37,6 +37,8 @@ import threading
 import traceback
 from typing import NamedTuple
 
+from minio_tpu.utils import tracing
+
 # <checkout>/.jax_cache — fixed, because the directory is part of the
 # persistent cache's key: a path that moves (tempfile, pid, timestamp)
 # never hits. Git-ignored.
@@ -85,12 +87,23 @@ def info() -> DeviceInfo:
     # one-second floor; without this only the big framers would hit.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     devs = jax.devices()
+    # The program's stages go onto the profiler's clock from here on
+    # (utils/tracing.stage): whoever traces this process — the
+    # benchmark's launcher, the admin's trace profile — finds them
+    # beside the device's operations.
+    tracing.set_annotator(jax.profiler.TraceAnnotation)
     return DeviceInfo(devs[0].platform, devs[0].device_kind, len(devs),
                       len(mesh_batch_devices(devs)))
 
 
 def on_tpu() -> bool:
     return info().platform == "tpu"
+
+
+def held() -> bool:
+    """This process has initialised JAX through info() — it holds the
+    device, if there is one. JAX-free: never initialises it."""
+    return info.cache_info().currsize > 0
 
 
 def mesh_batch_devices(devices=None) -> list:

@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from minio_tpu.ops import device
+from minio_tpu.utils import tracing
 from minio_tpu.utils.highwayhash import MAGIC_KEY
 
 _U32 = jnp.uint32
@@ -737,6 +738,58 @@ def framed_digests_eligible(n_blocks: int, shard_size: int) -> bool:
 # Fused encode + bitrot digests
 # ---------------------------------------------------------------------------
 
+def _lane_round_trip(upload, step) -> tuple:
+    """One framer dispatch, cut where the host truly waits, into the
+    kernel lane's three stages (utils/tracing.stage): `lane.upload`
+    (the host's part of moving the arrays onto the device: JAX returns
+    once the transfer is under way), `lane.kernel` (the jitted step
+    until its outputs are ready, which also waits out what is left of
+    the transfer) and `lane.readback` (outputs back as contiguous
+    numpy, and the batch's device buffers let go). The one
+    `block_until_ready` stands where `np.asarray` would block anyway;
+    the device trace tells transfer from kernel."""
+    with tracing.stage("lane.upload", type_="kernel"):
+        args = upload()
+    with tracing.stage("lane.kernel", type_="kernel"):
+        outs = jax.block_until_ready(step(*args))
+    with tracing.stage("lane.readback", type_="kernel"):
+        # ascontiguousarray: device arrays can come back with a
+        # non-contiguous minor axis for some batch shapes, and .view
+        # of a wider dtype requires contiguity.
+        rows = tuple(np.ascontiguousarray(np.asarray(o)) for o in outs)
+        # Released here, not by the return: freeing a batch-sized
+        # device buffer can take as long as reading the outputs back,
+        # and would otherwise be lane time no stage holds.
+        del args, outs
+        return rows
+
+
+def _rows32(data, parity32, dig_d32, dig_p32) -> list[list[tuple]]:
+    """The u32 framer's outputs as per-drive lists of (digest, block)
+    pieces; data blocks are views of `data` (zero copy). The lane's
+    fourth stage: B x n numpy slices under the GIL."""
+    with tracing.stage("lane.rows", type_="kernel"):
+        parity = parity32.view(np.uint8)             # [B, m, L]
+        dig_d = dig_d32.view(np.uint8)               # [B, k, 32]
+        dig_p = dig_p32.view(np.uint8)               # [B, m, 32]
+        b, k = data.shape[:2]
+        return ([[(dig_d[bi, i], data[bi, i]) for bi in range(b)]
+                 for i in range(k)]
+                + [[(dig_p[bi, j], parity[bi, j]) for bi in range(b)]
+                   for j in range(parity.shape[1])])
+
+
+def _rows8(data, parity, digests) -> list[list[tuple]]:
+    """The byte framer's outputs (digests [B, n, 32]) as per-drive
+    lists of (digest, block) pieces."""
+    with tracing.stage("lane.rows", type_="kernel"):
+        b, k = data.shape[:2]
+        shards = [data[:, i] for i in range(k)] \
+            + [parity[:, j] for j in range(parity.shape[1])]
+        return [[(digests[bi, i], shards[i][bi]) for bi in range(b)]
+                for i in range(len(shards))]
+
+
 def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
     """Fused PUT pipeline on device, one call per stripe batch.
 
@@ -795,35 +848,19 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
         of which is drive i's framed shard-file bytes. Data-block pieces
         are views of `data` (zero copy)."""
         data = np.ascontiguousarray(data, dtype=np.uint8)
-        b, k, l = data.shape
+        l = data.shape[2]
         pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
         if on_tpu and l % 1024 == 0 and pchunk >= 8:
             device.note_kernel("frame", "pallas")
-            data32 = jnp.asarray(data.view(np.uint32))
-            parity, dig_d, dig_p = fused32(
-                data32, jnp.asarray(_init_smem_np(MAGIC_KEY)), pchunk)
-            # ascontiguousarray: device arrays can come back with a
-            # non-contiguous minor axis for some batch shapes, and
-            # .view of a wider dtype requires contiguity.
-            parity = np.ascontiguousarray(np.asarray(parity)) \
-                .view(np.uint8)                          # [B, m, L]
-            dig_d = np.ascontiguousarray(np.asarray(dig_d)) \
-                .view(np.uint8)                          # [B, k, 32]
-            dig_p = np.ascontiguousarray(np.asarray(dig_p)) \
-                .view(np.uint8)                          # [B, m, 32]
-            return ([[(dig_d[bi, i], data[bi, i]) for bi in range(b)]
-                     for i in range(k)]
-                    + [[(dig_p[bi, j], parity[bi, j]) for bi in range(b)]
-                       for j in range(parity.shape[1])])
+            return _rows32(data, *_lane_round_trip(
+                lambda: (jnp.asarray(data.view(np.uint32)),
+                         jnp.asarray(_init_smem_np(MAGIC_KEY))),
+                lambda data32, init: fused32(data32, init, pchunk)))
         device.note_kernel("frame", "xla")
-        parity, digests = fused8(jnp.asarray(data, dtype=jnp.uint8),
-                                 jnp.asarray(_init_state_np(MAGIC_KEY)))
-        parity = np.asarray(parity)
-        digests = np.asarray(digests)                    # [B, n, 32]
-        shards = [data[:, i] for i in range(k)] \
-            + [parity[:, j] for j in range(parity.shape[1])]
-        return [[(digests[bi, i], shards[i][bi]) for bi in range(b)]
-                for i in range(n)]
+        return _rows8(data, *_lane_round_trip(
+            lambda: (jnp.asarray(data, dtype=jnp.uint8),
+                     jnp.asarray(_init_state_np(MAGIC_KEY))),
+            fused8))
 
     def device_step(data32):
         """Device-resident fused pipeline: u32 [B, k, L4] -> (parity,
@@ -989,33 +1026,21 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
 
     def run(data) -> list[list[tuple]]:
         data = np.ascontiguousarray(data, dtype=np.uint8)
-        b, kk, l = data.shape
+        b, _, l = data.shape
         assert b % ndev == 0, \
             f"batch {b} not divisible by {ndev}-chip mesh (pad buckets)"
         pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
         if on_tpu and l % 1024 == 0 and pchunk >= 8:
             device.note_kernel("frame", "pallas")
-            d32 = jax.device_put(data.view(np.uint32), sharding)
-            parity, dig_d, dig_p = mesh32(
-                d32, jnp.asarray(_init_smem_np(MAGIC_KEY)), pchunk)
-            parity = np.ascontiguousarray(np.asarray(parity)) \
-                .view(np.uint8)
-            dig_d = np.ascontiguousarray(np.asarray(dig_d)).view(np.uint8)
-            dig_p = np.ascontiguousarray(np.asarray(dig_p)).view(np.uint8)
-            return ([[(dig_d[bi, i], data[bi, i]) for bi in range(b)]
-                     for i in range(k)]
-                    + [[(dig_p[bi, j], parity[bi, j]) for bi in range(b)]
-                       for j in range(m)])
+            return _rows32(data, *_lane_round_trip(
+                lambda: (jax.device_put(data.view(np.uint32), sharding),
+                         jnp.asarray(_init_smem_np(MAGIC_KEY))),
+                lambda d32, init: mesh32(d32, init, pchunk)))
         device.note_kernel("frame", "xla")
-        d8 = jax.device_put(data, sharding)
-        parity, digests = mesh8(d8,
-                                jnp.asarray(_init_state_np(MAGIC_KEY)))
-        parity = np.asarray(parity)
-        digests = np.asarray(digests)
-        shards = [data[:, i] for i in range(k)] \
-            + [parity[:, j] for j in range(m)]
-        return [[(digests[bi, i], shards[i][bi]) for bi in range(b)]
-                for i in range(n)]
+        return _rows8(data, *_lane_round_trip(
+            lambda: (jax.device_put(data, sharding),
+                     jnp.asarray(_init_state_np(MAGIC_KEY))),
+            mesh8))
 
     run.mesh_devices = ndev
     return run

@@ -17,6 +17,11 @@ from minio_tpu.utils import tracing as _tracing
 from minio_tpu.utils.latency import Histogram, LastMinute, summarize
 
 
+def _process_cpu_seconds() -> float:
+    t = os.times()
+    return t.user + t.system
+
+
 class Metrics:
     def __init__(self):
         self._mu = threading.Lock()
@@ -119,6 +124,8 @@ class Metrics:
         out["latency_hist"] = {a: h.state() for a, h in hists.items()}
         out["last_minute"] = {a: lm.window() for a, lm in minutes.items()}
         out["slow_ops_total"] = _tracing.slow_total
+        out["stages"] = _tracing.stage_totals()
+        out["process_cpu_s"] = _process_cpu_seconds()
         return out
 
     # -- rendering -------------------------------------------------------
@@ -176,6 +183,22 @@ class Metrics:
             minutes = {a: lm.window()
                        for a, lm in self._last_minute.items()}
         slow_total = _tracing.slow_total
+        # The stage seconds and the kernel lane's service seconds are
+        # read here, together, though they are printed far apart: under
+        # load a render takes seconds, and a ratio of two counters read
+        # seconds apart (the lane's three-way split over the lane's own
+        # time) would be off by what the lane did in between.
+        stages = _tracing.stage_totals()
+        # Report the lane without CREATING it: kernel_lane() lazily
+        # spawns a worker thread, and a scrape on a host-codec-only
+        # process should not pay a permanent thread to export zeros.
+        from minio_tpu.io import engine as _engine
+        if _engine._kernel_lane is not None:
+            kst = _engine._kernel_lane.stats()
+        else:
+            kst = {"queued": 0, "submitted_total": 0,
+                   "service_hist": Histogram().state()}
+        cpu_s = None                # this process's own, read at render
         peer_metrics = [p["metrics"] for p in (peer_states or [])
                         if isinstance(p.get("metrics"), dict)]
         # Cluster federation: remote nodes' worker states join the
@@ -198,6 +221,8 @@ class Metrics:
             conn_active = keepalive_reuses = parse_fallbacks = 0
             resp_path = {}
             slow_total = 0
+            stages = {}
+            cpu_s = 0.0
             hist_states: dict[str, list] = {}
             minute_states: dict[str, list] = {}
             for st in peer_metrics:
@@ -219,6 +244,8 @@ class Metrics:
                 for k, v in st.get("response_path", {}).items():
                     resp_path[k] = resp_path.get(k, 0) + v
                 slow_total += st.get("slow_ops_total", 0)
+                _tracing.add_stage_totals(stages, st.get("stages", {}))
+                cpu_s += st.get("process_cpu_s", 0.0)
             hists = {a: Histogram.merge(sts)
                      for a, sts in hist_states.items()}
             minutes = {a: LastMinute.merge(ws)
@@ -317,9 +344,32 @@ class Metrics:
                "Spans that crossed the MTPU_SLOW_OP_MS threshold "
                "(slow-op log records emitted)", "counter",
                [({}, slow_total)])
+        # The always-on stage accumulator (utils/tracing.stage): where
+        # a request's seconds go, by named boundary of its path.
+        names = sorted(stages)
+        metric("minio_tpu_stage_seconds_total",
+               "Wall seconds spent inside each named stage of the "
+               "request path (includes waiting, e.g. for the GIL)",
+               "counter",
+               [({"stage": n}, round(stages[n][0], 6)) for n in names])
+        metric("minio_tpu_stage_cpu_seconds_total",
+               "Thread CPU seconds spent inside each named stage",
+               "counter",
+               [({"stage": n}, round(stages[n][1], 6)) for n in names])
+        metric("minio_tpu_stage_entries_total",
+               "Entries into each named stage", "counter",
+               [({"stage": n}, stages[n][2]) for n in names])
+        # Read on adjacent lines so their ratio (cores in use) carries
+        # no skew from the seconds a render takes under load.
+        uptime_s = time.time() - self._start
+        if cpu_s is None:
+            cpu_s = _process_cpu_seconds()
         metric("minio_tpu_process_uptime_seconds",
                "Seconds since server start", "gauge",
-               [({}, round(time.time() - self._start, 1))])
+               [({}, round(uptime_s, 1))])
+        metric("minio_tpu_process_cpu_seconds_total",
+               "User + system CPU seconds of the serving process(es)",
+               "counter", [({}, round(cpu_s, 3))])
 
         if scanner is not None:
             u = scanner.usage
@@ -889,16 +939,6 @@ class Metrics:
         hist_metric("minio_tpu_group_commit_wait_seconds",
                     "Coalescing wait per commit member (enqueue to "
                     "batch dispatch)", [({}, gst["wait_hist"])])
-        # Report the lane without CREATING it: kernel_lane() lazily
-        # spawns a worker thread, and a scrape on a host-codec-only
-        # process should not pay a permanent thread to export zeros.
-        from minio_tpu.io import engine as _engine
-        from minio_tpu.utils.latency import Histogram as _Hist
-        if _engine._kernel_lane is not None:
-            kst = _engine._kernel_lane.stats()
-        else:
-            kst = {"queued": 0, "submitted_total": 0,
-                   "service_hist": _Hist().state()}
         metric("minio_tpu_kernel_lane_queued",
                "Device dispatches waiting in the shared kernel lane",
                "gauge", [({}, kst["queued"])])

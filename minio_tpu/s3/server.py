@@ -906,6 +906,7 @@ def _make_handler(server: S3Server):
             # of re-running the pattern checks (the hot loop's
             # "admission without re-entering the router slow path").
             pc = admission_path_class(raw_path)
+            api = self._api_label(method, raw_path, bucket, key, pc)
             self._last_status = 0
             self._sent_bytes = 0
             self._auth_key = ""
@@ -948,8 +949,13 @@ def _make_handler(server: S3Server):
                 # binding channel the deadline budget rides.
                 if tracing_mod.ACTIVE:
                     tctx = tracing_mod.TraceContext()
+                # The whole request on the profiler's clock, under the
+                # API's label, holding its stages' seconds until it ends
+                # (the request's record is the trace root published
+                # below; its seconds are api_request_duration_seconds).
                 with deadline_mod.bind(dl), tracing_mod.bind(tctx), \
-                        server.profiler.request_profile():
+                        server.profiler.request_profile(), \
+                        tracing_mod.request_root("s3." + api):
                     self._route_inner(method, raw_path, query, bucket, key,
                                       pc)
             finally:
@@ -968,7 +974,6 @@ def _make_handler(server: S3Server):
                 except ValueError:
                     rx = 0
                 dt = _time_mod.perf_counter() - t0
-                api = self._api_label(method, raw_path, bucket, key, pc)
                 status = self._last_status or 500
                 server.metrics.record(api, status, dt,
                                       rx=rx, tx=self._sent_bytes)
@@ -1017,32 +1022,34 @@ def _make_handler(server: S3Server):
                 if raw_path == "/minio/health/ready":
                     return self._health_ready()
                 if pc == "metrics":
-                    # Worker mode: whichever worker the kernel handed
-                    # this scrape to aggregates the whole fleet via
-                    # the parent control pipe (io/workers.py).
-                    peers = None
-                    if server.cluster_stats is not None:
-                        try:
-                            peers = server.cluster_stats()
-                        except Exception:  # noqa: BLE001 - serve own
-                            peers = None
-                    # Cluster federation: pull every peer NODE's
-                    # telemetry over the grid (peer.metrics verb) so a
-                    # scrape of any node reports the whole cluster
-                    # with per-node labels. ?cluster=false opts out
-                    # (per-node scrape configs avoiding N^2 fan-out).
-                    nodes = None
-                    want_cluster = (query.get("cluster", [""])[0]
-                                    or "").lower() not in (
-                        "false", "0", "off", "no")
-                    if server.profile_peers and want_cluster:
-                        nodes = self._cluster_metrics_states()
-                    text = server.metrics.render(
-                        object_layer=server.object_layer,
-                        scanner=getattr(server.object_layer, "scanner",
-                                        None),
-                        server=server, peer_states=peers,
-                        node_states=nodes)
+                    with tracing_mod.stage("s3.metrics_render",
+                                           count=False):
+                        # Worker mode: whichever worker the kernel handed
+                        # this scrape to aggregates the whole fleet via
+                        # the parent control pipe (io/workers.py).
+                        peers = None
+                        if server.cluster_stats is not None:
+                            try:
+                                peers = server.cluster_stats()
+                            except Exception:  # noqa: BLE001 - serve own
+                                peers = None
+                        # Cluster federation: pull every peer NODE's
+                        # telemetry over the grid (peer.metrics verb) so a
+                        # scrape of any node reports the whole cluster
+                        # with per-node labels. ?cluster=false opts out
+                        # (per-node scrape configs avoiding N^2 fan-out).
+                        nodes = None
+                        want_cluster = (query.get("cluster", [""])[0]
+                                        or "").lower() not in (
+                            "false", "0", "off", "no")
+                        if server.profile_peers and want_cluster:
+                            nodes = self._cluster_metrics_states()
+                        text = server.metrics.render(
+                            object_layer=server.object_layer,
+                            scanner=getattr(server.object_layer, "scanner",
+                                            None),
+                            server=server, peer_states=peers,
+                            node_states=nodes)
                     return self._send(200, text.encode(),
                                       content_type="text/plain; "
                                       "version=0.0.4")
@@ -1063,43 +1070,45 @@ def _make_handler(server: S3Server):
                 # cmd/auth-handler.go:433-449 authTypeAnonymous ->
                 # globalPolicySys.IsAllowed).
                 h = self._headers_lower()
-                if "authorization" not in h \
-                        and "X-Amz-Signature" not in query \
-                        and "Signature" not in query:
-                    auth = sigv4.anonymous_auth()
-                else:
-                    auth = self._auth(method, raw_path, query)
-                self._auth_key = auth.credential.access_key
-                # STS credentials must present their session token on
-                # every request (reference: cmd/auth-handler.go's
-                # getSessionToken check); permanent keys have none.
-                if not auth.anonymous and \
-                        server.credentials.iam is not None:
-                    tok = server.credentials.iam.session_token_for(
-                        auth.credential.access_key)
-                    if tok is not None:
-                        presented = h.get("x-amz-security-token", "") or \
-                            query.get("X-Amz-Security-Token", [""])[0]
-                        if presented != tok:
-                            raise S3Error("AccessDenied",
-                                          "invalid session token")
+                with tracing_mod.stage("s3.auth"):
+                    if "authorization" not in h \
+                            and "X-Amz-Signature" not in query \
+                            and "Signature" not in query:
+                        auth = sigv4.anonymous_auth()
+                    else:
+                        auth = self._auth(method, raw_path, query)
+                    self._auth_key = auth.credential.access_key
+                    # STS credentials must present their session token on
+                    # every request (reference: cmd/auth-handler.go's
+                    # getSessionToken check); permanent keys have none.
+                    if not auth.anonymous and \
+                            server.credentials.iam is not None:
+                        tok = server.credentials.iam.session_token_for(
+                            auth.credential.access_key)
+                        if tok is not None:
+                            presented = h.get("x-amz-security-token", "") or \
+                                query.get("X-Amz-Security-Token", [""])[0]
+                            if presented != tok:
+                                raise S3Error("AccessDenied",
+                                              "invalid session token")
+                    if pc != "admin":
+                        # Per-request policy authorization (reference:
+                        # checkRequestAuthType -> IsAllowed): root passes, IAM
+                        # identities evaluate their policies merged deny-wins
+                        # with the bucket policy; anonymous identities need an
+                        # explicit bucket-policy Allow.
+                        ak = auth.credential.access_key
+                        ctx = self._auth_context(ak, query, h)
+                        for action, resource in _required_permissions(
+                                method, bucket, key, query, h):
+                            if not self._authorize(ak, auth.anonymous, action,
+                                                   resource, ctx):
+                                raise S3Error("AccessDenied", bucket=bucket,
+                                              key=key)
                 if pc == "admin":
                     if auth.anonymous:
                         raise S3Error("AccessDenied")
                     return self._admin_op(method, raw_path, query, auth)
-                # Per-request policy authorization (reference:
-                # checkRequestAuthType -> IsAllowed): root passes, IAM
-                # identities evaluate their policies merged deny-wins
-                # with the bucket policy; anonymous identities need an
-                # explicit bucket-policy Allow.
-                ak = auth.credential.access_key
-                ctx = self._auth_context(ak, query, h)
-                for action, resource in _required_permissions(
-                        method, bucket, key, query, h):
-                    if not self._authorize(ak, auth.anonymous, action,
-                                           resource, ctx):
-                        raise S3Error("AccessDenied", bucket=bucket,
-                                      key=key)
                 body = b""
                 payload = None
                 # Object-data PUTs stream O(window); every other body
@@ -2328,73 +2337,78 @@ def _make_handler(server: S3Server):
             h = self._headers_lower()
             if "x-amz-copy-source" in h:
                 return self._copy_object(bucket, key, h)
-            if "if-match" in h or "if-none-match" in h:
-                # Conditional write (create-only / replace-exact): check
-                # the current version before accepting the body. Only a
-                # definitive not-found counts as absent — a transient
-                # read failure must NOT let a create-only PUT overwrite.
-                from minio_tpu.object.types import (MethodNotAllowed as _MNA,
-                                                    ObjectNotFound as _ONF,
-                                                    VersionNotFound as _VNF)
-                try:
-                    cur = server.object_layer.get_object_info(
-                        bucket, key, GetOptions())
-                except (_ONF, _VNF, _MNA):
-                    cur = None
-                if cur is None:
-                    if "if-match" in h:
-                        raise S3Error("NoSuchKey", bucket=bucket, key=key)
+            # Everything between the signature and the body's first byte
+            # is one stage: conditions, versioning / object-lock / quota /
+            # SSE / replication decisions — each a bucket-metadata lookup
+            # that fans out to the drives when its TTL cache has run out.
+            with tracing_mod.stage("s3.put_prepare"):
+                if "if-match" in h or "if-none-match" in h:
+                    # Conditional write (create-only / replace-exact): check
+                    # the current version before accepting the body. Only a
+                    # definitive not-found counts as absent — a transient
+                    # read failure must NOT let a create-only PUT overwrite.
+                    from minio_tpu.object.types import (
+                        MethodNotAllowed as _MNA, ObjectNotFound as _ONF,
+                        VersionNotFound as _VNF)
+                    try:
+                        cur = server.object_layer.get_object_info(
+                            bucket, key, GetOptions())
+                    except (_ONF, _VNF, _MNA):
+                        cur = None
+                    if cur is None:
+                        if "if-match" in h:
+                            raise S3Error("NoSuchKey", bucket=bucket, key=key)
+                    else:
+                        self._check_conditions(h, cur, for_read=False)
+                meta = {k[len("x-amz-meta-"):]: v for k, v in h.items()
+                        if k.startswith("x-amz-meta-")}
+                opts = PutOptions(
+                    versioned=_versioned(server.object_layer, bucket),
+                    user_metadata=meta,
+                    content_type=h.get("content-type", ""),
+                    storage_class=h.get("x-amz-storage-class", "STANDARD"),
+                    tags=h.get("x-amz-tagging", ""))
+                opts.internal_metadata.update(
+                    self._object_lock_put_meta(bucket, h))
+                self._check_quota(bucket, payload.size)
+                fused = self._fused_put_prepare(bucket, key, payload, h, opts)
+                if fused is not None:
+                    # Fused single-pass plane: the raw LOGICAL body goes to
+                    # the object layer with a TransformSpec — etag md5,
+                    # declared checksums, compression, and DARE all run as
+                    # ONE native pass next to the framer
+                    # (object/transform.py). Checksum verification runs
+                    # pre-commit via the spec's verify hook.
+                    payload, sse_headers, checksum_hdrs, plain_size = fused
                 else:
-                    self._check_conditions(h, cur, for_read=False)
-            meta = {k[len("x-amz-meta-"):]: v for k, v in h.items()
-                    if k.startswith("x-amz-meta-")}
-            opts = PutOptions(
-                versioned=_versioned(server.object_layer, bucket),
-                user_metadata=meta,
-                content_type=h.get("content-type", ""),
-                storage_class=h.get("x-amz-storage-class", "STANDARD"),
-                tags=h.get("x-amz-tagging", ""))
-            opts.internal_metadata.update(
-                self._object_lock_put_meta(bucket, h))
-            self._check_quota(bucket, payload.size)
-            fused = self._fused_put_prepare(bucket, key, payload, h, opts)
-            if fused is not None:
-                # Fused single-pass plane: the raw LOGICAL body goes to
-                # the object layer with a TransformSpec — etag md5,
-                # declared checksums, compression, and DARE all run as
-                # ONE native pass next to the framer
-                # (object/transform.py). Checksum verification runs
-                # pre-commit via the spec's verify hook.
-                payload, sse_headers, checksum_hdrs, plain_size = fused
-            else:
-                from minio_tpu.object import transform as _tf
-                from minio_tpu.object.erasure_object import \
-                    STREAM_THRESHOLD as _ST
-                if payload.size <= _ST:
-                    _tf.note_put("legacy", payload.size)
-                payload, checksum_hdrs = self._apply_checksums(payload, h,
-                                                               opts)
-                plain_size = payload.size
-                # Compression BEFORE encryption: the block scheme sees
-                # plaintext (ciphertext is incompressible), so
-                # compressed+encrypted objects store DARE(compressed)
-                # — the same layering the fused pass produces.
-                payload = self._apply_compression(key, payload, opts)
-                payload, sse_headers = self._apply_sse(bucket, key,
-                                                       payload, h, opts)
-            # Replicate only after the SSE decision: encrypted objects
-            # do not replicate in v1 (their keys bind to this cluster),
-            # and an incoming REPLICA must not ping-pong back in
-            # active-active setups (the mtpu-replica marker).
-            replicate = (server.replicator is not None
-                         and "x-amz-meta-mtpu-replica" not in h
-                         and not opts.internal_metadata.get(
-                             "x-internal-sse-alg")
-                         and server.replicator.should_replicate(bucket,
-                                                                key))
-            if replicate:
-                from minio_tpu.replication import REPL_STATUS_KEY
-                opts.internal_metadata[REPL_STATUS_KEY] = "PENDING"
+                    from minio_tpu.object import transform as _tf
+                    from minio_tpu.object.erasure_object import \
+                        STREAM_THRESHOLD as _ST
+                    if payload.size <= _ST:
+                        _tf.note_put("legacy", payload.size)
+                    payload, checksum_hdrs = self._apply_checksums(payload, h,
+                                                                   opts)
+                    plain_size = payload.size
+                    # Compression BEFORE encryption: the block scheme sees
+                    # plaintext (ciphertext is incompressible), so
+                    # compressed+encrypted objects store DARE(compressed)
+                    # — the same layering the fused pass produces.
+                    payload = self._apply_compression(key, payload, opts)
+                    payload, sse_headers = self._apply_sse(bucket, key,
+                                                           payload, h, opts)
+                # Replicate only after the SSE decision: encrypted objects
+                # do not replicate in v1 (their keys bind to this cluster),
+                # and an incoming REPLICA must not ping-pong back in
+                # active-active setups (the mtpu-replica marker).
+                replicate = (server.replicator is not None
+                             and "x-amz-meta-mtpu-replica" not in h
+                             and not opts.internal_metadata.get(
+                                 "x-internal-sse-alg")
+                             and server.replicator.should_replicate(bucket,
+                                                                    key))
+                if replicate:
+                    from minio_tpu.replication import REPL_STATUS_KEY
+                    opts.internal_metadata[REPL_STATUS_KEY] = "PENDING"
             info = server.object_layer.put_object(bucket, key, payload, opts)
             self._note_quota_write(bucket, plain_size)
             if replicate:
@@ -3894,17 +3908,21 @@ def _make_handler(server: S3Server):
             # StartProfilingHandler / DownloadProfilingDataHandler).
             if op == "start-profiling" and method == "POST":
                 from minio_tpu.s3.profiling import ProfileError
+                kind = q1.get("profilerType", "") or "cpu"
                 try:
-                    server.profiler.start()
+                    server.profiler.start(kind)
                 except ProfileError as e:
                     raise S3Error("InvalidRequest", str(e)) from None
-                for _name, client in server.profile_peers:
+                # A trace is of the local node alone: the process that
+                # holds the device.
+                for _name, client in (server.profile_peers
+                                      if kind == "cpu" else ()):
                     try:
                         client.call("peer.profile", {"action": "start"},
                                     timeout=5)
                     except Exception:  # noqa: BLE001 - peer down
                         pass
-                return ok({"started": True})
+                return ok({"started": True, "profilerType": kind})
             if op == "download-profiling" and method == "GET":
                 import base64 as _b64
 

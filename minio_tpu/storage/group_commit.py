@@ -787,7 +787,12 @@ class GroupCommit:
             # The batch serves many requests with many budgets; the
             # health wrapper's own op timeout bounds the commit, and
             # the per-member deadlines were enforced at cull time.
-            with deadline_mod.shield():
+            # The members' trees get this span by record_into below
+            # (no context is bound on a lane's thread); here it goes
+            # onto the profiler's clock.
+            with deadline_mod.shield(), \
+                    tracing.stage("commit.group", type_="storage",
+                                  count=False):
                 results = disk.commit_group([m.op for m in live],
                                             _info=info)
         except BaseException as e:  # noqa: BLE001 - delivered per member

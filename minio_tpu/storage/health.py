@@ -218,16 +218,17 @@ class DiskHealthWrapper:
             self._half_open_probe = False
 
     def _call(self, op: str, fn, args, kwargs):
-        if tracing.ACTIVE:
-            # Every storage op becomes one span (drive + op name) —
-            # the per-drive attribution layer of the trace tree. The
-            # span covers admit + pool wait + the op itself; the
-            # engine-level span above it carries the queue-wait split.
-            with tracing.span("storage", f"disk.{op}",
-                              {"drive": str(self.endpoint
-                                            or self.root or "")}):
-                return self._call_inner(op, fn, args, kwargs)
-        return self._call_inner(op, fn, args, kwargs)
+        # Every storage op becomes one span (drive + op name) — the
+        # per-drive attribution layer of the trace tree — and one
+        # annotation on the profiler's clock. The span covers admit +
+        # pool wait + the op itself; the engine-level span above it
+        # carries the queue-wait split. No stage counter:
+        # drive_op_duration_seconds already counts the op.
+        tags = {"drive": str(self.endpoint or self.root or "")} \
+            if tracing.ACTIVE else None
+        with tracing.stage(f"disk.{op}", tags, type_="storage",
+                           count=False):
+            return self._call_inner(op, fn, args, kwargs)
 
     def _call_inner(self, op: str, fn, args, kwargs):
         # Deadline pre-check BEFORE _admit(): an already-exhausted

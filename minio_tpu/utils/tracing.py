@@ -26,6 +26,16 @@ structured record carrying its ancestry — a slow GET names the slow
 drive — into a bounded in-process ring surfaced via admin info, the
 trace stream (type unchanged, `"slow": true`), and stderr.
 
+Stages (`stage()`) are the coarse boundaries of a request's path —
+body read, frame wait, shard queue, commit, the kernel lane's upload /
+kernel / readback. One call site per boundary feeds three sinks: a
+profiler annotation when the process that took the chip installed one
+(`set_annotator`, ops/device.py — this module never imports JAX), so
+the program's spans sit on the device trace's clock; an always-on
+per-stage accumulator of wall seconds, thread CPU seconds and entries
+(`stage_totals`, exported by s3/metrics.py); and, armed with a bound
+context, the same span record `span()` writes.
+
 Environment:
   MTPU_SLOW_OP_MS      slow-op threshold in ms (0/unset = off)
   MTPU_TRACE_MAX_SPANS per-request span ring size (default 512)
@@ -40,6 +50,7 @@ import sys
 import threading
 import time
 import uuid
+import weakref
 from typing import Optional
 
 # Every trace type a span may carry; admin trace filters on these.
@@ -425,6 +436,185 @@ def op_span(type_: str, name: str, tags: Optional[dict] = None):
     if getattr(_local, "ctx", None) is not None:
         return _Span(_local.ctx, type_, name, tags)
     return _OpSpan(type_, name, tags)
+
+
+# -- stages -----------------------------------------------------------------
+# One primitive, three sinks (module docstring). The accumulator is
+# per-thread cells — a stage entry touches only its own thread's dict,
+# never a lock other request threads share — merged when somebody
+# reads the totals; the cells of threads that have exited are folded
+# into `_stage_retired` by the reader (and, so that a server nobody
+# scrapes does not keep one dict per thread that ever served, by a
+# sweep each time the registry doubles). Inside a request
+# (`request_root`) the thread's stages wait in the request's own cell
+# and join the thread's when the request ends.
+
+_annotator = None
+
+
+def set_annotator(cls) -> None:
+    """Install the profiler's annotation class: a context manager
+    built from a name (`jax.profiler.TraceAnnotation`). ops/device.py
+    calls this in the process that takes the chip; None uninstalls.
+    With one installed and no profiler session running, entering it
+    costs the profiler's own "not active" check."""
+    global _annotator
+    _annotator = cls
+
+
+_stage_mu = threading.Lock()
+_stage_cells: list = []        # [(weakref to the thread, its cells)]
+_stage_retired: dict = {}      # stage -> [wall s, cpu s, entries]
+_stage_sweep_at = 64
+
+
+def add_stage_totals(into: dict, cells: dict) -> None:
+    """into[stage] += cells[stage], field by field: how per-thread
+    cells, and per-worker states (s3/metrics.py), sum."""
+    for name, cell in tuple(cells.items()):
+        tot = into.setdefault(name, [0.0, 0.0, 0])
+        for i in range(3):
+            tot[i] += cell[i]
+
+
+def _sweep_stage_cells_locked() -> None:
+    global _stage_sweep_at
+    live = []
+    for ref, cells in _stage_cells:
+        th = ref()
+        if th is not None and th.is_alive():
+            live.append((ref, cells))
+        else:
+            add_stage_totals(_stage_retired, cells)
+    _stage_cells[:] = live
+    _stage_sweep_at = max(64, 2 * len(live))
+
+
+def _thread_stage_cells() -> dict:
+    cells = getattr(_local, "stage_cells", None)
+    if cells is None:
+        cells = _local.stage_cells = {}
+        with _stage_mu:
+            _stage_cells.append(
+                (weakref.ref(threading.current_thread()), cells))
+            if len(_stage_cells) >= _stage_sweep_at:
+                _sweep_stage_cells_locked()
+    return cells
+
+
+def stage_totals() -> dict:
+    """{stage: [wall seconds, thread CPU seconds, entries]} since the
+    process started, over every thread. A cell is written without a
+    lock by the one thread that owns it, so a total can run one entry
+    ahead in seconds of its count: skew of one entry, never a lost
+    update."""
+    with _stage_mu:
+        _sweep_stage_cells_locked()
+        out = {name: list(tot) for name, tot in _stage_retired.items()}
+        live = [cells for _, cells in _stage_cells]
+    for cells in live:
+        add_stage_totals(out, cells)
+    return out
+
+
+class _Stage:
+    __slots__ = ("_name", "_type", "_tags", "_count", "_ann", "_span",
+                 "_t0", "_c0")
+
+    def __init__(self, name, tags, type_, count):
+        self._name = name
+        self._type = type_
+        self._tags = tags
+        self._count = count
+        self._ann = None
+        self._span = None
+
+    def tag(self, **kv):
+        if self._span is not None:
+            self._span.tag(**kv)
+
+    def __enter__(self):
+        ann = _annotator
+        if ann is not None:
+            self._ann = ann(self._name)
+            self._ann.__enter__()
+        if ACTIVE:
+            ctx = getattr(_local, "ctx", None)
+            if ctx is not None:
+                self._span = _Span(ctx, self._type, self._name, self._tags)
+                self._span.__enter__()
+        if self._count:
+            self._c0 = time.thread_time()
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._count:
+            wall = time.perf_counter() - self._t0
+            cpu = time.thread_time() - self._c0
+            cells = getattr(_local, "request_cells", None)
+            if cells is None:
+                cells = _thread_stage_cells()
+            cell = cells.get(self._name)
+            if cell is None:
+                cell = cells[self._name] = [0.0, 0.0, 0]
+            cell[0] += wall
+            cell[1] += cpu
+            cell[2] += 1
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+class _RequestRoot:
+    __slots__ = ("_ann", "_outer")
+
+    def __init__(self, name):
+        ann = _annotator
+        self._ann = ann(name) if ann is not None else None
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._outer = getattr(_local, "request_cells", None)
+        _local.request_cells = {}
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        cells, _local.request_cells = _local.request_cells, self._outer
+        add_stage_totals(_thread_stage_cells() if self._outer is None
+                         else self._outer, cells)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def request_root(name: str):
+    """The whole request, entered by the server around its handler: a
+    profiler annotation of that name (no counter, no span — the
+    server publishes the request's record itself, and
+    `api_request_duration_seconds` counts its seconds), and a cell
+    that holds the stage seconds of this thread until the request
+    ends. Credited then, beside the request's own seconds, a ratio of
+    stage seconds to request seconds covers the same requests however
+    many are in flight when it is read."""
+    return _RequestRoot(name)
+
+
+def stage(name: str, tags: Optional[dict] = None, type_: str = "s3",
+          count: bool = True):
+    """One boundary of a request's path, entered as a context manager:
+    a profiler annotation of the same name when an annotator is
+    installed; wall seconds, thread CPU seconds and one entry in the
+    per-stage accumulator (always on; `count=False` for boundaries
+    another series already counts, e.g. per-drive ops); and, armed
+    with a context bound, the span `span(type_, name, tags)` records.
+    With nothing to feed it is the shared no-op."""
+    if not count and _annotator is None and not ACTIVE:
+        return NOOP
+    return _Stage(name, tags, type_, count)
 
 
 def record(type_: str, name: str, start_wall: float, duration_ms: float,

@@ -1,0 +1,433 @@
+"""The stage primitive (utils/tracing.stage) and its three sinks: the
+always-on per-stage accumulator and its /metrics export, the
+request-scoped span tree, and the profiler annotation that puts the
+program's stages on the device trace's clock — with a fake annotator
+and with the real jax.profiler on the CPU backend."""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from minio_tpu.object import erasure_object as eo
+from minio_tpu.object.erasure_object import ErasureSet
+from minio_tpu.s3.server import S3Server
+from minio_tpu.storage.health import wrap_disks
+from minio_tpu.storage.local import LocalStorage
+from minio_tpu.utils import tracing
+from tests.s3client import S3Client
+
+MiB = 1 << 20
+REQUEST_STAGES = ("put.prepare", "put.writers_start", "put.body_read",
+                  "put.frame", "put.md5", "put.shard_enqueue",
+                  "put.shard_drain", "put.commit")
+
+
+def totals_since(before: dict) -> dict:
+    """What the process-wide accumulator gained since `before`."""
+    out = {}
+    for name, (wall, cpu, n) in tracing.stage_totals().items():
+        w0, c0, n0 = before.get(name, (0.0, 0.0, 0))
+        if n > n0:
+            out[name] = (wall - w0, cpu - c0, n - n0)
+    return out
+
+
+class FakeAnnotator:
+    """Stands where jax.profiler.TraceAnnotation stands: built from a
+    name, entered and left. Keeps every event, with its thread."""
+
+    events: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        FakeAnnotator.events.append(
+            ("enter", self.name, threading.get_ident(), time.perf_counter()))
+        return self
+
+    def __exit__(self, *exc):
+        FakeAnnotator.events.append(
+            ("exit", self.name, threading.get_ident(), time.perf_counter()))
+        return False
+
+
+@pytest.fixture
+def annotator():
+    prev = tracing._annotator
+    FakeAnnotator.events = []
+    tracing.set_annotator(FakeAnnotator)
+    try:
+        yield FakeAnnotator
+    finally:
+        tracing.set_annotator(prev)
+
+
+@pytest.fixture
+def small_windows(monkeypatch):
+    """Streaming PUTs in 8-block windows, so that a 16 MiB body is two
+    windows and nobody sends 64 MiB."""
+    monkeypatch.setattr(eo, "STREAM_WINDOW_BLOCKS", 8)
+    monkeypatch.setattr(eo, "STREAM_THRESHOLD", 8 * MiB)
+
+
+def make_set(tmp_path, n=4, parity=2, backend=None):
+    """Drives wrapped as the server's boot wraps them."""
+    disks = [LocalStorage(str(tmp_path / f"d{i}")) for i in range(n)]
+    for d in disks:
+        d.make_vol("bkt")
+    return ErasureSet(wrap_disks(disks), parity=parity, backend=backend)
+
+
+def body_of(nbytes: int, seed: int = 7) -> bytes:
+    return np.random.default_rng(seed).integers(
+        0, 256, size=nbytes, dtype=np.uint8).tobytes()
+
+
+# -- sink 2: the accumulator ---------------------------------------------------
+
+def test_counters_hold_wall_cpu_and_entries():
+    before = tracing.stage_totals()
+    for _ in range(3):
+        with tracing.stage("t.sleep"):
+            time.sleep(0.02)
+    with tracing.stage("t.spin"):
+        t_end = time.thread_time() + 0.02
+        while time.thread_time() < t_end:
+            pass
+    got = totals_since(before)
+    wall, cpu, n = got["t.sleep"]
+    assert n == 3
+    assert wall >= 0.06                  # never less than what was slept
+    assert cpu < wall / 2                # a sleeping thread is off the CPU
+    wall, cpu, n = got["t.spin"]
+    assert n == 1 and cpu >= 0.02 and wall >= cpu * 0.99
+
+
+def test_two_threads_lose_no_updates():
+    before = tracing.stage_totals()
+    n_threads, each = 8, 20_000
+    start = threading.Event()
+
+    def work():
+        start.wait(10)
+        for _ in range(each):
+            with tracing.stage("t.race"):
+                pass
+
+    threads = [threading.Thread(target=work) for _ in range(n_threads)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        start.set()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert totals_since(before)["t.race"][2] == n_threads * each
+
+
+def test_totals_outlive_the_threads_that_made_them():
+    before = tracing.stage_totals()
+
+    def once():
+        with tracing.stage("t.gone"):
+            pass
+
+    for _ in range(200):                 # past the registry's sweep mark
+        t = threading.Thread(target=once)
+        t.start()
+        t.join(10)
+    assert totals_since(before)["t.gone"][2] == 200
+    with tracing._stage_mu:
+        held = len(tracing._stage_cells)
+    # exited threads' cells were folded, not kept one dict each
+    assert held <= threading.active_count() + 1
+    assert totals_since(before)["t.gone"][2] == 200
+
+
+def test_nothing_to_feed_is_the_shared_noop(annotator):
+    tracing.set_annotator(None)
+    assert not tracing.ACTIVE
+    assert tracing.stage("t.free", count=False) is tracing.NOOP
+    assert tracing.stage("t.counted") is not tracing.NOOP
+    tracing.set_annotator(annotator)
+    before = tracing.stage_totals()
+    with tracing.request_root("t.root"):
+        with tracing.stage("t.uncounted", count=False):
+            pass
+    assert [e[:2] for e in annotator.events] == [
+        ("enter", "t.root"), ("enter", "t.uncounted"),
+        ("exit", "t.uncounted"), ("exit", "t.root")]
+    assert totals_since(before) == {}    # neither is counted
+
+
+def test_a_requests_stages_are_credited_when_it_ends():
+    """Inside a request root the thread's stage seconds wait in the
+    request's own cell and reach the totals when the request ends —
+    beside its api_request_duration_seconds — while another thread's
+    stages (the batcher's, the lane's) are credited at once."""
+    before = tracing.stage_totals()
+    seen = {}
+
+    def elsewhere():
+        with tracing.stage("t.lane"):
+            pass
+        seen["mid"] = totals_since(before)
+
+    with tracing.request_root("t.request"):
+        for _ in range(2):
+            with tracing.stage("t.held"):
+                time.sleep(0.01)
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join(10)
+    assert seen["mid"]["t.lane"][2] == 1 and "t.held" not in seen["mid"]
+    wall, cpu, n = totals_since(before)["t.held"]
+    assert n == 2 and wall >= 0.02 and cpu < wall
+    # outside any request a stage is credited at once, as before
+    with tracing.stage("t.held"):
+        pass
+    assert totals_since(before)["t.held"][2] == 3
+
+
+# -- sink 3: the request's span tree ---------------------------------------------
+
+def test_armed_and_bound_the_same_names_land_in_the_span_tree():
+    tracing.arm("test-stage")
+    try:
+        ctx = tracing.TraceContext()
+        with tracing.bind(ctx):
+            with tracing.stage("t.outer", {"k": 1}, type_="storage") as st:
+                st.tag(extra=2)
+                with tracing.stage("t.inner", type_="kernel", count=False):
+                    pass
+            with tracing.span("storage", "t.plain"):
+                pass
+    finally:
+        tracing.disarm("test-stage")
+    by_name = {s["name"]: s for s in ctx.spans}
+    assert set(by_name) == {"t.outer", "t.inner", "t.plain"}
+    outer, inner = by_name["t.outer"], by_name["t.inner"]
+    assert outer["parent"] == 0 and inner["parent"] == outer["span"]
+    assert outer["type"] == "storage" and inner["type"] == "kernel"
+    assert outer["tags"] == {"k": 1, "extra": 2}
+    # the record's shape is span()'s own
+    assert set(by_name["t.plain"]) <= set(outer)
+    # disarmed, or with no context bound, a stage records no span
+    with tracing.stage("t.unbound"):
+        pass
+    assert "t.unbound" not in {s["name"] for s in ctx.spans}
+
+
+# -- sink 1: the annotation, through a streaming PUT -----------------------------
+
+def test_streaming_put_enters_its_stages_in_order(tmp_path, annotator,
+                                                  small_windows):
+    es = make_set(tmp_path)
+    body = body_of(16 * MiB)
+    before = tracing.stage_totals()
+    me = threading.get_ident()
+    t0 = time.perf_counter()
+    try:
+        es.put_object("bkt", "two-windows", body)
+        wall = time.perf_counter() - t0
+        _, got = es.get_object("bkt", "two-windows")
+    finally:
+        es.close()
+    assert got == body
+    mine = [(kind, name) for kind, name, tid, _ in annotator.events
+            if tid == me and name in REQUEST_STAGES]
+    # stages of the request thread follow one another and never nest
+    assert all(mine[i][0] == "enter" and mine[i + 1] == ("exit", mine[i][1])
+               for i in range(0, len(mine), 2))
+    order = [name for kind, name in mine if kind == "enter"]
+    window = ["put.body_read", "put.frame", "put.shard_enqueue"]
+    assert [n for n in order if n != "put.md5"] == \
+        ["put.prepare", "put.writers_start"] + window * 2 \
+        + ["put.shard_drain", "put.commit"]
+    # the drive workers' spans are on the same clock: one create_file
+    # per drive from the writers, one engine.op + rename_data each
+    # from the commit's fan-out
+    names = [name for kind, name, _, _ in annotator.events
+             if kind == "enter"]
+    assert names.count("disk.create_file") == 4
+    assert names.count("disk.rename_data") == 4
+    assert names.count("engine.op") >= 4
+    got = totals_since(before)
+    assert {n: got[n][2] for n in window} == {n: 2 for n in window}
+    assert got["put.shard_drain"][2] == got["put.commit"][2] == 1
+    assert "disk.create_file" not in got and "engine.op" not in got
+    # the stages do not overlap: together no longer than the call
+    assert sum(got[n][0] for n in REQUEST_STAGES if n in got) <= wall
+
+
+# -- the export ------------------------------------------------------------------
+
+def test_metrics_export_the_stage_series(tmp_path, small_windows):
+    srv = S3Server(make_set(tmp_path), address="127.0.0.1:0")
+    srv.start()
+    reads_before = tracing.stage_totals().get("put.body_read", [0, 0, 0])[2]
+    try:
+        cli = S3Client(srv.address)
+        assert cli.request("PUT", "/bkt/o", body=body_of(9 * MiB))[0] == 200
+        cli.request("GET", "/minio/v2/metrics/cluster", sign=False)
+        st, _, text = cli.request("GET", "/minio/v2/metrics/cluster",
+                                  sign=False)
+    finally:
+        srv.stop()
+    assert st == 200
+    series = {}
+    for line in text.decode().splitlines():
+        if line.startswith("minio_tpu_stage_") or \
+                line.startswith("minio_tpu_process_"):
+            key, value = line.rsplit(" ", 1)
+            series[key] = float(value)
+    for stage in ("s3.auth", "s3.put_prepare", "put.prepare",
+                  "put.writers_start", "put.body_read", "put.frame",
+                  "put.shard_enqueue", "put.shard_drain", "put.commit"):
+        lab = f'{{stage="{stage}"}}'
+        assert series["minio_tpu_stage_entries_total" + lab] >= 1, stage
+        assert series["minio_tpu_stage_seconds_total" + lab] > 0, stage
+        assert series["minio_tpu_stage_cpu_seconds_total" + lab] >= 0
+    assert series["minio_tpu_process_cpu_seconds_total"] > 0
+    assert "minio_tpu_process_uptime_seconds" in series
+    # 9 MiB: a whole window and a tail
+    assert series['minio_tpu_stage_entries_total{stage="put.body_read"}'] \
+        == reads_before + 2
+    # uncounted: the scrape's own span is for the trace alone
+    assert 'minio_tpu_stage_entries_total{stage="s3.metrics_render"}' \
+        not in series
+
+
+def test_a_puts_stages_never_pass_its_own_seconds(tmp_path, small_windows):
+    """Stage seconds are credited when the request ends, with its
+    api_request_duration_seconds: over any interval the PUTs' stages
+    hold no more than the PUTs' seconds, in flight or not."""
+    srv = S3Server(make_set(tmp_path), address="127.0.0.1:0")
+    srv.start()
+    before = tracing.stage_totals()
+    try:
+        cli = S3Client(srv.address)
+        for i in range(3):
+            assert cli.request("PUT", f"/bkt/o{i}",
+                               body=body_of(9 * MiB))[0] == 200
+        put_s = srv.metrics.state()["latency_hist"]["PUT:object"]["sum"]
+    finally:
+        srv.stop()
+    got = totals_since(before)
+    staged = sum(got[n][0] for n in REQUEST_STAGES + ("s3.auth",
+                                                      "s3.put_prepare")
+                 if n in got)
+    assert got["put.commit"][2] == 3
+    assert 0 < staged <= put_s + 1e-5    # the sum is rounded to 1 us
+
+
+def test_worker_states_sum_into_the_fleet_series():
+    from minio_tpu.s3.metrics import Metrics
+    m = Metrics()
+    with tracing.stage("t.fleet"):
+        pass
+    st = m.state()
+    assert st["stages"]["t.fleet"][2] >= 1 and st["process_cpu_s"] > 0
+    peers = [{"metrics": {**st, "stages": {"t.fleet": [1.5, 0.5, 3]},
+                          "process_cpu_s": 2.0}},
+             {"metrics": {**st, "stages": {"t.fleet": [2.5, 1.0, 4]},
+                          "process_cpu_s": 3.0}}]
+    text = m.render(peer_states=peers)
+    assert 'minio_tpu_stage_seconds_total{stage="t.fleet"} 4.0' in text
+    assert 'minio_tpu_stage_cpu_seconds_total{stage="t.fleet"} 1.5' in text
+    assert 'minio_tpu_stage_entries_total{stage="t.fleet"} 7' in text
+    assert "minio_tpu_process_cpu_seconds_total 5.0" in text
+
+
+# -- the real profiler -----------------------------------------------------------
+
+def test_program_stages_are_in_a_jax_profiler_trace(tmp_path, monkeypatch,
+                                                    small_windows):
+    """With jax.profiler.start_trace on the CPU backend and the
+    portable framer, the trace's host plane holds the program's
+    stages: what benchmark/serve_traced.py's trace will show beside
+    the device's operations."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from minio_tpu.ops import device
+    from minio_tpu.ops.rs_device import DeviceBackend
+    monkeypatch.setenv("MTPU_BATCH_FORCE", "device")
+    # The CPU backend writes an event per XLA thunk per device: one
+    # device (the suite's virtual mesh has 8) and a geometry no other
+    # test frames (EC 4+1), since the framer is cached per geometry.
+    monkeypatch.setenv("MTPU_MESH_DEVICES", "1")
+    device.info()                        # this process holds the device,
+    tracing.set_annotator(jax.profiler.TraceAnnotation)  # as info() left it
+    es = make_set(tmp_path, n=5, parity=1, backend=DeviceBackend("auto"))
+    sb = eo._batcher_for(4, 1)
+    sb.reset_calibration()               # re-pin the cached batcher
+    trace_dir = str(tmp_path / "trace")
+    before = tracing.stage_totals()
+    try:
+        es.put_object("bkt", "warm", body_of(8 * MiB))   # compiles
+        try:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        except Exception as e:  # noqa: BLE001 - no session on this box
+            pytest.skip(f"profiler session cannot start: {e}")
+        try:
+            # one device-sized window and a 1 MiB tail: the CPU
+            # backend writes an event per XLA thunk, so keep it short
+            es.put_object("bkt", "traced", body_of(9 * MiB))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        es.close()
+        monkeypatch.delenv("MTPU_BATCH_FORCE", raising=False)
+        sb.reset_calibration()           # un-pin for suite-mates
+    got = totals_since(before)
+    lane = [got[n][2] for n in ("lane.upload", "lane.kernel",
+                                "lane.readback", "lane.rows")]
+    assert lane == [2, 2, 2, 2]          # one of each per device window
+    assert got["batcher.stage"][2] == lane[0]
+    assert "batcher.demux" not in got    # on the trace's clock, uncounted
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files
+    data = ProfileData.from_file(files[-1])
+    names = set()
+    for plane in data.planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    for want in ("put.body_read", "put.frame", "put.shard_enqueue",
+                 "put.commit", "batcher.stage", "batcher.demux",
+                 "lane.upload",
+                 "lane.kernel", "lane.readback", "lane.rows",
+                 "disk.create_file"):
+        assert want in names, (want, sorted(names)[:40])
+
+
+def test_tracing_imports_no_jax_and_the_device_owner_installs_it():
+    code = ("import sys; from minio_tpu.utils import tracing; "
+            "from minio_tpu.ops import device; "
+            "tracing.stage('x').__enter__(); "
+            "assert not device.held() and tracing._annotator is None; "
+            "assert 'jax' not in sys.modules, 'jax imported'; "
+            # the process that takes the device installs the annotator
+            "device.info(); import jax; assert device.held(); "
+            "assert tracing._annotator is jax.profiler.TraceAnnotation")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                         capture_output=True, timeout=120)
+    assert out.returncode == 0, out.stderr.decode()[-2000:]
