@@ -13,6 +13,7 @@ import os
 import threading
 import time
 
+from minio_tpu.storage import health as _health
 from minio_tpu.utils import tracing as _tracing
 from minio_tpu.utils.latency import Histogram, LastMinute, summarize
 
@@ -126,6 +127,7 @@ class Metrics:
         out["slow_ops_total"] = _tracing.slow_total
         out["stages"] = _tracing.stage_totals()
         out["process_cpu_s"] = _process_cpu_seconds()
+        out["drive_calls"] = _health.CALL_STATS.snapshot()
         return out
 
     # -- rendering -------------------------------------------------------
@@ -199,6 +201,7 @@ class Metrics:
             kst = {"queued": 0, "submitted_total": 0,
                    "service_hist": Histogram().state()}
         cpu_s = None                # this process's own, read at render
+        drive_calls = _health.CALL_STATS.snapshot()
         peer_metrics = [p["metrics"] for p in (peer_states or [])
                         if isinstance(p.get("metrics"), dict)]
         # Cluster federation: remote nodes' worker states join the
@@ -223,6 +226,7 @@ class Metrics:
             slow_total = 0
             stages = {}
             cpu_s = 0.0
+            drive_calls = dict.fromkeys(drive_calls, 0)
             hist_states: dict[str, list] = {}
             minute_states: dict[str, list] = {}
             for st in peer_metrics:
@@ -246,6 +250,8 @@ class Metrics:
                 slow_total += st.get("slow_ops_total", 0)
                 _tracing.add_stage_totals(stages, st.get("stages", {}))
                 cpu_s += st.get("process_cpu_s", 0.0)
+                for k, v in st.get("drive_calls", {}).items():
+                    drive_calls[k] = drive_calls.get(k, 0) + v
             hists = {a: Histogram.merge(sts)
                      for a, sts in hist_states.items()}
             minutes = {a: LastMinute.merge(ws)
@@ -1019,6 +1025,22 @@ class Metrics:
             metric("minio_tpu_drive_queue_wait_last_minute_seconds",
                    "Rolling last-minute queue wait before each drive op "
                    "(p50/p99/max)", "gauge", samples_lw)
+        # Below the queues, the health wrapper (storage/health.py) runs
+        # each call on a worker of its own: the wait for one should be
+        # a thread hand-off, and seconds here mean calls queue again.
+        metric("minio_tpu_drive_call_wait_seconds_sum",
+               "Seconds health-wrapped drive calls waited between "
+               "submission and their worker's first instruction",
+               "counter", [({}, round(drive_calls["wait_seconds"], 6))])
+        metric("minio_tpu_drive_call_wait_seconds_count",
+               "Health-wrapped drive calls handed to a worker",
+               "counter", [({}, drive_calls["calls"])])
+        metric("minio_tpu_drive_call_workers",
+               "Drive-call worker threads alive", "gauge",
+               [({}, drive_calls["workers"])])
+        metric("minio_tpu_drive_call_workers_started_total",
+               "Drive-call worker threads started", "counter",
+               [({}, drive_calls["workers_started"])])
 
         # -- read path: quorum-fileinfo cache + fused GET kernel --------
         # Hit rate says whether repeat GETs skip the k-drive metadata

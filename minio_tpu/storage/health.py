@@ -11,6 +11,17 @@ plain error handling never catches.
 
 Domain errors (missing files/volumes, corrupt journals) are the
 storage layer working CORRECTLY and never count against the drive.
+
+Every call runs off the caller's thread, so that the caller can give up
+on it at its deadline and leave it to finish unobserved. It is handed to
+a worker at once — an idle one, or a new one (_DaemonPool) — and never
+queues for one: a call that legitimately holds its worker for seconds
+(the streaming PUT's create_file, parked on its request's next window)
+must not stand in front of another request's rename_data or stat_vol.
+No fixed number bounds the workers. The calls in flight do: the request
+admission above the object layer bounds those on a healthy drive, and
+on a hung one the breaker does — trip_after timed-out calls open it, and
+from then on _admit() fails fast before any worker is asked for.
 """
 
 from __future__ import annotations
@@ -21,6 +32,7 @@ import time
 from concurrent.futures import Future
 from concurrent.futures import TimeoutError as FutureTimeout
 
+from minio_tpu.io.engine import IDLE_EXIT_S
 from minio_tpu.storage.local import (DiskAccessDenied, FaultyDisk,
                                      VolumeExists, VolumeNotEmpty,
                                      VolumeNotFound)
@@ -50,38 +62,118 @@ _BULK_OPS = {"create_file", "read_file", "rename_data", "commit_group"}
 _GENERATOR_OPS = {"walk_dir", "walk_scan"}
 
 
-class _DaemonPool:
-    """Minimal executor with DAEMON workers: a call hung on dead storage
-    must never block interpreter shutdown (ThreadPoolExecutor joins its
-    workers at exit)."""
+class _CallStats:
+    """Process-wide totals of the pools below, all drives together:
+    what `minio_tpu_drive_call_*` exports (s3/metrics.py)."""
 
-    def __init__(self, workers: int):
+    def __init__(self):
+        self._mu = threading.Lock()
+        self.wait_seconds = 0.0     # submit -> worker's first instruction
+        self.calls = 0
+        self.workers = 0            # alive now
+        self.workers_started = 0
+
+    def worker(self, delta: int) -> None:
+        with self._mu:
+            self.workers += delta
+            if delta > 0:
+                self.workers_started += delta
+
+    def waited(self, seconds: float) -> None:
+        with self._mu:
+            self.wait_seconds += seconds
+            self.calls += 1
+
+    def snapshot(self) -> dict:
+        with self._mu:
+            return {"wait_seconds": self.wait_seconds, "calls": self.calls,
+                    "workers": self.workers,
+                    "workers_started": self.workers_started}
+
+
+CALL_STATS = _CallStats()
+
+
+class _DaemonPool:
+    """Executor whose jobs never wait for a worker: `submit` hands the
+    job to an idle worker if there is one, else starts one; a worker
+    idle for IDLE_EXIT_S exits. So the workers alive equal the calls in
+    flight (plus a short idle tail), and a call parked in its worker for
+    seconds — a streaming create_file waiting for its request's next
+    window — delays nobody else's rename or lookup.
+
+    Workers are DAEMON threads: a call hung on dead storage must never
+    block interpreter shutdown (ThreadPoolExecutor joins its workers at
+    exit)."""
+
+    def __init__(self):
         self._q: "queue_mod.SimpleQueue" = queue_mod.SimpleQueue()
-        self._threads = [threading.Thread(target=self._work, daemon=True)
-                         for _ in range(workers)]
-        for t in self._threads:
-            t.start()
+        self._mu = threading.Lock()
+        self._idle = 0      # workers in get() that no submit has claimed
+        self._closed = False
 
     def submit(self, fn, *args, **kwargs) -> Future:
         f: Future = Future()
-        self._q.put((f, fn, args, kwargs))
+        job = (f, fn, args, kwargs, time.perf_counter())
+        with self._mu:
+            claimed = self._idle > 0
+            if claimed:
+                self._idle -= 1
+        if claimed:
+            self._q.put(job)
+            return f
+        try:
+            # The new worker takes this job as its first: nothing that
+            # is already waiting on the queue can take it instead.
+            threading.Thread(target=self._work, args=(job,), daemon=True,
+                             name="drive-call").start()
+        except RuntimeError as e:   # the process is out of threads
+            f.set_exception(e)
         return f
 
-    def _work(self) -> None:
+    def _work(self, job) -> None:
+        CALL_STATS.worker(+1)
+        try:
+            while job is not None:
+                self._run(job)
+                job = self._next()
+        finally:
+            CALL_STATS.worker(-1)
+
+    @staticmethod
+    def _run(job) -> None:
+        f, fn, args, kwargs, t_sub = job
+        CALL_STATS.waited(time.perf_counter() - t_sub)
+        if not f.set_running_or_notify_cancel():
+            return
+        try:
+            f.set_result(fn(*args, **kwargs))
+        except BaseException as e:  # noqa: BLE001 - ferried to caller
+            f.set_exception(e)
+
+    def _next(self):
+        """The next job, or None when this worker should exit (closed,
+        or idle for IDLE_EXIT_S with no submit having claimed it)."""
+        with self._mu:
+            if self._closed:
+                return None
+            self._idle += 1
         while True:
-            item = self._q.get()
-            if item is None:
-                return
-            f, fn, args, kwargs = item
-            if not f.set_running_or_notify_cancel():
-                continue
             try:
-                f.set_result(fn(*args, **kwargs))
-            except BaseException as e:  # noqa: BLE001 - ferried to caller
-                f.set_exception(e)
+                return self._q.get(timeout=IDLE_EXIT_S)
+            except queue_mod.Empty:
+                with self._mu:
+                    if self._idle > 0:
+                        self._idle -= 1
+                        return None
+                # Every waiting worker is claimed, this one included:
+                # a job is on its way to the queue.
 
     def shutdown(self) -> None:
-        for _ in self._threads:
+        with self._mu:
+            self._closed = True
+            idle, self._idle = self._idle, 0
+        for _ in range(idle):
             self._q.put(None)
 
 
@@ -114,9 +206,11 @@ class DiskHealthWrapper:
         self._clamped_streak = 0
         # op -> [count, errors, total_seconds]; small and bounded.
         self.op_stats: dict[str, list] = {}
-        # A hung call occupies a worker until it returns; the breaker
-        # stops new submissions long before the pool exhausts.
-        self._pool = _DaemonPool(workers=8)
+        # A hung call keeps its worker until it returns, and the pool
+        # starts another for the next call: the breaker, not the pool,
+        # bounds the threads on a hung drive (trip_after faults open it
+        # and _admit() fails fast; module docstring).
+        self._pool = _DaemonPool()
 
     # -- introspection ---------------------------------------------------
 
@@ -221,8 +315,8 @@ class DiskHealthWrapper:
         # Every storage op becomes one span (drive + op name) — the
         # per-drive attribution layer of the trace tree — and one
         # annotation on the profiler's clock. The span covers admit +
-        # pool wait + the op itself; the engine-level span above it
-        # carries the queue-wait split. No stage counter:
+        # the hand-off to a worker + the op itself; the engine-level
+        # span above it carries the queue-wait split. No stage counter:
         # drive_op_duration_seconds already counts the op.
         tags = {"drive": str(self.endpoint or self.root or "")} \
             if tracing.ACTIVE else None
