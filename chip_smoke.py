@@ -28,6 +28,11 @@ with no --ec-backend at all: the default (`auto`) must find the chip
 through its probe child, own it alone and label itself truthfully.
 Every phase ends in a SIGTERM that must exit 0 and leave the drives
 stamped clean — phase A's with a background heal in flight.
+
+On a four-chip host the same run is the mesh framer's bring-up proof
+(`mesh_devices: 4` in the report, bytes identical) and nothing more:
+how fast the mesh serves is the benchmark's cell
+`ec8p4-12d-4chip.put-64m`, not this script.
 """
 
 from __future__ import annotations

@@ -351,10 +351,11 @@ class StripeBatcher:
         stacked = np.zeros(
             (_bucket(max(self._min_device_blocks, self.mesh_devices)),)
             + sample.shape[1:], dtype=np.uint8)
-        self._device_fn(stacked)               # compile
-        t0 = time.perf_counter()
-        self._device_fn(stacked)
-        t_dev = time.perf_counter() - t0
+        with device.batch_of(0):               # zeros: all padding
+            self._device_fn(stacked)           # compile
+            t0 = time.perf_counter()
+            self._device_fn(stacked)
+            t_dev = time.perf_counter() - t0
         t0 = time.perf_counter()
         self._host_fn(stacked)
         t_host = time.perf_counter() - t0
@@ -718,14 +719,20 @@ class StripeBatcher:
             stacked[off:] = 0
         return lease, stacked
 
-    def _lane_dispatch(self, stacked: np.ndarray):
+    def _lane_dispatch(self, stacked: np.ndarray, real: int):
         """Run the device framer through the process-wide kernel lane
         (serialized device access + wait/service attribution); falls
-        back to a direct call if the lane is saturated or closed."""
+        back to a direct call if the lane is saturated or closed. The
+        first `real` rows of `stacked` are the members', the rest
+        bucket padding: said to the device function on the thread that
+        calls it (ops/device.batch_of)."""
+        def call():
+            with device.batch_of(real):
+                return self._device_fn(stacked)
         try:
-            fut = kernel_lane().submit(lambda: self._device_fn(stacked))
+            fut = kernel_lane().submit(call)
         except EngineSaturated:
-            return self._device_fn(stacked)
+            return call()
         return fut.result()
 
     def _run_batch(self, batch: list[_Pending]) -> None:
@@ -766,7 +773,7 @@ class StripeBatcher:
                     lease, stacked = self._stage(live, bucket)
                 t_lane = time.perf_counter()
                 try:
-                    rows_all = self._lane_dispatch(stacked)
+                    rows_all = self._lane_dispatch(stacked, total)
                 finally:
                     # The dispatch is synchronous through the readback
                     # (the framer returns host numpy), so the staging

@@ -19,8 +19,10 @@ this module so no call site can get them wrong:
     (`required()`).
   * Nothing that ran instead of the TPU kernel goes uncounted:
     `note_kernel()` records which implementation served each dispatch
-    (`pallas` | `xla` | `interpret`) and `record_fault()` counts and
-    logs, with its traceback, every exception a device call raised.
+    (`pallas` | `xla` | `interpret`), `note_mesh_blocks()` which chip
+    of a mesh got how much of a batch and how much of that was
+    padding, and `record_fault()` counts and logs, with its traceback,
+    every exception a device call raised.
 
 JAX is imported lazily: `erasure/codec.py` reaches `ops/gf256.py`
 through this package, and the host codec, the pre-forked host-codec
@@ -29,6 +31,7 @@ workers and chip_smoke.py's parent must stay JAX-free.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import subprocess
@@ -189,6 +192,7 @@ def required() -> bool:
 
 _mu = threading.Lock()
 _kernel_calls: dict[tuple[str, str], int] = {}
+_mesh_blocks: dict[tuple[int, str], int] = {}
 _faults: dict[str, int] = {}
 _last_fault = ""
 _FAULT_LOG_MAX = 20       # tracebacks printed per process; all counted
@@ -200,6 +204,39 @@ def note_kernel(kernel: str, impl: str) -> None:
     with _mu:
         key = (kernel, impl)
         _kernel_calls[key] = _kernel_calls.get(key, 0) + 1
+
+
+_batch = threading.local()
+
+
+@contextlib.contextmanager
+def batch_of(real_blocks: int):
+    """The batcher's word to the device function it calls inside, on
+    this thread: of the batch handed over, the first `real_blocks` rows
+    carry clients' data and the rest is bucket padding (the
+    calibration probe's batch of zeros is all padding)."""
+    _batch.real = real_blocks
+    try:
+        yield
+    finally:
+        _batch.real = None
+
+
+def note_mesh_blocks(blocks: int, chips: int) -> None:
+    """One dispatch of `blocks` rows cut evenly, in order, over `chips`
+    chips of a mesh (`P("stripe")`): per chip, how many rows of its
+    slice were real and how many padding. The real rows come first, so
+    padding lands on the last chips. Without the batcher's word
+    (`batch_of`) every row counts as real."""
+    real = getattr(_batch, "real", None)
+    real = blocks if real is None else min(real, blocks)
+    per_chip = blocks // chips
+    with _mu:
+        for chip in range(chips):
+            r = min(max(real - chip * per_chip, 0), per_chip)
+            for kind, v in (("real", r), ("pad", per_chip - r)):
+                _mesh_blocks[chip, kind] = _mesh_blocks.get(
+                    (chip, kind), 0) + v
 
 
 def record_fault(site: str, exc: BaseException) -> None:
@@ -223,6 +260,8 @@ def stats() -> dict:
     with _mu:
         return {"kernel_calls": {f"{k}/{i}": v for (k, i), v
                                  in sorted(_kernel_calls.items())},
+                "mesh_blocks": {f"{c}/{kind}": v for (c, kind), v
+                                in sorted(_mesh_blocks.items())},
                 "faults": dict(_faults),
                 "last_fault": _last_fault,
                 "required": _required}

@@ -1029,6 +1029,7 @@ def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
         b, _, l = data.shape
         assert b % ndev == 0, \
             f"batch {b} not divisible by {ndev}-chip mesh (pad buckets)"
+        device.note_mesh_blocks(b, ndev)
         pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
         if on_tpu and l % 1024 == 0 and pchunk >= 8:
             device.note_kernel("frame", "pallas")

@@ -853,6 +853,15 @@ class Metrics:
                "(pallas|xla|interpret)", "counter",
                [({"kernel": ki.split("/")[0], "impl": ki.split("/")[1]},
                  v) for ki, v in sorted(dev["kernel_calls"].items())])
+        if dev.get("mesh_blocks"):
+            # Written by the mesh framer alone: absent on one device.
+            metric("minio_tpu_mesh_blocks_total",
+                   "Erasure blocks of each chip's slice of the mesh "
+                   "framer's batches that carried a client's data "
+                   "(real) and that were bucket padding (pad)",
+                   "counter",
+                   [({"chip": ck.split("/")[0], "kind": ck.split("/")[1]},
+                     v) for ck, v in dev["mesh_blocks"].items()])
         metric("minio_tpu_device_errors_total",
                "Exceptions raised by device calls, by site "
                "(probe:<route>|dispatch:<route>|framed_digests)",
