@@ -41,6 +41,20 @@ and never holds a member past its request deadline (members whose
 budget is already spent fail alone — they are culled before dispatch
 and cannot poison batch-mates).
 
+The dispatcher thread never waits for the lane. A dispatch is three
+steps — stage (copy the members into the staging buffer), submit (the
+device call, on the kernel lane's own thread) and finish (lease back,
+rows demultiplexed, members released) — and the dispatcher does the
+first two and goes back for the next batch, while a finisher thread of
+its own does the third the moment the rows are back. The depth is two
+by construction: one batch in the lane, one being staged; before it
+submits, the dispatcher waits for its previous batch's lane call to
+have returned (the counted stage `batcher.lane_wait`: what it still
+waits for the device). So the cycle of a loaded dispatcher is
+max(stage, lane) instead of their sum, results come back in order, and
+at most two staging buffers are alive. A lone window (frame()'s solo
+path) runs the three steps on its own thread, one after the other.
+
 Every batched dispatch is also one `kernel` span FANNED into each
 member request's span tree (utils/tracing.record_into): a traced PUT
 shows the shared dispatch it rode — batch size, bucket, mesh width,
@@ -89,9 +103,11 @@ Environment:
 from __future__ import annotations
 
 import os
+import queue as queue_mod
 import threading
 import time
 import weakref
+from concurrent.futures import Future
 from typing import Callable, Optional
 
 import numpy as np
@@ -186,7 +202,32 @@ class _Pending:
         self.tctx, self.tparent = tracing.capture() if tracing.ACTIVE \
             else (None, 0)
         self.t_enq = time.perf_counter()
-        self.route_taken = "host"      # resolved by _run_batch
+        self.route_taken = "host"      # resolved by _finish
+
+
+class _Batch:
+    """One dispatch, from the members the dispatcher took to their
+    release: what staging, the lane call and the finish hand on to each
+    other, on whichever threads they run."""
+    __slots__ = ("live", "total", "bucket", "route", "t_wall", "t0",
+                 "lease", "stacked", "t_lane", "overlapped", "done")
+
+    def __init__(self, live: list, total: int, bucket: int, route: str):
+        self.live = live
+        self.total = total
+        self.bucket = bucket
+        self.route = route
+        self.t_wall = time.time()
+        self.t0 = time.perf_counter()
+        self.lease = None
+        self.stacked = None
+        self.t_lane = 0.0
+        # Staging began while the dispatcher's previous batch was still
+        # in the lane.
+        self.overlapped = False
+        # Set when the finish is over: the lane call has returned, the
+        # staging lease is back and every member is released.
+        self.done = threading.Event()
 
 
 # Live batchers, for fleet-wide occupancy metrics (s3/metrics.py
@@ -200,6 +241,7 @@ ROUTES = ("put", "get", "reconstruct", "transform")
 def _route_zero() -> dict:
     return {
         "dispatches": {"device": 0, "host": 0},
+        "overlapped": 0,
         "requests": {"device": 0, "host": 0, "bypass": 0},
         "buckets": {},
         "batched_blocks": 0,
@@ -234,6 +276,7 @@ def aggregate_stats() -> dict:
         agg = out["routes"].setdefault(route, _route_zero())
         for key in ("device", "host"):
             agg["dispatches"][key] += st["dispatches"][key]
+        agg["overlapped"] += st["overlapped"]
         for key in ("device", "host", "bypass"):
             agg["requests"][key] += st["requests"][key]
         for b, v in st["buckets"].items():
@@ -298,6 +341,9 @@ class StripeBatcher:
         self._deadline = 0.0            # current window's dispatch-by time
         self._inflight = 0              # frame() calls currently active
         self._dispatcher: Optional[threading.Thread] = None
+        # The dispatcher's last submitted batch: the next one is staged
+        # while this one is in the lane, and submitted once it is back.
+        self._in_lane: Optional[_Batch] = None
         self._closed = False
         # Calibration: None = unknown (host until probed), True/False.
         self._device_ok: Optional[bool] = None
@@ -318,6 +364,9 @@ class StripeBatcher:
         # moments hot paths want to count).
         self._stat_mu = threading.Lock()
         self._dispatches = {"device": 0, "host": 0}
+        # Device dispatches whose staging began while the batch before
+        # was still in the lane.
+        self._overlapped = 0
         self._requests = {"device": 0, "host": 0, "bypass": 0}
         # Calibrated-host bypass count: bumped WITHOUT _stat_mu on the
         # zero-overhead pass-through, folded into stats() reads.
@@ -487,6 +536,7 @@ class StripeBatcher:
                 "route": self.route,
                 "mesh_devices": self.mesh_devices,
                 "dispatches": dict(self._dispatches),
+                "overlapped": self._overlapped,
                 "requests": requests,
                 "buckets": dict(self._bucket_dispatches),
                 "batched_blocks": self._batched_blocks,
@@ -633,6 +683,20 @@ class StripeBatcher:
             self._cur_wait = max(_MIN_WAIT_S, self._cur_wait * 0.5)
 
     def _dispatch_loop(self) -> None:
+        # The dispatcher never waits for the lane: it stages a batch,
+        # submits it and comes back for the next, and its finisher
+        # (alive as long as it is) releases each batch's members when
+        # that batch's rows are back.
+        finishes: queue_mod.SimpleQueue = queue_mod.SimpleQueue()
+        threading.Thread(target=self._finish_loop, args=(finishes,),
+                         daemon=True,
+                         name="stripe-batcher-finish").start()
+        try:
+            self._dispatch_batches(finishes)
+        finally:
+            finishes.put(None)
+
+    def _dispatch_batches(self, finishes: queue_mod.SimpleQueue) -> None:
         while True:
             with self._mu:
                 while not self._pending and not self._closed:
@@ -685,7 +749,7 @@ class StripeBatcher:
                 self._pending = rest
                 if rest:
                     self._deadline = now      # no extra wait for them
-            self._run_batch(batch)
+            self._dispatch(batch, finishes)
 
     def _stage(self, live: list[_Pending], bucket: int):
         """(lease, stacked [bucket, k, L]): members copied into ONE
@@ -719,23 +783,42 @@ class StripeBatcher:
             stacked[off:] = 0
         return lease, stacked
 
-    def _lane_dispatch(self, stacked: np.ndarray, real: int):
-        """Run the device framer through the process-wide kernel lane
-        (serialized device access + wait/service attribution); falls
-        back to a direct call if the lane is saturated or closed. The
-        first `real` rows of `stacked` are the members', the rest
-        bucket padding: said to the device function on the thread that
-        calls it (ops/device.batch_of)."""
-        def call():
-            with device.batch_of(real):
-                return self._device_fn(stacked)
+    def _launch(self, b: _Batch, prev: Optional[_Batch] = None) -> Future:
+        """Stage `b` and hand its device call to the process-wide kernel
+        lane (serialized device access + wait/service attribution; a
+        direct call if the lane is saturated or closed). The Future
+        holds the device rows, or whatever raised on the way there,
+        the copy included. `prev` is the dispatcher's previous batch:
+        its lane call must have returned before this one is submitted,
+        so one batch of this batcher is in the lane and one being
+        staged, never more — two staging buffers alive at most, and
+        `_lane_hist` never times a wait behind our own batch."""
+        fut: Future = Future()
         try:
-            fut = kernel_lane().submit(call)
-        except EngineSaturated:
-            return call()
-        return fut.result()
+            with tracing.stage("batcher.stage", type_="kernel"):
+                b.lease, b.stacked = self._stage(b.live, b.bucket)
+            if prev is not None:
+                with tracing.stage("batcher.lane_wait", type_="kernel"):
+                    prev.done.wait()
 
-    def _run_batch(self, batch: list[_Pending]) -> None:
+            def call():
+                # The first `total` rows are the members', the rest
+                # bucket padding: said to the device function on the
+                # thread that calls it (ops/device.batch_of).
+                with device.batch_of(b.total):
+                    return self._device_fn(b.stacked)
+            b.t_lane = time.perf_counter()
+            try:
+                return kernel_lane().submit(call)
+            except EngineSaturated:
+                fut.set_result(call())
+        except BaseException as e:  # noqa: BLE001 - _finish delivers it
+            fut.set_exception(e)
+        return fut
+
+    def _open(self, batch: list[_Pending]) -> Optional[_Batch]:
+        """The batch's live members with its bucket and route; None when
+        nobody is left to serve."""
         # Cull members whose budget is already spent: they fail ALONE
         # (DeadlineExceeded, counted) and never poison batch-mates —
         # the dispatch proceeds without them.
@@ -755,30 +838,76 @@ class StripeBatcher:
                     "request deadline exceeded before batch dispatch")
                 p.event.set()
         if not live:
-            return
-        counts = [p.count for p in live]
-        total = sum(counts)
+            return None
+        total = sum(p.count for p in live)
         # Never pick a bucket narrower than the mesh: the device run()
         # requires batch % mesh_devices == 0, and small dispatches on a
         # wide mesh (e.g. 8 blocks across 16 chips) would otherwise
         # fail every batch member.
         bucket = _bucket(max(total, self.mesh_devices))
-        route = "host"
-        t_wall = time.time()
-        t0 = time.perf_counter()
+        route = "device" if total >= self._min_device_blocks \
+            and self._device_ok else "host"
+        return _Batch(live, total, bucket, route)
+
+    def _run_batch(self, batch: list[_Pending]) -> None:
+        """One batch from start to finish on the calling thread (a lone
+        window of frame())."""
+        b = self._open(batch)
+        if b is not None:
+            fut = self._launch(b) if b.route == "device" else None
+            self._finish(b, fut)
+
+    def _dispatch(self, batch: list[_Pending],
+                  finishes: queue_mod.SimpleQueue) -> None:
+        """The dispatcher's share of one batch: stage it while the
+        previous one is in the lane, submit it, and go back for the
+        next — the finish runs on the finisher when the rows are back."""
+        b = self._open(batch)
+        if b is None:
+            return
+        if b.route != "device":
+            self._finish(b, None)
+            return
+        prev = self._in_lane
+        b.overlapped = prev is not None and not prev.done.is_set()
+        fut = self._launch(b, prev)
+        self._in_lane = b
+        finishes.put((b, fut))
+
+    def _finish_loop(self, finishes: queue_mod.SimpleQueue) -> None:
+        """Finish the dispatcher's batches in the order their lane calls
+        return (the lane is one FIFO worker), so members leave the
+        moment their rows are back and not after the next batch's copy."""
+        while True:
+            item = finishes.get()
+            if item is None:
+                return
+            self._finish(*item)
+            # The Future's rows view the batch's staging buffer: not to
+            # be kept alive while this thread waits for the next batch.
+            del item
+
+    def _finish(self, b: _Batch, fut: Optional[Future]) -> None:
+        """Wait for the batch's lane call (`fut`; None on the host
+        route), give the staging buffer back, demultiplex the rows to
+        the members, count, and release them."""
+        live, total, bucket, route = b.live, b.total, b.bucket, b.route
+        counts = [p.count for p in live]
         try:
-            if total >= self._min_device_blocks and self._device_ok:
-                route = "device"
-                with tracing.stage("batcher.stage", type_="kernel"):
-                    lease, stacked = self._stage(live, bucket)
-                t_lane = time.perf_counter()
+            if fut is not None:
                 try:
-                    rows_all = self._lane_dispatch(stacked, total)
+                    rows_all = fut.result()
                 finally:
                     # The dispatch is synchronous through the readback
                     # (the framer returns host numpy), so the staging
                     # buffer is done feeding HBM here — and not before.
-                    self._lane_hist.observe(time.perf_counter() - t_lane)
+                    if b.t_lane:
+                        self._lane_hist.observe(
+                            time.perf_counter() - b.t_lane)
+                    # Let go of the buffer here too: `_in_lane` keeps
+                    # the batch until the next one is submitted, and a
+                    # mapping of a whole bucket must not live that long.
+                    lease, b.lease, b.stacked = b.lease, None, None
                     if lease is not None:
                         lease.release()
                 with tracing.stage("batcher.demux", type_="kernel",
@@ -812,6 +941,7 @@ class StripeBatcher:
                             off += c
                 with self._stat_mu:
                     self._dispatches["device"] += 1
+                    self._overlapped += b.overlapped
                     self._requests["device"] += len(live)
                     self._bucket_dispatches[bucket] = \
                         self._bucket_dispatches.get(bucket, 0) + 1
@@ -836,24 +966,34 @@ class StripeBatcher:
             for p in live:
                 p.exc = e
         finally:
-            dur_ms = (time.perf_counter() - t0) * 1000.0
+            dur_ms = (time.perf_counter() - b.t0) * 1000.0
             for p in live:
                 p.route_taken = route
-                wait_s = max(0.0, t0 - p.t_enq)
+                wait_s = max(0.0, b.t0 - p.t_enq)
                 self._wait_hist.observe(wait_s)
                 if p.tctx is not None:
                     # ONE kernel span fanned into each member's tree.
                     tracing.record_into(
                         p.tctx, p.tparent, "kernel", "batcher.dispatch",
-                        t_wall, dur_ms,
+                        b.t_wall, dur_ms,
                         tags={"blocks": p.count, "batch_blocks": total,
                               "bucket": bucket, "members": len(live),
                               "route": route,
                               "mesh_devices": self.mesh_devices,
                               "wait_ms": round(wait_s * 1000.0, 3)})
                 p.event.set()
+            b.done.set()
 
     def close(self) -> None:
+        """Stop accumulating: what is pending dispatches at once, and
+        the call returns when the dispatcher has gone and its last
+        batch has delivered."""
         with self._mu:
             self._closed = True
             self._mu.notify_all()
+            dispatcher = self._dispatcher
+        if dispatcher is not None:
+            dispatcher.join()
+        last = self._in_lane
+        if last is not None:
+            last.done.wait()

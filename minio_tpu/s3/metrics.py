@@ -791,6 +791,10 @@ class Metrics:
                "(put|get|reconstruct) and resolved path", "counter",
                [({"route": r, "path": p}, v) for r, st in routes
                 for p, v in sorted(st["dispatches"].items())])
+        metric("minio_tpu_batcher_overlapped_dispatches_total",
+               "Device dispatches staged while the dispatcher's "
+               "previous batch was still in the kernel lane", "counter",
+               [({"route": r}, st["overlapped"]) for r, st in routes])
         metric("minio_tpu_batcher_requests_total",
                "Stripe windows routed through the batcher by route "
                "(bypass = calibrated host pass-through)", "counter",

@@ -366,6 +366,41 @@ def test_force_device_engages_batcher_off_tpu(monkeypatch, tmp_path):
     assert sb._device_ok is None
 
 
+def test_mesh_batches_overlap_under_the_mesh_bucket_rule():
+    """The pipelined dispatcher on a mesh (the mesh framer over four of
+    the suite's virtual devices): batch N+1 is staged while N is in
+    the lane, both are whole multiples of the mesh at the mesh's fill
+    target, and their rows are the host codec's byte for byte."""
+    import jax
+    from minio_tpu.ops import gf256
+    from minio_tpu.ops.hh_device import make_mesh_framer
+    from tests import batcher_rig as rig
+    framer = make_mesh_framer(gf256.parity_matrix(rig.K, rig.M),
+                              devices=jax.devices()[:4])
+    seen = []
+
+    def mesh(stacked):
+        seen.append(stacked.shape[0])
+        return framer(stacked)
+    mesh.mesh_devices = framer.mesh_devices
+    with rig.Rig("put", hold_dev=[0], device_fn=mesh) as r:
+        assert r.sb.mesh_devices == 4 and r.sb._fill_target() == 128
+        first = r.send(seed=100)
+        rig.wait(r.dev.entered[0], "N in the lane")
+        second = r.send(seed=200)
+        rig.wait(r.stage.left[1], "N+1 staged")
+        assert not r.dev.left[0].is_set()
+        r.dev.go[0].set()
+        for batch in (first, second):
+            for m, want in zip(batch, r.synchronous(batch)):
+                assert m.returned().exc is None
+                rig.same("put", m.rows, want)
+        st = r.sb.stats()
+        assert seen == [128, 128]
+        assert st["dispatches"]["device"] == 2 and st["overlapped"] == 1
+        assert max(r.outstanding) == 2
+
+
 _MESH_BODY = r"""
 import numpy as np
 from minio_tpu.object.erasure_object import _host_rows

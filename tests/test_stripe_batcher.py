@@ -192,3 +192,170 @@ def test_host_rows_matches_framer_format():
     from minio_tpu.object.erasure_object import _framer_for
     w = _mk_window(3, 7)
     _rows_equal(_host_rows(K, M, w), _framer_for(K, M)(w))
+
+
+# -- the dispatcher stages the next batch while the last one is in the
+# -- lane (tests/batcher_rig.py: events only, no clock) -----------------
+
+from minio_tpu.utils.deadline import DeadlineExceeded  # noqa: E402
+from tests import batcher_rig as rig  # noqa: E402
+
+routes = pytest.mark.parametrize("route", rig.ROUTES)
+
+
+@routes
+def test_next_batch_is_staged_while_the_last_is_in_the_lane(route):
+    """(a) Batch N+1's copy runs while N's device call has not
+    returned, and N's members are home before N+1's staging ends."""
+    with rig.Rig(route, hold_dev=[0], hold_stage=[1]) as r:
+        first = r.send(seed=10)
+        rig.wait(r.dev.entered[0], "N in the lane")
+        second = r.send(seed=20)
+        rig.wait(r.stage.entered[1], "N+1's copy started")
+        assert not r.dev.left[0].is_set()      # N is still in the lane
+        r.dev.go[0].set()
+        for m in first:
+            assert m.returned().exc is None
+        # N+1's staging has not ended, and its members wait for it.
+        assert not r.stage.left[1].is_set()
+        assert all(m.inside() for m in second)
+        r.stage.go[1].set()
+        for m in second:
+            assert m.returned().exc is None
+        st = r.sb.stats()
+        assert st["dispatches"]["device"] == 2 and st["overlapped"] == 1
+        assert st["requests"]["device"] == 4
+
+
+@routes
+def test_two_staging_leases_at_most_and_each_held_through_its_call(route):
+    """(b) One batch in the lane, one being staged: never a third
+    staging lease, and a batch's lease is held until ITS device call
+    has returned (donation safety)."""
+    with rig.Rig(route, hold_dev=[0, 1]) as r:
+        refs_in_call = {}
+        r.dev.on_call = lambda i: refs_in_call.setdefault(
+            i, r.leases[i].refs)
+        n0 = r.send(seed=1)
+        rig.wait(r.dev.entered[0], "N in the lane")
+        n1 = r.send(seed=3)
+        rig.wait(r.stage.left[1], "N+1 staged")
+        n2 = r.send(seed=5)
+        # N+1 is staged and waits for N's call; N+2 waits for the
+        # dispatcher: it cannot be staged before N's lease is back.
+        rig.until(lambda: len(r.sb._pending) == 2, "N+2 queued")
+        assert r.pool.stats()["outstanding"] == 2
+        assert r.leases[0].refs == 1 and r.leases[1].refs == 1
+        assert not r.stage.entered[2].is_set()
+        r.dev.go[0].set()
+        for m in n0:
+            m.returned()
+        assert r.leases[0].refs == 0           # back with N's rows
+        rig.wait(r.dev.entered[1], "N+1 in the lane")
+        rig.wait(r.stage.left[2], "N+2 staged")
+        assert r.leases[1].refs == 1 and r.leases[2].refs == 1
+        r.dev.go[1].set()
+        for m in n1 + n2:
+            assert m.returned().exc is None
+        assert refs_in_call == {0: 1, 1: 1, 2: 1}
+        assert max(r.outstanding) == 2 and len(r.leases) == 3
+    st = r.pool.stats()
+    assert st["outstanding"] == 0 and st["leaks"] == 0
+
+
+@routes
+def test_a_failed_batch_fails_its_own_members_only(route):
+    """(c) What N's device call raised reaches N's members and nobody
+    else: N+1, staged meanwhile, is served."""
+    with rig.Rig(route, hold_dev=[0], fail=[0]) as r:
+        first = r.send(seed=7)
+        rig.wait(r.dev.entered[0], "N in the lane")
+        second = r.send(seed=9)
+        rig.wait(r.stage.left[1], "N+1 staged")
+        r.dev.go[0].set()
+        for m in first:
+            assert isinstance(m.returned().exc, RuntimeError)
+            assert m.rows is None
+        for m, want in zip(second, r.synchronous(second)):
+            assert m.returned().exc is None
+            rig.same(route, m.rows, want)
+        assert r.sb.stats()["dispatches"]["device"] == 1
+    assert r.pool.stats()["outstanding"] == 0
+
+
+@routes
+def test_a_member_past_its_deadline_fails_alone_under_overlap(route):
+    """(d) A member whose budget ran out while it queued is culled when
+    the dispatcher takes its batch — while the batch before is still in
+    the lane — and its batch-mates are served."""
+    with rig.Rig(route, hold_dev=[0], hold_stage=[0]) as r:
+        first = r.send(seed=30)
+        rig.wait(r.stage.entered[0], "N being staged")
+        mates = r.send(seed=40, n=3)
+        rig.until(lambda: len(r.sb._pending) == 3, "N+1 queued")
+        with r.sb._mu:
+            doomed = r.sb._pending[1]
+            doomed.expires_at = 0.0          # spent while it queued
+        late = next(m for m in mates if m.stacked is doomed.stacked)
+        r.stage.go[0].set()
+        rig.wait(r.dev.entered[0], "N in the lane")
+        assert isinstance(late.returned().exc, DeadlineExceeded)
+        assert not r.dev.left[0].is_set()      # N is still in the lane
+        r.dev.go[0].set()
+        kept = [m for m in mates if m is not late]
+        for m, want in zip(first + kept, r.synchronous(first + kept)):
+            assert m.returned().exc is None
+            rig.same(route, m.rows, want)
+        assert r.sb.stats()["deadline_failures"] == 1
+        assert r.sb.stats()["requests"]["device"] == 4
+
+
+@routes
+def test_close_returns_after_the_batch_in_flight_has_delivered(route):
+    """(e) close() waits for what the dispatcher has taken: the batch
+    in the lane and the one staged behind it."""
+    with rig.Rig(route, hold_dev=[0]) as r:
+        first = r.send(seed=50)
+        rig.wait(r.dev.entered[0], "N in the lane")
+        second = r.send(seed=60)
+        rig.wait(r.stage.left[1], "N+1 staged")
+        seen = {}
+
+        def closer():
+            r.sb.close()
+            seen["delivered"] = r.sb.stats()["requests"]["device"]
+
+        t = threading.Thread(target=closer)
+        t.start()
+        rig.until(lambda: r.sb._closed, "close() called")
+        assert t.is_alive() and not seen       # N is still in the lane
+        r.dev.go[0].set()
+        t.join(rig.WAIT_S)
+        assert not t.is_alive()
+        assert seen["delivered"] == 4    # counted before close() returned
+        for m in first + second:
+            assert m.returned().exc is None
+
+
+@routes
+def test_pipelined_rows_are_the_synchronous_paths_rows(route):
+    """(f) Rows of N and N+1, staged and finished on three threads,
+    are byte for byte what one thread gives for the same windows."""
+    with rig.Rig(route, hold_dev=[0]) as r:
+        first = r.send(seed=70)
+        rig.wait(r.dev.entered[0], "N in the lane")
+        second = r.send(seed=80)
+        rig.wait(r.stage.left[1], "N+1 staged")
+        r.dev.go[0].set()
+        for batch in (first, second):
+            for m, want in zip(batch, r.synchronous(batch)):
+                assert m.returned().exc is None
+                rig.same(route, m.rows, want)
+                if route == "put":
+                    # Data rows point into the member's own window, not
+                    # into a staging buffer that went back to the pool.
+                    for drive in range(rig.K):
+                        assert all(np.shares_memory(np.asarray(blk),
+                                                    m.stacked)
+                                   for _dig, blk in m.rows[drive])
+        assert r.sb.stats()["overlapped"] == 1
