@@ -1080,12 +1080,10 @@ class ErasureSet:
         # a lone PUT with nobody to batch with — the host codec runs
         # with zero added latency (ops/batcher.py).
         # MTPU_BATCH_FORCE=device overrides the platform check: the
-        # reproducibility knob must reach the REAL batched device route
-        # on any host (CI plumbing proofs, the put_scaling sweep on
-        # virtual devices) — without it, a non-TPU backend silently
-        # measured the host path no matter what the batcher was forced
-        # to, which is exactly the invisible degradation the knob
-        # exists to rule out.
+        # pin must reach the REAL batched device route on any host
+        # (the tests' way to the mesh on virtual CPU devices) — without
+        # it a non-TPU backend would serve from the host path whatever
+        # the batcher was pinned to.
         batcher_for = _batcher_for if route == "put" \
             else _transform_batcher_for
         use_device = (full >= 1 and m > 0
@@ -1134,18 +1132,10 @@ class ErasureSet:
                 chunks[i].append(framed_tail[i])
         return chunks, lease
 
-    def _encode_and_frame(self, data: bytes, k: int, m: int,
-                          pad_blocks: int = 0) -> list[list]:
-        """Compatibility wrapper over _frame_windows for callers that
-        want self-owned bytes (decom/restore paths, tests): any pooled
-        views are copied out and the lease returns immediately.
-
-        pad_blocks: retained for call-site compatibility; batch-shape
-        stability is the stripe batcher's job (it pads coalesced
-        batches to fixed buckets, so compiled shapes stay bounded no
-        matter how requests interleave).
-        """
-        del pad_blocks
+    def _encode_and_frame(self, data: bytes, k: int, m: int) -> list[list]:
+        """_frame_windows for callers that want self-owned bytes
+        (decom/restore paths, tests): any pooled views are copied out
+        and the lease returns immediately."""
         chunks, lease = self._frame_windows(data, k, m)
         if lease is None:
             return chunks
@@ -1185,9 +1175,8 @@ class ErasureSet:
         plen = len(data)
         spec.plain_size = plen
         # native.feature honors the MTPU_TRANSFORM_FUSED kill-switch:
-        # direct object-layer callers (bench legacy legs, tests) must
-        # take the staged pipeline under "off" exactly like the S3
-        # handler path does.
+        # direct object-layer callers (tests) must take the staged
+        # pipeline under "off" exactly like the S3 handler path does.
         lib = native.feature("mtpu_transform_frame")
         e = self._erasure(k, m)
         n = k + m

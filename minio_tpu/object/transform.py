@@ -82,7 +82,7 @@ def stats() -> dict:
 
 
 def reset_stats() -> None:
-    """Test/bench hook: zero the path-split counters."""
+    """Test hook: zero the path-split counters."""
     with _stat_mu:
         for d in (_put_requests, _get_requests):
             for key in list(d):

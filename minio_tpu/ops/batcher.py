@@ -14,11 +14,11 @@ is what fills it.
 
 What makes the batch DEVICE-resident (ops/hh_device.make_mesh_framer):
 the coalesced window is staged into ONE pooled bufpool buffer, padded to
-a fixed power-of-two bucket, and dispatched as a pjit-style sharded step
-— NamedSharding(mesh, P("stripe")) splits the batch dim over every
-available chip and `donate_argnums` hands the staged HBM buffer to the
-kernel so data flows host -> HBM -> parity with no defensive copy. One
-compiled executable exists per (bucket, EC config), never per
+a fixed power-of-two bucket, and dispatched as one jitted step placed
+by ops/device.batch_placement: on one chip as it stands, on several
+with the batch dim cut over the chips (`P("stripe")`) and the staged
+batch donated, so data flows host -> HBM -> parity with no defensive
+copy. One compiled executable exists per (bucket, EC config), never per
 concurrency level. All device dispatches in the process serialize
 through the shared io/engine kernel lane (the chip is one resource, like
 a drive), which also yields wait-vs-service attribution for free.
@@ -88,8 +88,8 @@ same-shape runs per batch.
 
 Environment:
   MTPU_BATCH_FORCE    device|host|auto (default auto): pin the
-                      calibration verdict — reproducible benches/CI
-                      instead of a silent probe-dependent route.
+                      calibration verdict — a reproducible route in
+                      tests/CI instead of a probe-dependent one.
                       Accepts per-route pins as a comma list, e.g.
                       "put=device,get=host" (unnamed routes stay auto).
   MTPU_BATCH_WAIT_MS  base accumulation window in ms (default 2).
@@ -121,7 +121,7 @@ from minio_tpu.utils.latency import Histogram
 
 # Batch-dim padding buckets: one compiled device shape per bucket, not
 # one per distinct concurrency level. Powers of two so every bucket
-# divides evenly across a power-of-two chip mesh (hh_device
+# divides evenly across a power-of-two chip mesh (ops/device
 # mesh_batch_devices) with zero per-chip remainder shapes.
 _BUCKETS = (8, 16, 32, 64, 128, 256)
 # Base accumulation window (first window of a burst); the adaptive
@@ -476,11 +476,11 @@ class StripeBatcher:
             or bool(self._pending)
 
     def force(self, device_ok: bool) -> None:
-        """Pin the calibration verdict (bench/tests): no probe runs,
+        """Pin the calibration verdict (tests): no probe runs,
         dispatch follows `device_ok` unconditionally. The env knob
         MTPU_BATCH_FORCE=device|host applies the same pin at
-        construction (CI/bench reproducibility: a slow-link probe must
-        not silently degrade a measured run to pass-through)."""
+        construction (a slow-link probe must not silently turn a
+        test of the device route into pass-through)."""
         with self._mu:
             self._probe_started = True
             self._pinned = True
@@ -488,7 +488,7 @@ class StripeBatcher:
             self._device_ok = bool(device_ok)
 
     def reset_calibration(self) -> None:
-        """Back to the configured default (bench/tests cleanup after
+        """Back to the configured default (tests' cleanup after
         force()): unprobed under auto, re-pinned under a
         MTPU_BATCH_FORCE override."""
         with self._mu:

@@ -114,9 +114,9 @@ def mesh_batch_devices(devices=None) -> list:
     buckets are powers of two (ops/batcher._BUCKETS), so a power-of-two
     mesh keeps every bucketed batch evenly divisible across chips with
     zero per-chip remainder shapes (one compile per bucket, not per
-    (bucket, remainder) pair). MTPU_MESH_DEVICES caps the prefix — the
-    chip-count scaling sweep (bench.py put_scaling) uses it to measure
-    1/2/4/8-chip aggregates on one host."""
+    (bucket, remainder) pair). MTPU_MESH_DEVICES caps the prefix (the
+    tests' way to one device on the virtual eight; ROADMAP C17 asks
+    whether it is a constant or a deployment setting)."""
     if devices is None:
         import jax
         devices = jax.devices()
@@ -133,6 +133,63 @@ def mesh_batch_devices(devices=None) -> list:
     while p * 2 <= len(devs) and p * 2 <= 256:
         p *= 2
     return devs[:p]
+
+
+def batch_placement(devices=None) -> tuple:
+    """Where a stacked batch runs: the one placement rule of every
+    batched device operation (frame, de-frame, matrix apply). Returns
+    `(jit_body, upload, ndev)` for `mesh_batch_devices(devices)`:
+    `jit_body(body, static_argnames=())` is the jitted step of a body
+    whose first argument carries the batch on its leading axis (the
+    rest are replicated), `upload(x)` moves a host batch to where that
+    step reads it.
+
+    One device: `jax.jit(body)` on the default device and
+    `jnp.asarray`, no mesh object and no donation. Several: the body
+    under `shard_map` over `Mesh(devs, ("stripe",))`, batch axis
+    `P("stripe")` in and out — each chip runs the body on its slice,
+    no cross-chip traffic (stripes are independent) — jitted with the
+    batch donated on a TPU (the staged host batch flows host -> HBM ->
+    outputs without XLA's defensive copy; the CPU backend ignores
+    donation with a compile warning, so it is declared only where it
+    buys the copy), and one `jax.device_put(x, NamedSharding)` of a
+    batch that divides by the chips (the batcher pads to power-of-two
+    buckets). One compile per (padded batch size, body)."""
+    import jax
+    devs = mesh_batch_devices(devices)
+    ndev = len(devs)
+    if ndev == 1:
+        import jax.numpy as jnp
+
+        def jit_body(body, static_argnames=()):
+            return jax.jit(body, static_argnames=static_argnames)
+
+        return jit_body, jnp.asarray, 1
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(devs), ("stripe",))
+    sharding = NamedSharding(mesh, P("stripe"))
+    donate = (0,) if on_tpu() else ()
+
+    def jit_body(body, static_argnames=()):
+        @functools.wraps(body)
+        def sharded(batch, *replicated, **static):
+            # check_vma off: the Pallas calls inside the bodies carry
+            # no varying-manual-axes annotations.
+            return jax.shard_map(
+                functools.partial(body, **static), mesh=mesh,
+                in_specs=(P("stripe"),) + (P(),) * len(replicated),
+                out_specs=P("stripe"), check_vma=False)(batch, *replicated)
+        return jax.jit(sharded, static_argnames=static_argnames,
+                       donate_argnums=donate)
+
+    def upload(batch):
+        assert batch.shape[0] % ndev == 0, \
+            f"batch {batch.shape[0]} not divisible by {ndev}-chip mesh " \
+            f"(pad buckets)"
+        return jax.device_put(batch, sharding)
+
+    return jit_body, upload, ndev
 
 
 def probe_platform(timeout: float = 180.0) -> str:
@@ -227,7 +284,10 @@ def note_mesh_blocks(blocks: int, chips: int) -> None:
     chips of a mesh (`P("stripe")`): per chip, how many rows of its
     slice were real and how many padding. The real rows come first, so
     padding lands on the last chips. Without the batcher's word
-    (`batch_of`) every row counts as real."""
+    (`batch_of`) every row counts as real. One device is no mesh:
+    nothing is counted, and the series stays absent."""
+    if chips <= 1:
+        return
     real = getattr(_batch, "real", None)
     real = blocks if real is None else min(real, blocks)
     per_chip = blocks // chips

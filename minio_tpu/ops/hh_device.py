@@ -790,7 +790,7 @@ def _rows8(data, parity, digests) -> list[list[tuple]]:
                 for i in range(len(shards))]
 
 
-def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
+def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
     """Fused PUT pipeline on device, one call per stripe batch.
 
     Returns fn(data uint8 [B, k, L]) -> per-drive lists of per-block
@@ -805,15 +805,25 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
     digests ride the device->host link. Digest algorithm is the bitrot
     default HighwayHash-256S under the magic key (cmd/bitrot.go:37,
     105-110).
+
+    The batch dimension ("stripes from MANY concurrent PutObject
+    requests", coalesced by ops/batcher.StripeBatcher) runs where
+    `device.batch_placement(devices)` puts it: on one device as one
+    jitted step, on several sharded over the chips, each framing its
+    local stripe slice (stripes are independent, the same property the
+    reference exploits with per-goroutine encode,
+    cmd/erasure-encode.go:27). One compile per (padding bucket, EC
+    config): callers pad the batch dim to the fixed buckets, never to
+    raw concurrency levels. `run.mesh_devices` says how many chips.
     """
     from minio_tpu.ops.rs_device import make_encoder, make_encoder32
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-    n = matrix.shape[1] + matrix.shape[0]
+    m, k = matrix.shape
+    n = k + m
     encode = make_encoder(matrix, mode=mode)
     encode32 = make_encoder32(matrix, mode=mode)
     on_tpu = device.on_tpu()
 
-    @functools.partial(jax.jit, static_argnames=("pchunk",))
     def fused32(data32, init, pchunk: int):
         """u32 hot path: data [B, k, L4] u32 -> (parity [B, m, L4],
         dig_d [B, k, 8], dig_p [B, m, 8]) u32.
@@ -824,8 +834,7 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
         data and parity hash as two separate stream sets (no shards
         concatenate). No u8<->u32 relayouts and no XLA copies anywhere.
         """
-        b, k, l4 = data32.shape
-        m = n - k
+        b = data32.shape[0]
         parity = encode32(data32)                  # [B, m, L4]
         dig_d = _hash_words_pallas(data32, init,
                                    pchunk=pchunk).reshape(b, k, 8)
@@ -833,14 +842,17 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
                                    pchunk=pchunk).reshape(b, m, 8)
         return parity, dig_d, dig_p
 
-    @functools.partial(jax.jit, static_argnames=())
     def fused8(data, init):
         """Portable byte path (off-TPU / ineligible shapes)."""
-        b, k, l = data.shape
+        b, _, l = data.shape
         parity = encode(data)                      # [B, m, L]
         shards = jnp.concatenate([data, parity], axis=1)  # [B, n, L]
         digests = _hash_impl(shards.reshape(b * n, l), init, l)
         return parity, digests.reshape(b, n, 32)
+
+    jit_body, upload, ndev = device.batch_placement(devices)
+    step32 = jit_body(fused32, static_argnames=("pchunk",))
+    step8 = jit_body(fused8)
 
     def run(data) -> list[list[tuple]]:
         """data uint8 [B, k, L] numpy -> n per-drive lists; entry i is
@@ -848,38 +860,44 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
         of which is drive i's framed shard-file bytes. Data-block pieces
         are views of `data` (zero copy)."""
         data = np.ascontiguousarray(data, dtype=np.uint8)
-        l = data.shape[2]
+        b, _, l = data.shape
+        device.note_mesh_blocks(b, ndev)
         pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
         if on_tpu and l % 1024 == 0 and pchunk >= 8:
             device.note_kernel("frame", "pallas")
             return _rows32(data, *_lane_round_trip(
-                lambda: (jnp.asarray(data.view(np.uint32)),
+                lambda: (upload(data.view(np.uint32)),
                          jnp.asarray(_init_smem_np(MAGIC_KEY))),
-                lambda data32, init: fused32(data32, init, pchunk)))
+                lambda data32, init: step32(data32, init, pchunk=pchunk)))
         device.note_kernel("frame", "xla")
         return _rows8(data, *_lane_round_trip(
-            lambda: (jnp.asarray(data, dtype=jnp.uint8),
-                     jnp.asarray(_init_state_np(MAGIC_KEY))),
-            fused8))
+            lambda: (upload(data), jnp.asarray(_init_state_np(MAGIC_KEY))),
+            step8))
 
     def device_step(data32):
         """Device-resident fused pipeline: u32 [B, k, L4] -> (parity,
         data digests, parity digests) device arrays. The exact jitted
-        graph the PUT hot path runs — exposed so bench.py measures
-        production code rather than a hand copy."""
+        step the PUT hot path runs, without the host round trip:
+        `__graft_entry__.entry()` returns it as the flagship step."""
         l4 = data32.shape[2]
-        return fused32(data32, jnp.asarray(_init_smem_np(MAGIC_KEY)),
-                       _pick_pchunk(l4 // 8))
+        return step32(data32, jnp.asarray(_init_smem_np(MAGIC_KEY)),
+                      pchunk=_pick_pchunk(l4 // 8))
 
     run.device_step = device_step
-    run.mesh_devices = 1
+    run.mesh_devices = ndev
     return run
+
+
+def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
+    """make_mesh_framer on the default device alone: same run()
+    contract, same bytes, `run.mesh_devices == 1`."""
+    return make_mesh_framer(matrix, mode=mode, devices=jax.devices()[:1])
 
 
 # ---------------------------------------------------------------------------
 # Fused GET verify (the device de-framer)
 # ---------------------------------------------------------------------------
-# The read-side mirror of make_encode_framer: the GET hot loop's cost on
+# The read-side mirror of make_mesh_framer: the GET hot loop's cost on
 # the host is HighwayHashing every fetched framed shard block
 # (native.cc mtpu_get_frame does it GIL-free; the numpy path in
 # storage/bitrot.read_framed_blocks_many does it vectorized). The
@@ -896,18 +914,22 @@ def make_encode_framer(matrix: np.ndarray, mode: str = "auto"):
 # "does the device hash agree", asserted by tests/test_decode_route.py.
 
 
-def make_deframer(k: int, mode: str = "auto"):
-    """Single-chip fused GET verifier for k-data-shard stripes.
+def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
+    """Fused GET verifier for k-data-shard stripes.
 
     Returns fn(framed uint8 [B, k, F]) -> ok bool numpy [B, k], where
     F = 32 + shard_size and row b holds erasure block b's k on-disk
     frames. ok[b, i] is True when shard i's block b digest verifies —
-    the same verdict mtpu_get_frame's bad-mask encodes, batched.
+    the same verdict mtpu_get_frame's bad-mask encodes, batched. The
+    batch dimension ("erasure blocks from MANY concurrent GetObject
+    windows", coalesced by ops/batcher's get route) runs where
+    `device.batch_placement(devices)` puts it, exactly the encode
+    framer's dispatch shape mirrored; only the B*k verdicts ride back.
+    One compile per (padding bucket, k, frame width).
     """
-    del k  # shape-generic: the stream count is B*k either way
+    del k, mode  # shape-generic: the stream count is B*k either way
     on_tpu = device.on_tpu()
 
-    @functools.partial(jax.jit, static_argnames=("pchunk",))
     def verify32(framed32, init, pchunk: int):
         """u32 hot path: framed [B, k, F4] u32 -> ok bool [B, k]."""
         b, kk, f4 = framed32.shape
@@ -916,7 +938,6 @@ def make_deframer(k: int, mode: str = "auto"):
         stored = framed32[:, :, :8].reshape(b * kk, 8)
         return jnp.all(digs == stored, axis=1).reshape(b, kk)
 
-    @jax.jit
     def verify8(framed, init):
         """Portable byte path: framed [B, k, F] u8 -> ok bool [B, k]."""
         b, kk, f = framed.shape
@@ -925,193 +946,30 @@ def make_deframer(k: int, mode: str = "auto"):
         stored = framed[:, :, :32].reshape(b * kk, 32)
         return jnp.all(digs == stored, axis=1).reshape(b, kk)
 
+    jit_body, upload, ndev = device.batch_placement(devices)
+    step32 = jit_body(verify32, static_argnames=("pchunk",))
+    step8 = jit_body(verify8)
+
     def run(framed) -> np.ndarray:
         framed = np.ascontiguousarray(framed, dtype=np.uint8)
-        b, kk, f = framed.shape
+        f = framed.shape[2]
         s = f - 32
         pchunk = _pick_pchunk(s // 32) if s and s % 32 == 0 else 0
         if on_tpu and f % 4 == 0 and s % 1024 == 0 and pchunk >= 8:
             device.note_kernel("deframe", "pallas")
-            f32 = jnp.asarray(framed.view(np.uint32))
-            ok = verify32(f32, jnp.asarray(_init_smem_np(MAGIC_KEY)),
-                          _pick_pchunk(s // 4 // 8))
+            ok = step32(upload(framed.view(np.uint32)),
+                        jnp.asarray(_init_smem_np(MAGIC_KEY)),
+                        pchunk=pchunk)
         else:
             device.note_kernel("deframe", "xla")
-            ok = verify8(jnp.asarray(framed),
-                         jnp.asarray(_init_state_np(MAGIC_KEY)))
-        return np.asarray(ok)
-
-    run.mesh_devices = 1
-    return run
-
-
-# ---------------------------------------------------------------------------
-# Mesh-sharded cross-request framer
-# ---------------------------------------------------------------------------
-
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map with the varying-manual-axes check off: the Pallas
-    calls inside the bodies carry no vma annotations."""
-    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-
-
-def make_mesh_framer(matrix: np.ndarray, mode: str = "auto", devices=None):
-    """The cross-request device framer: make_encode_framer's run()
-    contract — stacked u8 [B, k, L] -> k+m per-drive lists of
-    (digest, block) piece tuples — with the batch dimension ("stripes
-    from MANY concurrent PutObject requests", coalesced by
-    ops/batcher.StripeBatcher) sharded over every available chip.
-
-    pjit-style dispatch (SNIPPETS [1][2][3]): the jitted step carries a
-    NamedSharding(mesh, P("stripe")) on the batch axis — each chip runs
-    the fused GF(2^8)+HighwayHash pipeline on its local stripe slice,
-    no cross-chip traffic inside the hot loop (stripes are independent,
-    the same property the reference exploits with per-goroutine encode,
-    cmd/erasure-encode.go:27) — and `donate_argnums=(0,)` donates the
-    input HBM buffer so the pooled host staging (io/bufpool) flows
-    host->HBM->parity without XLA's defensive copy. One compile per
-    (padding bucket, EC config): callers pad the batch dim to the fixed
-    buckets, never to raw concurrency levels.
-
-    On one device (CPU tests, MTPU_MESH_DEVICES=1) this degrades to the
-    single-chip fused framer — same bytes, no mesh machinery.
-    """
-    devs = device.mesh_batch_devices(devices)
-    ndev = len(devs)
-    if ndev <= 1:
-        return make_encode_framer(matrix, mode=mode)
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from minio_tpu.ops.rs_device import make_encoder, make_encoder32
-    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-    m, k = matrix.shape
-    n = k + m
-    mesh = Mesh(np.asarray(devs), ("stripe",))
-    sharding = NamedSharding(mesh, P("stripe"))
-    on_tpu = device.on_tpu()
-    # Donation is a TPU-memory contract; the CPU backend ignores it
-    # with a compile warning, so only declare it where it buys the copy.
-    donate = (0,) if on_tpu else ()
-    encode = make_encoder(matrix, mode=mode)
-    encode32 = make_encoder32(matrix, mode=mode)
-
-    @functools.partial(jax.jit, static_argnames=("pchunk",),
-                       donate_argnums=donate)
-    def mesh32(data32, init, pchunk: int):
-        """u32 hot path, batch sharded over the mesh (see fused32)."""
-        def body(d, ini):
-            b = d.shape[0]
-            parity = encode32(d)
-            dig_d = _hash_words_pallas(d, ini,
-                                       pchunk=pchunk).reshape(b, k, 8)
-            dig_p = _hash_words_pallas(parity, ini,
-                                       pchunk=pchunk).reshape(b, m, 8)
-            return parity, dig_d, dig_p
-        return _shard_map(
-            body, mesh=mesh, in_specs=(P("stripe"), P()),
-            out_specs=(P("stripe"), P("stripe"), P("stripe")))(data32, init)
-
-    @functools.partial(jax.jit, donate_argnums=donate)
-    def mesh8(data, init):
-        """Portable byte path, batch sharded over the mesh."""
-        def body(d, ini):
-            b, _, l = d.shape
-            parity = encode(d)
-            shards = jnp.concatenate([d, parity], axis=1)
-            digests = _hash_impl(shards.reshape(b * n, l), ini, l)
-            return parity, digests.reshape(b, n, 32)
-        return _shard_map(
-            body, mesh=mesh, in_specs=(P("stripe"), P()),
-            out_specs=(P("stripe"), P("stripe")))(data, init)
-
-    def run(data) -> list[list[tuple]]:
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        b, _, l = data.shape
-        assert b % ndev == 0, \
-            f"batch {b} not divisible by {ndev}-chip mesh (pad buckets)"
-        device.note_mesh_blocks(b, ndev)
-        pchunk = _pick_pchunk(l // 32) if l and l % 32 == 0 else 0
-        if on_tpu and l % 1024 == 0 and pchunk >= 8:
-            device.note_kernel("frame", "pallas")
-            return _rows32(data, *_lane_round_trip(
-                lambda: (jax.device_put(data.view(np.uint32), sharding),
-                         jnp.asarray(_init_smem_np(MAGIC_KEY))),
-                lambda d32, init: mesh32(d32, init, pchunk)))
-        device.note_kernel("frame", "xla")
-        return _rows8(data, *_lane_round_trip(
-            lambda: (jax.device_put(data, sharding),
-                     jnp.asarray(_init_state_np(MAGIC_KEY))),
-            mesh8))
-
-    run.mesh_devices = ndev
-    return run
-
-
-def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
-    """The cross-request device de-framer: make_deframer's run()
-    contract — framed u8 [B, k, F] -> ok bool [B, k] — with the batch
-    dimension ("erasure blocks from MANY concurrent GetObject windows",
-    coalesced by ops/batcher's get route) sharded over every available
-    chip via NamedSharding(mesh, P("stripe")), exactly the encode
-    framer's dispatch shape mirrored.
-
-    `donate_argnums=(0,)` on TPU donates the staged framed window (one
-    pooled bufpool lease, ops/batcher._stage) into HBM so the read-side
-    batch flows host->HBM copy-free; only the B*k verdicts ride back.
-    One compile per (padding bucket, k, frame width). On one device
-    (CPU tests, MTPU_MESH_DEVICES=1) this degrades to the single-chip
-    fused verifier — same verdicts, no mesh machinery.
-    """
-    devs = device.mesh_batch_devices(devices)
-    ndev = len(devs)
-    if ndev <= 1:
-        return make_deframer(k, mode=mode)
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.asarray(devs), ("stripe",))
-    sharding = NamedSharding(mesh, P("stripe"))
-    on_tpu = device.on_tpu()
-    donate = (0,) if on_tpu else ()
-
-    @functools.partial(jax.jit, static_argnames=("pchunk",),
-                       donate_argnums=donate)
-    def mesh_verify32(framed32, init, pchunk: int):
-        def body(fr, ini):
-            b, kk, f4 = fr.shape
-            words = fr[:, :, 8:].reshape(b * kk, f4 - 8)
-            digs = _hash_words_pallas(words, ini, pchunk=pchunk)
-            stored = fr[:, :, :8].reshape(b * kk, 8)
-            return jnp.all(digs == stored, axis=1).reshape(b, kk)
-        return _shard_map(body, mesh=mesh, in_specs=(P("stripe"), P()),
-                         out_specs=P("stripe"))(framed32, init)
-
-    @functools.partial(jax.jit, donate_argnums=donate)
-    def mesh_verify8(framed, init):
-        def body(fr, ini):
-            b, kk, f = fr.shape
-            blocks = fr[:, :, 32:].reshape(b * kk, f - 32)
-            digs = _hash_impl(blocks, ini, f - 32)
-            stored = fr[:, :, :32].reshape(b * kk, 32)
-            return jnp.all(digs == stored, axis=1).reshape(b, kk)
-        return _shard_map(body, mesh=mesh, in_specs=(P("stripe"), P()),
-                         out_specs=P("stripe"))(framed, init)
-
-    def run(framed) -> np.ndarray:
-        framed = np.ascontiguousarray(framed, dtype=np.uint8)
-        b, kk, f = framed.shape
-        assert b % ndev == 0, \
-            f"batch {b} not divisible by {ndev}-chip mesh (pad buckets)"
-        s = f - 32
-        pchunk = _pick_pchunk(s // 32) if s and s % 32 == 0 else 0
-        if on_tpu and f % 4 == 0 and s % 1024 == 0 and pchunk >= 8:
-            device.note_kernel("deframe", "pallas")
-            f32 = jax.device_put(framed.view(np.uint32), sharding)
-            ok = mesh_verify32(f32, jnp.asarray(_init_smem_np(MAGIC_KEY)),
-                               _pick_pchunk(s // 4 // 8))
-        else:
-            device.note_kernel("deframe", "xla")
-            f8 = jax.device_put(framed, sharding)
-            ok = mesh_verify8(f8, jnp.asarray(_init_state_np(MAGIC_KEY)))
+            ok = step8(upload(framed),
+                       jnp.asarray(_init_state_np(MAGIC_KEY)))
         return np.asarray(ok)
 
     run.mesh_devices = ndev
     return run
+
+
+def make_deframer(k: int, mode: str = "auto"):
+    """make_mesh_deframer on the default device alone."""
+    return make_mesh_deframer(k, mode=mode, devices=jax.devices()[:1])

@@ -17,18 +17,18 @@ Formulation — bitplane decomposition to GF(2):
   exact in bf16 past k=16. This mirrors how GFNI expresses GF(2^8) ops as
   8x8 bit-matrix affine transforms, mapped onto a 128x128 systolic array.
 
-Two implementations behind one `DeviceBackend`:
+Three implementations of the one transform:
   * `_xla_apply` — pure jax.numpy, runs anywhere (CPU tests, the virtual
-    8-device mesh) and lets XLA fuse unpack/pack. Materialises the 8x
-    bitplane expansion in HBM, so it is bandwidth-bound at ~1/17 of peak.
-  * `_pallas_apply` — fused Pallas kernel: unpack -> matmul -> mod2 -> pack
-    all inside VMEM per tile, so HBM traffic is just bytes-in + parity-out
-    (~(1 + r/k) x). Bit rows/cols are PLANE-major (row = plane*width + byte)
-    so in-kernel unpack is a static concatenate of 8 shifted views and the
-    repack is 8 static sublane slices — no strided sublane access, which
-    Mosaic does not support.
+    8-device mesh); materialises the 8x bitplane expansion in HBM.
+  * `_pallas_apply` — fused Pallas kernel on u8 arrays: unpack -> matmul
+    -> mod2 -> pack inside VMEM per tile, so HBM traffic is bytes-in +
+    parity-out; PLANE-major bit rows/cols (row = plane*width + byte), as
+    Mosaic has no strided sublane access. Behind `DeviceBackend` (whose
+    portable mode is `_xla_apply`): the `reconstruct` route, `apply_matrix`.
+  * `_pallas_apply32` — the same matmul on u32 lanes (`make_encoder32`,
+    below): what the PUT framer (hh_device `fused32`) runs on the chip.
 
-Both produce bytes identical to the host numpy backend and therefore to the
+All three produce the host numpy backend's bytes and therefore the
 reference's shards (golden digests, cmd/erasure-coding.go:163).
 """
 
@@ -444,8 +444,9 @@ def make_encoder(matrix: np.ndarray, mode: str = "auto"):
 
     The GF matrix is baked in host-side (prep + padding handled); the
     returned closure is safe to wrap in jax.jit or call inside jitted
-    code. This is the single dispatch point — bench.py, __graft_entry__
-    and the sharded stripe steps all go through it.
+    code. This is the single dispatch point of the u8 transform: the
+    byte framer (hh_device), make_mesh_matrix, __graft_entry__ and the
+    sharded stripe steps all go through it.
     """
     backend = DeviceBackend(mode)
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
@@ -453,50 +454,34 @@ def make_encoder(matrix: np.ndarray, mode: str = "auto"):
 
 
 def make_mesh_matrix(matrix: np.ndarray, mode: str = "auto", devices=None):
-    """Mesh-sharded batched GF(2^8) matrix application — the decode
-    mirror of hh_device.make_mesh_framer's parity step: stacked u8
-    [B, k, L] -> u8 [B, r, L] with the batch dim ("stripes from MANY
-    degraded GetObject / heal calls", coalesced by ops/batcher's
-    reconstruct route) sharded over the chips via
-    NamedSharding(mesh, P("stripe")).
+    """Batched GF(2^8) matrix application — the decode mirror of
+    hh_device.make_mesh_framer's parity step: stacked u8 [B, k, L] ->
+    u8 [B, r, L] numpy, with the batch dim ("stripes from MANY degraded
+    GetObject / heal calls", coalesced by ops/batcher's reconstruct
+    route) placed by `device.batch_placement(devices)`: one jitted step
+    on one device, sharded over the chips on several.
 
     `matrix` is any (r x k) GF matrix: decode-matrix rows
     (gf256.decode_matrix gathered for the missing data shards — one
     compiled route per surviving-shard set, the common case being ONE
     set per dead drive) for degraded reads, parity rows for heal's
-    re-derive. `donate_argnums=(0,)` on TPU donates the staged survivor
-    batch. On one device this degrades to the single-chip encoder —
-    same bytes (gf256 bitplane transform, byte-identical to the host
-    codec by the rs_device contract).
+    re-derive. Same bytes on any chip count (gf256 bitplane transform,
+    byte-identical to the host codec by the rs_device contract).
     """
-    from minio_tpu.ops.hh_device import _shard_map
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-    devs = device.mesh_batch_devices(devices)
-    ndev = len(devs)
-    encode = make_encoder(matrix, mode=mode)
-    if ndev <= 1:
-        def run_solo(stacked) -> np.ndarray:
-            stacked = np.ascontiguousarray(stacked, dtype=np.uint8)
-            return np.asarray(encode(jnp.asarray(stacked)))
-        run_solo.mesh_devices = 1
-        return run_solo
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.asarray(devs), ("stripe",))
-    sharding = NamedSharding(mesh, P("stripe"))
-    donate = (0,) if device.on_tpu() else ()
+    backend = DeviceBackend(mode)
+    impl = "interpret" if backend._interpret else backend.mode
 
-    @functools.partial(jax.jit, donate_argnums=donate)
-    def mesh_apply(data):
-        return _shard_map(lambda d: encode(d), mesh=mesh,
-                          in_specs=(P("stripe"),),
-                          out_specs=P("stripe"))(data)
+    def matrix_apply(data):
+        return backend.apply_matrix_device(matrix, data)
+
+    jit_body, upload, ndev = device.batch_placement(devices)
+    step = jit_body(matrix_apply)
 
     def run(stacked) -> np.ndarray:
         stacked = np.ascontiguousarray(stacked, dtype=np.uint8)
-        assert stacked.shape[0] % ndev == 0, \
-            f"batch {stacked.shape[0]} not divisible by {ndev}-chip mesh"
-        d = jax.device_put(stacked, sharding)
-        return np.asarray(mesh_apply(d))
+        device.note_kernel("matrix", impl)
+        return np.asarray(step(upload(stacked)))
 
     run.mesh_devices = ndev
     return run

@@ -276,6 +276,17 @@ class LaneBreaker:
             self._probe_streak = 0
             self._half_open_probe = False
 
+    def abstain(self) -> None:
+        """The caller's attempt ended without a verdict on the
+        transport (it failed before the wire, or the peer answered
+        with an S3 error). If it was the half-open probe, the slot
+        goes back now — the next chain probes at once — instead of
+        parking the whole lane until PROBE_TTL."""
+        with self._mu:
+            if self._half_open_probe and \
+                    self._probe_owner == threading.get_ident():
+                self._half_open_probe = False
+
     def state(self) -> str:
         with self._mu:
             if self._open_since == 0.0:
@@ -966,6 +977,11 @@ class ReplicationEngine:
             return
         from minio_tpu.replication.common import (DeliveryError,
                                                   is_transport_error)
+        if lane.breaker is not None:
+            if is_transport_error(err):
+                lane.breaker.fault()
+            else:
+                lane.breaker.abstain()
         if isinstance(err, DeliveryError):
             # SSE (or otherwise non-replicable) version: terminal on
             # the first attempt, accounted separately from real
@@ -973,8 +989,6 @@ class ReplicationEngine:
             self.sse_skipped += 1
             self._finish(lane, ck, intent, ok=False)
             return
-        if lane.breaker is not None and is_transport_error(err):
-            lane.breaker.fault()
         intent.attempt += 1
         if intent.attempt < self._RETRIES and not self._stop.is_set():
             with self._mu:

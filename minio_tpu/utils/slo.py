@@ -241,7 +241,7 @@ class SLOEngine:
     def snapshot(self, metrics=None) -> dict:
         """The admin-info / Prometheus view: the last evaluation,
         refreshed in-line when stale (covers deployments where the
-        background thread was never started — tests, bench)."""
+        background thread was never started — tests)."""
         with self._mu:
             fresh = self._last_eval \
                 and self._now() - self._last_eval_t < 2 * self.eval_s

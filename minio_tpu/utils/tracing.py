@@ -75,7 +75,7 @@ def set_node(node_id: str) -> None:
 # ACTIVE is THE fast-path gate: call sites check it before touching any
 # span machinery. It is true while any source (a trace subscriber
 # wanting internal types, a remote worker relay, a configured slow-op
-# threshold, a bench harness) holds an arm() token.
+# threshold) holds an arm() token.
 
 ACTIVE = False
 _arm_mu = threading.Lock()

@@ -3,8 +3,8 @@
 the drives (xl.meta journals written straight to disk, no PUT path) so a
 10M-object namespace builds in minutes instead of hours.
 
-The metadata-plane bench (bench.py meta_listing) and the high-cardinality
-listing tests need namespaces far past what put_object can build in a
+The high-cardinality listing tests (and any metadata-plane measurement)
+need namespaces far past what put_object can build in a
 test budget: a PUT pays erasure encode + staging + rename + fsync per
 object (~1 ms floor), while a fabricated object is one makedirs + one
 unsynced write of a ~400-byte journal. The journals are REAL — built by

@@ -1,8 +1,13 @@
 #!/usr/bin/env bash
-# Tier-1 verification gate (ROADMAP.md): a fast whole-tree compile
-# check, then the non-slow test suite under the same flags and timeout
-# the driver uses. Chaos STRESS tests are marked `slow` and excluded
-# here so tier-1 wall time stays inside the 870 s budget.
+# Tier-1 verification gate: a fast whole-tree compile check, then the
+# non-slow test suite by the command the driver runs after every PR
+# (/root/TESTS_LAST_RUN.json `commands`: six xdist workers, one file a
+# worker at a time, 1470 s). Chaos STRESS tests are marked `slow` and
+# excluded. The driver also exports ALLOW_MULTIPLE_LIBTPU_LOAD=1 for
+# its run in the sandbox; no file of this repository sets it (on the
+# machine with the chip that lock keeps two processes off one chip),
+# and no tier-1 test loads libtpu. Speed is not measured here: that
+# is `benchmark/run.py` on the chip (PERF.md).
 set -o pipefail
 cd "$(dirname "$0")/.."
 
@@ -13,15 +18,6 @@ python -m compileall -q minio_tpu || exit 1
 # snake_case and registered exactly once (scripts/metrics_lint.py).
 echo "== metrics lint =="
 python scripts/metrics_lint.py || exit 1
-
-# Opt-in bench smoke (MTPU_BENCH_SMOKE=1): the concurrent-PUT
-# aggregate at small budget, failing on >20% regression against the
-# committed BENCH_r*.json. Off by default — tier-1 wall time stays
-# inside budget and cross-machine numbers are not comparable.
-if [ "${MTPU_BENCH_SMOKE:-}" = "1" ]; then
-    echo "== bench smoke =="
-    bash scripts/bench_smoke.sh || exit 1
-fi
 
 # Opt-in crash-consistency sweep (MTPU_CRASH_SWEEP=1): the full
 # power-cut crash-point matrix (tests/test_crash_matrix.py, marked
@@ -69,12 +65,17 @@ echo "== fleet trace smoke =="
 env JAX_PLATFORMS=cpu python scripts/fleet_trace_smoke.py || exit 1
 
 echo "== tier-1 tests =="
-rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu \
+rm -rf /tmp/_t1.log /tmp/_t1.xml
+timeout -k 10 1470 env JAX_PLATFORMS=cpu \
     python -m pytest tests/ -q -m 'not slow' \
-    --continue-on-collection-errors \
-    -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
+    --continue-on-collection-errors -p no:cacheprovider \
+    -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml \
+    -p no:randomly 2>&1 | tee /tmp/_t1.log
 rc=${PIPESTATUS[0]}
-echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
-    | tr -cd . | wc -c)
+said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' \
+    /tmp/_t1.xml 2>/dev/null | head -n 1 \
+    | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}')
+echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
+    | tr -cd . | wc -c)}
+echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null)
 exit $rc

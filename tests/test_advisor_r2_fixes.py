@@ -54,11 +54,21 @@ def test_reader_writer_exclusion_with_one_amnesiac_locker():
 def test_drop_conn_only_fails_own_sockets_calls():
     import queue as queue_mod
 
+    from minio_tpu.grid import loop
     from minio_tpu.grid.client import GridClient, _SENTINEL_ERR
 
     c = GridClient("127.0.0.1", 1)  # never actually connected
+    # _drop_conn also tells the process-wide grid poller to forget the
+    # socket. Whether one exists used to depend on which test files the
+    # xdist worker had run before this one (none when run alone): have
+    # it exist, so the same path runs in every order.
+    if loop.available():
+        loop.poller()
 
     class FakeSock:
+        def fileno(self):
+            return -1           # what a closed socket says
+
         def close(self):
             pass
 

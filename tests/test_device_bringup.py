@@ -176,25 +176,6 @@ def test_dispatch_fault_is_counted(monkeypatch):
     assert device.stats()["faults"]["dispatch:get"] == before + 1
 
 
-# -- bench.py: no chip child under a chip parent -----------------------------
-
-def test_bench_scaling_section_fails_alone_under_a_jax_parent(
-        monkeypatch, capsys):
-    """A parent that has imported JAX holds the chip: the sweep's
-    children cannot have it. That fails the SECTION — an `error` line,
-    a non-zero exit at the end — and the run goes on."""
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setitem(sys.modules, "jax", sys.modules.get("jax", object()))
-    monkeypatch.setattr(bench, "_FAILED_SECTIONS", [])
-    bench._put_scaling()                       # must not raise
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["metric"] == "put_scaling_aggregate_gibps"
-    assert line["value"] is None and "imported JAX" in line["error"]
-    assert bench._FAILED_SECTIONS == ["put_scaling_aggregate_gibps"]
-
-
 # -- no silent interpreter, no JAX in a host process -------------------------
 
 def test_pallas_off_tpu_raises_and_interpret_is_by_name():

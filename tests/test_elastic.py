@@ -171,6 +171,10 @@ def test_parallel_drain_workers(layer, monkeypatch):
     for k, b in bodies.items():
         _, got = layer.get_object("db", k)
         assert got == b
+    # ... and lists exactly once: with the bytes above, the drained
+    # namespace is identical to the one that went in
+    vpage = layer.list_objects("db", max_keys=100, include_versions=True)
+    assert sorted(o.name for o in vpage.objects) == sorted(bodies)
 
 
 # -- coordinator lease ------------------------------------------------------

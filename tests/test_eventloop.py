@@ -37,6 +37,7 @@ from minio_tpu.object.erasure_object import ErasureSet
 from minio_tpu.s3 import eventloop
 from minio_tpu.s3.server import S3Server
 from minio_tpu.storage.local import LocalStorage
+from tests.batcher_rig import until
 from tests.s3client import S3Client, ramp_get
 
 pytestmark = pytest.mark.skipif(not hasattr(select, "epoll"),
@@ -475,19 +476,26 @@ def tiered_srv(tmp_path):
 def test_sendfile_short_circuit_tier_get(tiered_srv):
     server, body = tiered_srv
     cli = S3Client(server.address)
+
+    def paths():
+        return server.metrics.http_conn_stats()["response_path"]
+
     st, _, got = cli.request("GET", "/tb/logs/app")
     assert st == 200 and got == body
-    rp = server.metrics.http_conn_stats()["response_path"]
-    assert rp.get("sendfile", 0) == 1, rp
+    # The path is stamped after the final send returns: the client can
+    # hold the whole body before the server has counted it. Wait for
+    # the count, then hold it to exactly one.
+    until(lambda: paths().get("sendfile", 0) >= 1, "sendfile stamped")
+    assert paths()["sendfile"] == 1, paths()
     # Ranged reads leave the sendfile fast path; since the first GET
     # admitted the object to the hot read tier, the range is sliced
     # from the RAM copy (falls back to pooled windows when it isn't).
     st, _, got = cli.request("GET", "/tb/logs/app",
                              headers={"Range": "bytes=100-199"})
     assert st == 206 and got == body[100:200]
-    rp2 = server.metrics.http_conn_stats()["response_path"]
-    assert rp2["sendfile"] == 1, rp2
-    assert rp2.get("hotcache", 0) + rp2.get("pooled", 0) >= 1, rp2
+    until(lambda: paths().get("hotcache", 0) + paths().get("pooled", 0) >= 1,
+          "the ranged GET's path stamped")
+    assert paths()["sendfile"] == 1, paths()
     # The split is exported.
     text = server.metrics.render()
     assert 'minio_tpu_http_response_path_total{path="sendfile"} 1' in text

@@ -77,9 +77,15 @@ def test_batched_vs_solo_byte_identity(tmp_path):
         d.write_metadata(BKT, "k4", mkfi("k4", t0 - 5))
     fis.append(("k4", mkfi("k4", t0 + 6)))
 
+    info = {}
     res = da.commit_group([GroupOp.write_meta(BKT, k, fi)
-                           for k, fi in fis])
+                           for k, fi in fis], _info=info)
     assert res == [None] * len(fis)
+    # Seven members on four objects: three merge into a batch-mate's
+    # journal, and ONE WAL fdatasync stands for the four journals'
+    # own, so three are saved (a count, not a time).
+    assert (info["objects"], info["merged"]) == (4, 3)
+    assert info["fsyncs_saved"] == 3
     for k, fi in fis:
         db.write_metadata(BKT, k, fi)
     for k in ("k1", "k2", "k3", "k4"):
@@ -319,6 +325,9 @@ def test_concurrent_inline_puts_coalesce_and_roundtrip(tmp_path):
     st = es.group_commit.stats()
     assert st["members"] > 0, "no commit ever rode the lanes"
     assert st["batches"] < st["members"], "no coalescing happened"
+    # Every key is its own object, so a batch of two or more members
+    # is two or more journals under one WAL sync.
+    assert st["fsyncs_saved"] > 0
     for t in (0, 5, 11):
         for i in (0, 14):
             _, data = es.get_object(BKT, f"k-{t}-{i}")

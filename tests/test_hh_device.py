@@ -3,8 +3,8 @@ to the host bitrot layer (and therefore to the reference's golden digests,
 cmd/bitrot.go:225-230).
 
 The Pallas kernels run in interpret mode off-TPU, so shapes here stay
-small; bench.py and the TPU-gated tests exercise the compiled kernels on
-real hardware.
+small; the TPU-gated tests (run this file on the chip) and the benchmark's
+cells exercise the compiled kernels on real hardware.
 """
 
 import numpy as np

@@ -209,3 +209,81 @@ def test_a_served_put_on_a_four_device_mesh_is_upstreams_on_disk(
                                         body)
     assert seen["wrong"] == 0
     assert seen["right"] == drives >= cfg["write_quorum"]
+
+
+# -- one body and one run() per operation, one placement rule (PR 31) ----------
+
+def _framed(k: int, blocks: int, seed: int) -> np.ndarray:
+    """On-disk frames [B, k, 32 + L], the last one with a flipped byte."""
+    data = window(k, blocks, seed)
+    digests = highway.hash256_many(
+        data.reshape(blocks * k, -1)).reshape(blocks, k, 32)
+    framed = np.concatenate([digests, data], axis=2)
+    framed[-1, -1, -1] ^= 1
+    return framed
+
+
+def _operation(name: str):
+    """(the one-chip entry, the mesh entry given devices, an input, the
+    result as an array) of a batched device operation."""
+    from minio_tpu.ops.hh_device import make_deframer, make_mesh_deframer
+    from minio_tpu.ops.rs_device import make_mesh_matrix
+    k, m = 4, 2
+    rows = gf256.parity_matrix(k, m)
+    if name == "frame":
+        return (lambda: make_encode_framer(rows),
+                lambda devs: make_mesh_framer(rows, devices=devs),
+                window(k, 8, seed=34), lambda out: np.concatenate(
+                    as_arrays(out), axis=2))
+    if name == "deframe":
+        return (lambda: make_deframer(k),
+                lambda devs: make_mesh_deframer(k, devices=devs),
+                _framed(k, 8, seed=35), np.asarray)
+    # the matrix route has the one entry: one chip is one device named
+    return (lambda: make_mesh_matrix(rows, devices=jax.devices()[:1]),
+            lambda devs: make_mesh_matrix(rows, devices=devs),
+            window(k, 8, seed=36), np.asarray)
+
+
+@pytest.mark.parametrize("name", ["frame", "deframe", "matrix"])
+def test_the_one_chip_entry_is_the_mesh_entry_at_one_device(name):
+    """Each operation is written once: the one-chip factory is the mesh
+    factory at one device, and on four devices the same run() drives
+    the same body under another placement. Same answers on 1 and 4."""
+    one_chip, mesh, x, as_array = _operation(name)
+    devs = jax.devices()
+    solo, at_one, at_four = one_chip(), mesh(devs[:1]), mesh(devs[:CHIPS])
+    assert solo.mesh_devices == at_one.mesh_devices == 1
+    assert at_four.mesh_devices == CHIPS
+    assert solo.__code__ is at_one.__code__ is at_four.__code__
+    want = as_array(solo(x))
+    assert np.array_equal(as_array(at_one(x)), want)
+    assert np.array_equal(as_array(at_four(x)), want)
+    if name == "deframe":
+        assert not want[-1, -1] and want.sum() == want.size - 1
+    if name == "matrix":
+        b, k, piece = x.shape
+        flat = np.ascontiguousarray(x.transpose(1, 0, 2)).reshape(k, -1)
+        assert np.array_equal(
+            want.transpose(1, 0, 2).reshape(-1, b * piece),
+            gf_rs.encode(flat, k, want.shape[1]))
+
+
+def test_placement_is_one_rule_for_every_operation():
+    """`device.batch_placement` alone builds a mesh, a sharding or a
+    donation: one device gets a plain jit and no mesh object, several
+    the body under shard_map with the batch cut in order."""
+    jit_body, upload, ndev = device.batch_placement(jax.devices()[:1])
+    assert ndev == 1
+    x = np.arange(8 * 3, dtype=np.uint8).reshape(8, 3)
+    step = jit_body(lambda batch, bias: batch + bias)
+    assert np.array_equal(np.asarray(step(upload(x), np.uint8(1))), x + 1)
+    jit_body, upload, ndev = device.batch_placement(jax.devices()[:CHIPS])
+    assert ndev == CHIPS
+    on_mesh = upload(x)
+    assert [s.data.shape for s in on_mesh.addressable_shards] == \
+        [(8 // CHIPS, 3)] * CHIPS
+    step = jit_body(lambda batch, bias: batch + bias)
+    assert np.array_equal(np.asarray(step(on_mesh, np.uint8(1))), x + 1)
+    with pytest.raises(AssertionError, match="not divisible"):
+        upload(x[:6])

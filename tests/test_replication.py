@@ -19,6 +19,7 @@ from minio_tpu.replication.engine import (BreakerOpen, LaneBreaker,
                                           ReplWAL)
 from minio_tpu.s3.server import S3Server
 from minio_tpu.storage.local import LocalStorage
+from tests.batcher_rig import until
 from tests.s3client import S3Client
 
 REPL_XML = b"""<ReplicationConfiguration>
@@ -222,6 +223,34 @@ def test_wal_replay_and_torn_tail(tmp_path):
     assert w3.replay_others() == []
     for w in (w2, w3):
         w.close()
+
+
+def test_probe_that_fails_off_the_wire_gives_the_slot_back(tmp_path):
+    """A half-open probe whose delivery fails WITHOUT a transport fault
+    (here: the source object is gone before the wire is touched) says
+    nothing about the target: the slot goes back at once and the next
+    chain may probe. It used to stay taken for PROBE_TTL (30 s) — the
+    tail of test_chaos_target_kill_restart_converges, whose k0 PUT may
+    still be queued when k0 is deleted."""
+    from minio_tpu.utils import tracing
+    es, eng = _solo_engine(tmp_path)
+    try:
+        eng.enqueue("srcb", "gone", "", "put", mod_time=1)
+        lane = eng._lanes["127.0.0.1:1"]
+        for _ in range(3):
+            lane.breaker.admit()
+            lane.breaker.fault()
+        lane.breaker._open_since -= 100      # the cooldown is over
+        assert lane.breaker.state() == "half-open"
+        eng._service_inner("127.0.0.1:1", ("srcb", "gone"), tracing.NOOP)
+        assert lane.chains[("srcb", "gone")][0].attempt == 1
+        assert lane.breaker.faults_total == 3     # no fault counted
+        assert lane.breaker.state() == "half-open"
+        lane.breaker.admit()                  # the next probe, now
+        with pytest.raises(BreakerOpen):
+            lane.breaker.admit()
+    finally:
+        eng.stop()
 
 
 def _solo_engine(tmp_path, endpoint="127.0.0.1:1", workers=0, **kw):
@@ -710,7 +739,17 @@ def _assert_converged(sc, dc, expect: dict, timeout=60):
             diverged = [("extra-on-target", sorted(extra))]
         last = diverged
         time.sleep(0.5)
-    raise AssertionError(f"divergent objects after chaos: {last}")
+    raise AssertionError(f"divergent objects after chaos: {last}; "
+                         f"source says {_repl_status(sc)}")
+
+
+def _repl_status(client) -> dict:
+    st, _, body = client.request("GET",
+                                 "/minio/admin/v3/replication-status")
+    assert st == 200, body
+    doc = json.loads(body)
+    doc.pop("lag_hist", None)
+    return doc
 
 
 def test_chaos_target_kill_restart_converges(tmp_path):
@@ -734,7 +773,12 @@ def test_chaos_target_kill_restart_converges(tmp_path):
         # A delete during the outage must also converge.
         assert sc.request("DELETE", "/srcb/k0")[0] == 204
         expect["k0"] = None
-        time.sleep(1.0)                  # let retries burn into FAILED
+        # The target comes back only once the source has met the outage
+        # (its lane's breaker has opened: deliveries are parked or
+        # burning retries), however long that takes on a loaded box.
+        until(lambda: sum(ln["breaker_opens"]
+                          for ln in _repl_status(sc)["lanes"]) >= 1,
+              "the source's lane breaker opened")
         dst.restart(0)
         dc = dst.client(0)
         _assert_converged(sc, dc, expect, timeout=90)

@@ -20,6 +20,7 @@ from minio_tpu.s3.server import S3Server
 from minio_tpu.storage.health import wrap_disks
 from minio_tpu.storage.local import LocalStorage
 from minio_tpu.utils import tracing
+from tests.batcher_rig import until
 from tests.s3client import S3Client
 
 MiB = 1 << 20
@@ -321,7 +322,14 @@ def test_a_puts_stages_never_pass_its_own_seconds(tmp_path, small_windows):
         for i in range(3):
             assert cli.request("PUT", f"/bkt/o{i}",
                                body=body_of(9 * MiB))[0] == 200
-        put_s = srv.metrics.state()["latency_hist"]["PUT:object"]["sum"]
+        # A PUT's seconds and stages are credited after its response
+        # is sent: the third is counted once the server has left
+        # `_route`, not when the client has its 200.
+        def puts():
+            return srv.metrics.state()["latency_hist"].get(
+                "PUT:object", {"count": 0, "sum": 0.0})
+        until(lambda: puts()["count"] == 3, "the third PUT is recorded")
+        put_s = puts()["sum"]
     finally:
         srv.stop()
     got = totals_since(before)
