@@ -1783,7 +1783,12 @@ class ErasureSet:
 
                     def gen():
                         while True:
-                            c = qs[i].get()
+                            # The stream is starved: its thread (the
+                            # drive's, inside `disk.stream`) waits for
+                            # the next window's row, i.e. the batcher.
+                            with tracing.stage("disk.stream.row_wait",
+                                               type_="storage", cpu=False):
+                                c = qs[i].get()
                             finish_inflight()
                             if got_sentinel(i, c):
                                 return

@@ -14,8 +14,10 @@ import threading
 import time
 
 from minio_tpu.storage import health as _health
+from minio_tpu.storage import local as _local
 from minio_tpu.utils import tracing as _tracing
-from minio_tpu.utils.latency import Histogram, LastMinute, summarize
+from minio_tpu.utils.latency import (BUCKETS, Histogram, LastMinute,
+                                     summarize)
 
 
 def _process_cpu_seconds() -> float:
@@ -128,6 +130,7 @@ class Metrics:
         out["stages"] = _tracing.stage_totals()
         out["process_cpu_s"] = _process_cpu_seconds()
         out["drive_calls"] = _health.CALL_STATS.snapshot()
+        out["drive_streams"] = _local.STREAM_STATS.snapshot()
         return out
 
     # -- rendering -------------------------------------------------------
@@ -157,7 +160,7 @@ class Metrics:
                 else:
                     lines.append(f"{name} {value}")
 
-        def hist_metric(name, help_, samples):
+        def hist_metric(name, help_, samples, buckets=BUCKETS):
             """Prometheus histogram family: per-label-set cumulative
             `_bucket{le=}` lines plus `_sum`/`_count`. `samples` is
             [(labels, hist_state)]."""
@@ -165,7 +168,7 @@ class Metrics:
             lines.append(f"# TYPE {name} histogram")
             for labels, st in samples:
                 base = ",".join(f'{k}="{v}"' for k, v in labels.items())
-                for le, cum in Histogram.cumulative(st):
+                for le, cum in Histogram.cumulative(st, buckets):
                     lab = f'{base},le="{le}"' if base else f'le="{le}"'
                     lines.append(f"{name}_bucket{{{lab}}} {cum}")
                 suffix = f"{{{base}}}" if base else ""
@@ -202,6 +205,7 @@ class Metrics:
                    "service_hist": Histogram().state()}
         cpu_s = None                # this process's own, read at render
         drive_calls = _health.CALL_STATS.snapshot()
+        drive_streams = _local.STREAM_STATS.snapshot()
         peer_metrics = [p["metrics"] for p in (peer_states or [])
                         if isinstance(p.get("metrics"), dict)]
         # Cluster federation: remote nodes' worker states join the
@@ -252,6 +256,9 @@ class Metrics:
                 cpu_s += st.get("process_cpu_s", 0.0)
                 for k, v in st.get("drive_calls", {}).items():
                     drive_calls[k] = drive_calls.get(k, 0) + v
+            drive_streams = _local.merge_stream_stats(
+                [st["drive_streams"] for st in peer_metrics
+                 if "drive_streams" in st])
             hists = {a: Histogram.merge(sts)
                      for a, sts in hist_states.items()}
             minutes = {a: LastMinute.merge(ws)
@@ -1054,6 +1061,33 @@ class Metrics:
         metric("minio_tpu_drive_call_workers_started_total",
                "Drive-call worker threads started", "counter",
                [({}, drive_calls["workers_started"])])
+        # Inside those calls (storage/local.py): the shard streams, and
+        # the fdatasync each ends with — behind the batcher's pace the
+        # shard files' syncs are what piles up (PERF.md, PR 29 and 32),
+        # and these say so in any scrape. The seconds of a stream's
+        # stages are minio_tpu_stage_seconds_total{stage="disk.stream*"}.
+        def by_kind(d):
+            return [({"kind": k}, d[k]) for k in _local.SYNC_KINDS]
+        metric("minio_tpu_drive_streams_open",
+               "Shard-file writes (create_file calls) in flight", "gauge",
+               [({}, drive_streams["streams_open"])])
+        metric("minio_tpu_drive_syncs_in_flight",
+               "fdatasync calls in flight, of shard files and of "
+               "journals (xl.meta, the group-commit WAL)", "gauge",
+               by_kind(drive_streams["syncs_in_flight"]))
+        hist_metric("minio_tpu_drive_sync_seconds",
+                    "Bucketed duration of one fdatasync",
+                    by_kind(drive_streams["sync_hist"]),
+                    _local.SYNC_BUCKETS)
+        metric("minio_tpu_drive_slow_syncs_total",
+               f"fdatasync calls that took over {_local.SLOW_SYNC_S:g} s",
+               "counter", by_kind(drive_streams["slow_syncs"]))
+        metric("minio_tpu_drive_streams_total",
+               "Shard-file writes completed, by how they were written: "
+               "O_DIRECT, O_DIRECT dropped because the mount refused "
+               "the first write, or buffered", "counter",
+               [({"mode": m}, drive_streams["streams"][m])
+                for m in _local.STREAM_MODES])
 
         # -- read path: quorum-fileinfo cache + fused GET kernel --------
         # Hit rate says whether repeat GETs skip the k-drive metadata

@@ -33,6 +33,8 @@ from typing import Iterator, Optional
 from minio_tpu.storage import meta as metafmt
 from minio_tpu.storage.meta import (FileInfo, FileNotFoundErr, MetaError,
                                     VersionNotFoundErr, XLMeta)
+from minio_tpu.utils import tracing
+from minio_tpu.utils.latency import BUCKETS, Histogram
 
 SYS_VOL = ".mtpu.sys"
 META_FILE = "xl.meta"
@@ -57,6 +59,88 @@ FS_OSYNC = os.environ.get("MTPU_FS_OSYNC", "").lower() in ("1", "on", "true")
 O_DIRECT_ENABLED = hasattr(os, "O_DIRECT") and \
     os.environ.get("MTPU_O_DIRECT", "on").lower() not in ("0", "off",
                                                           "false")
+
+
+# A sync over this long is counted slow: the signature of a run in
+# which the shard files' fdatasync piles up (PERF.md, PR 29: p90 7.5 s).
+SLOW_SYNC_S = 1.0
+# ... and its histogram keeps finite buckets past that tail.
+SYNC_BUCKETS = BUCKETS + (30.0, 60.0)
+SYNC_KINDS = ("shard", "meta")
+STREAM_MODES = ("direct", "direct_dropped", "buffered")
+_SYNC_STAGE = {"shard": "disk.stream.sync", "meta": "disk.meta.sync"}
+# The syncs' clock, looked up at each call (tests step it).
+_now = time.perf_counter
+
+
+class _StreamStats:
+    """Process-wide totals of the shard streams and the syncs below,
+    all drives together: what `minio_tpu_drive_streams_*` and
+    `_sync*` export (s3/metrics.py)."""
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        self.streams_open = 0           # create_file calls in flight
+        self.syncs_in_flight = dict.fromkeys(SYNC_KINDS, 0)
+        self.slow_syncs = dict.fromkeys(SYNC_KINDS, 0)
+        self.streams = dict.fromkeys(STREAM_MODES, 0)
+        self.sync_hist = {k: Histogram(SYNC_BUCKETS) for k in SYNC_KINDS}
+
+    def stream(self, delta: int, mode: str = "") -> None:
+        with self._mu:
+            self.streams_open += delta
+            if mode:
+                self.streams[mode] += 1
+
+    def sync_begin(self, disk, kind: str) -> tuple[int, int]:
+        """-> (syncs in flight in the process, on this drive), before
+        this one joins them."""
+        with self._mu:
+            peers = sum(self.syncs_in_flight.values())
+            peers_drive = disk._syncs_in_flight
+            self.syncs_in_flight[kind] += 1
+            disk._syncs_in_flight += 1
+        return peers, peers_drive
+
+    def sync_end(self, disk, kind: str, seconds: float) -> None:
+        with self._mu:
+            self.syncs_in_flight[kind] -= 1
+            disk._syncs_in_flight -= 1
+            if seconds > SLOW_SYNC_S:
+                self.slow_syncs[kind] += 1
+        self.sync_hist[kind].observe(seconds)
+
+    def snapshot(self) -> dict:
+        with self._mu:
+            out = {"streams_open": self.streams_open,
+                   "syncs_in_flight": dict(self.syncs_in_flight),
+                   "slow_syncs": dict(self.slow_syncs),
+                   "streams": dict(self.streams)}
+        out["sync_hist"] = {k: h.state() for k, h in self.sync_hist.items()}
+        return out
+
+
+STREAM_STATS = _StreamStats()
+
+
+def merge_stream_stats(snapshots) -> dict:
+    """The sum of several processes' STREAM_STATS.snapshot()s."""
+    out = _StreamStats().snapshot()
+    for field, total in out.items():
+        for snap in snapshots:
+            got = snap.get(field)
+            if got is None or field == "sync_hist":
+                continue
+            if isinstance(total, dict):
+                for k in total:
+                    total[k] += got.get(k, 0)
+            else:
+                out[field] += got
+    out["sync_hist"] = {
+        k: Histogram.merge([snap.get("sync_hist", {}).get(k, {})
+                            for snap in snapshots], SYNC_BUCKETS)
+        for k in SYNC_KINDS}
+    return out
 
 
 class StorageError(Exception):
@@ -276,7 +360,7 @@ class LocalStorage:
         with f:
             f.write(data)
             f.flush()
-            os.fdatasync(f.fileno())
+            self._fdatasync(f.fileno(), "meta", len(data))
         try:
             os.replace(tmp, dest)
         except FileNotFoundError:
@@ -381,53 +465,117 @@ class LocalStorage:
         aligned bulk writes O_DIRECT; the ragged tail flips the flag
         off on the SAME fd (the CopyAligned trick); any O_DIRECT
         error falls back to the buffered path. MTPU_O_DIRECT=off
-        disables it outright."""
+        disables it outright.
+
+        The call names its own stages (utils/tracing.stage), one after
+        the other inside `disk.stream`: `disk.stream.open`, then
+        `disk.stream.write` a write and — entered by the caller's
+        iterator where it blocks on its producer — `disk.stream.
+        row_wait`, then `disk.stream.sync`. What they leave of
+        `disk.stream` is the copy into the bounce buffer and the
+        loop's Python. All of them are credited when the stream ends
+        (`request_root`'s cell): a part over the whole is a ratio of
+        whole streams."""
         dest = self._obj_dir(volume, path)
-        os.makedirs(os.path.dirname(dest), exist_ok=True)
-        if O_DIRECT_ENABLED and not isinstance(data, (bytes, bytearray,
-                                                      memoryview)):
+        STREAM_STATS.stream(+1)
+        mode = ""
+        try:
+            with tracing.request_root(), \
+                    tracing.stage("disk.stream", type_="storage"):
+                mode = self._create_file(dest, data)
+        finally:
+            STREAM_STATS.stream(-1, mode)
+
+    def _create_file(self, dest: str, data) -> str:
+        """-> the stream's mode, one of STREAM_MODES."""
+        streaming = not isinstance(data, (bytes, bytearray, memoryview))
+        with tracing.stage("disk.stream.open", type_="storage"):
+            os.makedirs(os.path.dirname(dest), exist_ok=True)
             # The iterator form is the streaming shard path — the one
-            # worth O_DIRECT. Buffered fallback on any failure.
-            if self._create_file_direct(dest, data):
-                return
-            # data may be partially consumed only when the FIRST open
-            # failed (nothing written) — _create_file_direct guarantees
-            # it; resume buffered with the same iterator.
-        with open(dest, "wb") as f:
-            if isinstance(data, (bytes, bytearray, memoryview)):
-                f.write(data)
-            else:
-                for chunk in data:
-                    f.write(chunk)
+            # worth O_DIRECT. Buffered where the open refuses the flag:
+            # nothing is consumed or written by then.
+            direct = self._open_direct(dest) \
+                if O_DIRECT_ENABLED and streaming else None
+            f = open(dest, "wb") if direct is None else None
+        if direct is not None:
+            return self._write_direct(*direct, data)
+        nbytes = 0
+        with f:
+            # One write stage a chunk; the flush of the file object's
+            # own last few KiB is left to the unnamed rest.
+            for chunk in (data if streaming else (data,)):
+                self._staged_write(f.write, chunk)
+                nbytes += memoryview(chunk).nbytes
             f.flush()
-            os.fdatasync(f.fileno())
+            self._fdatasync(f.fileno(), "shard", nbytes)
+        return "buffered"
+
+    @staticmethod
+    def _staged_write(write, view) -> None:
+        # one entry a write, ten to a hundred a stream: wall alone, and
+        # no shared counter (the stage's cell is the thread's own)
+        with tracing.stage("disk.stream.write", type_="storage", cpu=False):
+            write(view)
+
+    # Syncs in flight on this drive; STREAM_STATS's lock guards it.
+    _syncs_in_flight = 0
+
+    def _fdatasync(self, fd: int, kind: str, nbytes: int,
+                   direct: bool = False) -> None:
+        """os.fdatasync(fd) as a stage of its kind (`disk.stream.sync`
+        for a shard file, `disk.meta.sync` for a journal or the WAL),
+        counted in STREAM_STATS. Armed, the span says what one kept
+        trace needs to set a sync's latency against its size and its
+        company: the bytes it covers, the syncs already in flight in
+        the process and on this drive, and whether the aligned part
+        went O_DIRECT."""
+        peers, peers_drive = STREAM_STATS.sync_begin(self, kind)
+        tags = {"bytes": nbytes, "peers": peers, "peers_drive": peers_drive,
+                "direct": direct} if tracing.ACTIVE else None
+        t0 = _now()
+        try:
+            with tracing.stage(_SYNC_STAGE[kind], tags, type_="storage"):
+                os.fdatasync(fd)
+        finally:
+            STREAM_STATS.sync_end(self, kind, _now() - t0)
 
     _ALIGN = 4096
 
-    def _create_file_direct(self, dest: str, chunks) -> bool:
-        """O_DIRECT streaming write; returns False (with NOTHING
-        consumed or written) when O_DIRECT cannot be used here.
+    @staticmethod
+    def _open_direct(dest: str):
+        """-> (fd opened O_DIRECT, the leased bounce buffer), or None
+        where O_DIRECT cannot be used here.
 
         The aligned staging buffer is LEASED from the buffer pool
         (io/bufpool) rather than mmap'd fresh per call — at steady
         state the shard-write path allocates nothing. The lease is
         acquired and released on this thread, so a deadline-abandoned
         health-wrapper call can never leave a recycled buffer exposed."""
-        import fcntl
-
         from minio_tpu.io.bufpool import global_pool
         try:
             fd = os.open(dest, os.O_CREAT | os.O_WRONLY | os.O_TRUNC
                          | os.O_DIRECT, 0o644)
         except (OSError, AttributeError):
-            return False
+            return None
+        try:
+            # Page-aligned (O_DIRECT needs aligned memory; pooled
+            # buffers are mmap pages, so any lease satisfies it).
+            return fd, global_pool().lease(1 << 20)
+        except BaseException:
+            os.close(fd)
+            raise
+
+    def _write_direct(self, fd: int, lease, chunks) -> str:
+        """The O_DIRECT streaming write into `fd`; closes it and
+        releases the lease. -> "direct", or "direct_dropped" where the
+        mount took open(O_DIRECT) and refused the first write."""
+        import fcntl
         align = self._ALIGN
-        # Page-aligned staging buffer (O_DIRECT needs aligned memory;
-        # pooled buffers are mmap pages, so any lease satisfies it).
-        lease = global_pool().lease(1 << 20)
         buf = lease.raw
         fill = 0
+        nbytes = 0
         wrote_any = False
+        refused = False
 
         def write_full(view):
             # os.pwritev-style full write: os.write may write SHORT
@@ -451,7 +599,7 @@ class LocalStorage:
                 nonlocal fill, wrote_any
                 whole = (fill // align) * align
                 if whole:
-                    write_full(memoryview(buf)[:whole])
+                    self._staged_write(write_full, memoryview(buf)[:whole])
                     wrote_any = True
                     rest = bytes(memoryview(buf)[whole:fill])
                     fill = len(rest)
@@ -459,43 +607,41 @@ class LocalStorage:
                     buf.write(rest)
                     buf.seek(0)
 
+            def flush_or_drop():
+                nonlocal wrote_any, refused
+                try:
+                    flush_aligned()
+                except OSError:
+                    if wrote_any:
+                        raise
+                    # First write rejected (FUSE/overlay mounts accept
+                    # open(O_DIRECT) but EINVAL the write): everything
+                    # consumed so far still sits in buf — drop the flag
+                    # and continue buffered on the same fd.
+                    refused = True
+                    drop_direct()
+                    flush_aligned()
+                    wrote_any = True
+
             for chunk in chunks:
                 view = memoryview(chunk)
+                nbytes += view.nbytes
                 while view.nbytes:
                     take = min(view.nbytes, len(buf) - fill)
                     buf[fill:fill + take] = view[:take]
                     fill += take
                     view = view[take:]
                     if fill == len(buf):
-                        try:
-                            flush_aligned()
-                        except OSError:
-                            if wrote_any:
-                                raise
-                            # First write rejected (FUSE/overlay mounts
-                            # accept open(O_DIRECT) but EINVAL the
-                            # write): everything consumed so far still
-                            # sits in buf — drop the flag and continue
-                            # buffered on the same fd.
-                            drop_direct()
-                            flush_aligned()
-                            wrote_any = True
-            try:
-                flush_aligned()
-            except OSError:
-                if wrote_any:
-                    raise
-                drop_direct()
-                flush_aligned()
-                wrote_any = True
+                        flush_or_drop()
+            flush_or_drop()
             if fill:
                 # Ragged tail: drop O_DIRECT on the same fd and write
                 # the remainder buffered (reference CopyAligned's
                 # final unaligned write does the same).
                 drop_direct()
-                write_full(memoryview(buf)[:fill])
-            os.fdatasync(fd)
-            return True
+                self._staged_write(write_full, memoryview(buf)[:fill])
+            self._fdatasync(fd, "shard", nbytes, direct=not refused)
+            return "direct_dropped" if refused else "direct"
         finally:
             os.close(fd)
             lease.release()
@@ -939,7 +1085,7 @@ class LocalStorage:
             view = memoryview(frame)
             while off < len(frame):
                 off += os.write(fd, view[off:])
-            os.fdatasync(fd)
+            self._fdatasync(fd, "meta", len(frame))
             if created:
                 if FS_OSYNC:
                     self._fsync_dir(os.path.dirname(self._gc_wal_path))

@@ -29,18 +29,21 @@ _SLOTS = 60
 
 
 class Histogram:
-    """Fixed-boundary latency histogram (cumulative on render)."""
+    """Fixed-boundary latency histogram (cumulative on render).
+    `buckets` replaces BUCKETS for a series whose tail runs past 10 s;
+    the same bounds then go to merge() and cumulative()."""
 
-    __slots__ = ("_mu", "counts", "sum", "count")
+    __slots__ = ("_mu", "_buckets", "counts", "sum", "count")
 
-    def __init__(self):
+    def __init__(self, buckets: Sequence[float] = BUCKETS):
         self._mu = threading.Lock()
-        self.counts = [0] * (len(BUCKETS) + 1)   # last = overflow (+Inf)
+        self._buckets = buckets
+        self.counts = [0] * (len(buckets) + 1)   # last = overflow (+Inf)
         self.sum = 0.0
         self.count = 0
 
     def observe(self, seconds: float) -> None:
-        i = _bucket_index(seconds)
+        i = _bucket_index(seconds, self._buckets)
         with self._mu:
             self.counts[i] += 1
             self.sum += seconds
@@ -52,8 +55,9 @@ class Histogram:
                     "sum": round(self.sum, 6), "count": self.count}
 
     @staticmethod
-    def merge(states: Sequence[dict]) -> dict:
-        counts = [0] * (len(BUCKETS) + 1)
+    def merge(states: Sequence[dict],
+              buckets: Sequence[float] = BUCKETS) -> dict:
+        counts = [0] * (len(buckets) + 1)
         total_sum, total_count = 0.0, 0
         for st in states:
             for i, c in enumerate(st.get("counts", [])[:len(counts)]):
@@ -64,25 +68,26 @@ class Histogram:
                 "count": total_count}
 
     @staticmethod
-    def cumulative(state: dict) -> list[tuple[str, int]]:
+    def cumulative(state: dict, buckets: Sequence[float] = BUCKETS
+                   ) -> list[tuple[str, int]]:
         """[(le_label, cumulative_count)] including +Inf — the
         Prometheus exposition shape."""
         out = []
         acc = 0
         counts = state.get("counts", [])
-        for i, ub in enumerate(BUCKETS):
+        for i, ub in enumerate(buckets):
             acc += counts[i] if i < len(counts) else 0
             out.append((_le(ub), acc))
-        acc += counts[len(BUCKETS)] if len(counts) > len(BUCKETS) else 0
+        acc += counts[len(buckets)] if len(counts) > len(buckets) else 0
         out.append(("+Inf", acc))
         return out
 
 
-def _bucket_index(seconds: float) -> int:
-    for i, ub in enumerate(BUCKETS):
+def _bucket_index(seconds: float, buckets: Sequence[float] = BUCKETS) -> int:
+    for i, ub in enumerate(buckets):
         if seconds <= ub:
             return i
-    return len(BUCKETS)
+    return len(buckets)
 
 
 def _le(ub: float) -> str:

@@ -518,14 +518,15 @@ def stage_totals() -> dict:
 
 
 class _Stage:
-    __slots__ = ("_name", "_type", "_tags", "_count", "_ann", "_span",
-                 "_t0", "_c0")
+    __slots__ = ("_name", "_type", "_tags", "_count", "_cpu", "_ann",
+                 "_span", "_t0", "_c0")
 
-    def __init__(self, name, tags, type_, count):
+    def __init__(self, name, tags, type_, count, cpu=True):
         self._name = name
         self._type = type_
         self._tags = tags
         self._count = count
+        self._cpu = cpu
         self._ann = None
         self._span = None
 
@@ -544,14 +545,14 @@ class _Stage:
                 self._span = _Span(ctx, self._type, self._name, self._tags)
                 self._span.__enter__()
         if self._count:
-            self._c0 = time.thread_time()
+            self._c0 = time.thread_time() if self._cpu else 0.0
             self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._count:
             wall = time.perf_counter() - self._t0
-            cpu = time.thread_time() - self._c0
+            cpu = time.thread_time() - self._c0 if self._cpu else 0.0
             cells = getattr(_local, "request_cells", None)
             if cells is None:
                 cells = _thread_stage_cells()
@@ -573,7 +574,7 @@ class _RequestRoot:
 
     def __init__(self, name):
         ann = _annotator
-        self._ann = ann(name) if ann is not None else None
+        self._ann = ann(name) if ann is not None and name else None
 
     def __enter__(self):
         if self._ann is not None:
@@ -591,7 +592,7 @@ class _RequestRoot:
         return False
 
 
-def request_root(name: str):
+def request_root(name: Optional[str] = None):
     """The whole request, entered by the server around its handler: a
     profiler annotation of that name (no counter, no span — the
     server publishes the request's record itself, and
@@ -599,22 +600,33 @@ def request_root(name: str):
     that holds the stage seconds of this thread until the request
     ends. Credited then, beside the request's own seconds, a ratio of
     stage seconds to request seconds covers the same requests however
-    many are in flight when it is read."""
+    many are in flight when it is read. Without a name it is the cell
+    alone, around something long that is a stage itself and has stages
+    of its own (one shard stream: `disk.stream` and its parts reach
+    the totals together, so a part over the whole is a ratio of whole
+    streams)."""
     return _RequestRoot(name)
 
 
 def stage(name: str, tags: Optional[dict] = None, type_: str = "s3",
-          count: bool = True):
+          count: bool = True, cpu: bool = True):
     """One boundary of a request's path, entered as a context manager:
     a profiler annotation of the same name when an annotator is
     installed; wall seconds, thread CPU seconds and one entry in the
     per-stage accumulator (always on; `count=False` for boundaries
     another series already counts, e.g. per-drive ops); and, armed
     with a context bound, the span `span(type_, name, tags)` records.
-    With nothing to feed it is the shared no-op."""
+    With nothing to feed it is the shared no-op. `cpu=False` leaves
+    the thread's CPU clock unread (the stage's CPU seconds stay 0):
+    `time.thread_time()` is a system call made with the GIL held, and
+    behind a sandbox that traps every call it is the dearest thing a
+    stage does (6 of 16 us twice over on the chip machine, PERF.md,
+    PR 32) — for stages entered a hundred times a request on threads
+    whose CPU nobody reads. A stopgap: ROADMAP C asks whether CPU
+    seconds become opt-in for the stages whose metric reads them."""
     if not count and _annotator is None and not ACTIVE:
         return NOOP
-    return _Stage(name, tags, type_, count)
+    return _Stage(name, tags, type_, count, cpu)
 
 
 def record(type_: str, name: str, start_wall: float, duration_ms: float,

@@ -358,6 +358,234 @@ def test_worker_states_sum_into_the_fleet_series():
     assert "minio_tpu_process_cpu_seconds_total 5.0" in text
 
 
+def test_a_held_stage_credits_its_parts_when_it_ends():
+    """A nameless `request_root` around a stage: the whole and what
+    this thread entered inside it reach the totals at one instant — a
+    part over the whole is then a ratio of whole things, whatever is
+    in flight at a scrape."""
+    before = tracing.stage_totals()
+    with tracing.request_root(), tracing.stage("t.whole"):
+        for _ in range(3):
+            with tracing.stage("t.part"):
+                pass
+        assert "t.part" not in totals_since(before)
+    got = totals_since(before)
+    assert got["t.whole"][2] == 1 and got["t.part"][2] == 3
+    assert got["t.part"][0] <= got["t.whole"][0]
+    # inside a request they join the request's cell, not the thread's
+    before = tracing.stage_totals()
+    with tracing.request_root("t.request"):
+        with tracing.request_root(), tracing.stage("t.whole"):
+            with tracing.stage("t.part"):
+                pass
+        assert totals_since(before) == {}
+    got = totals_since(before)
+    assert got["t.whole"][2] == got["t.part"][2] == 1
+
+
+def test_a_stage_can_leave_the_cpu_clock_unread():
+    """`cpu=False`: wall seconds and entries as ever, CPU seconds 0 —
+    and the thread's CPU clock is not asked."""
+    before = tracing.stage_totals()
+    real, asked = time.thread_time, []
+    time.thread_time = lambda: asked.append(1) or real()
+    try:
+        with tracing.stage("t.nocpu", cpu=False):
+            sum(range(20000))
+        assert asked == []
+        with tracing.stage("t.cpu"):
+            pass
+        assert len(asked) == 2
+    finally:
+        time.thread_time = real
+    got = totals_since(before)
+    assert got["t.nocpu"][0] > 0 and got["t.nocpu"][2] == 1
+    assert got["t.nocpu"][1] == 0.0 and got["t.cpu"][2] == 1
+
+
+# -- the shard streams' stages (PR 32) ----------------------------------------------
+
+STREAM_PARTS = ("disk.stream.open", "disk.stream.row_wait",
+                "disk.stream.write", "disk.stream.sync")
+
+
+def test_a_streaming_put_names_each_streams_stages(tmp_path, annotator,
+                                                   small_windows):
+    """Two windows on four drives: a stream a drive, each pulling its
+    queue three times (two rows and the sentinel), on the drive's own
+    thread — inside `disk.stream`, one after the other."""
+    from minio_tpu.storage.local import STREAM_STATS
+    es = make_set(tmp_path)
+    before, stats = tracing.stage_totals(), STREAM_STATS.snapshot()
+    me = threading.get_ident()
+    try:
+        es.put_object("bkt", "two-windows", body_of(16 * MiB))
+    finally:
+        es.close()
+    got = totals_since(before)
+    assert got["disk.stream"][2] == got["disk.stream.open"][2] == 4
+    assert got["disk.stream.sync"][2] == 4
+    assert got["disk.stream.row_wait"][2] == 3 * 4
+    assert got["disk.stream.write"][2] >= 4
+    assert got["disk.meta.sync"][2] >= 4       # an xl.meta a drive
+    assert sum(got[n][0] for n in STREAM_PARTS) <= got["disk.stream"][0]
+    by_thread: dict = {}
+    for kind, name, tid, _ in annotator.events:
+        if name.startswith("disk.stream"):
+            by_thread.setdefault(tid, []).append((kind, name))
+    assert len(by_thread) == 4 and me not in by_thread
+    for events in by_thread.values():
+        assert events[0] == ("enter", "disk.stream")
+        assert events[-1] == ("exit", "disk.stream")
+        inner = events[1:-1]
+        # never nested inside each other: every enter is left at once
+        assert all(inner[i][0] == "enter"
+                   and inner[i + 1] == ("exit", inner[i][1])
+                   for i in range(0, len(inner), 2))
+        order = [name for kind, name in inner if kind == "enter"]
+        assert order[0] == "disk.stream.open"
+        assert order[1] == "disk.stream.row_wait"
+        assert order[-1] == "disk.stream.sync"
+        assert order.count("disk.stream.row_wait") == 3
+    after = STREAM_STATS.snapshot()
+    assert sum(after["streams"].values()) - sum(stats["streams"].values()) == 4
+    assert after["streams_open"] == 0
+    assert after["syncs_in_flight"] == {"shard": 0, "meta": 0}
+
+
+def test_a_withheld_row_is_row_wait_and_nothing_else(tmp_path, annotator,
+                                                     small_windows):
+    """The first window is held back until every stream is inside its
+    row wait: what the streams waited is there, not in a write or a
+    sync (stage against stage, and against what the test itself held)."""
+    es = make_set(tmp_path)
+    real = es._frame_windows
+    held = []
+
+    def frame(*a, **kw):
+        if not held:
+            def waiting():
+                names = [(k, n) for k, n, _, _ in annotator.events
+                         if n == "disk.stream.row_wait"]
+                return names.count(("enter", "disk.stream.row_wait")) == 4
+            until(waiting, "four streams wait for their first row")
+            t0 = time.perf_counter()
+            time.sleep(0.3)
+            held.append(time.perf_counter() - t0)
+        return real(*a, **kw)
+    es._frame_windows = frame
+    before = tracing.stage_totals()
+    try:
+        es.put_object("bkt", "held", body_of(16 * MiB))
+    finally:
+        es.close()
+    got = totals_since(before)
+    assert got["disk.stream.row_wait"][0] >= 4 * held[0]
+    assert got["disk.stream"][0] >= got["disk.stream.row_wait"][0]
+    rest = sum(got[n][0] for n in STREAM_PARTS if n != "disk.stream.row_wait")
+    assert got["disk.stream.row_wait"][0] + rest <= got["disk.stream"][0]
+    # a thread that waits burns no CPU
+    assert got["disk.stream.row_wait"][1] < got["disk.stream.row_wait"][0]
+
+
+def test_a_streams_seconds_outlive_its_thread(tmp_path):
+    """The health pool's worker exits when its drive is closed; what its
+    stream entered stays in the totals."""
+    disk = wrap_disks([LocalStorage(str(tmp_path / "d"))])[0]
+    disk.make_vol("v")
+    before = tracing.stage_totals()
+    seen = []
+
+    def chunks():
+        seen.append(threading.current_thread())
+        yield b"x" * (MiB + 5)
+    disk.create_file("v", "f", chunks())
+    disk.close()
+    (worker,) = seen
+    assert worker is not threading.current_thread()
+    worker.join(10)
+    assert not worker.is_alive()
+    got = totals_since(before)
+    assert got["disk.stream"][2] == got["disk.stream.sync"][2] == 1
+    assert got["disk.stream"][0] > 0
+    assert totals_since(before) == got          # retired, and counted once
+
+
+def test_metrics_export_the_drive_stream_series(tmp_path, small_windows):
+    srv = S3Server(make_set(tmp_path), address="127.0.0.1:0")
+    srv.start()
+
+    def scrape(cli):
+        st, _, text = cli.request("GET", "/minio/v2/metrics/cluster",
+                                  sign=False)
+        assert st == 200
+        series = {}
+        for line in text.decode().splitlines():
+            if line.startswith(("minio_tpu_drive_s",
+                                "minio_tpu_stage_entries_total")):
+                key, value = line.rsplit(" ", 1)
+                series[key] = float(value)
+        return series
+    try:
+        cli = S3Client(srv.address)
+        before = scrape(cli)
+        assert cli.request("PUT", "/bkt/o", body=body_of(9 * MiB))[0] == 200
+        series = scrape(cli)
+    finally:
+        srv.stop()
+
+    def gained(key):            # the process has run other files' tests
+        return series[key] - before.get(key, 0)
+    assert series["minio_tpu_drive_streams_open"] == 0
+    for kind in ("shard", "meta"):
+        lab = f'{{kind="{kind}"}}'
+        assert series["minio_tpu_drive_syncs_in_flight" + lab] == 0
+        assert gained("minio_tpu_drive_sync_seconds_count" + lab) >= 4
+        assert gained("minio_tpu_drive_sync_seconds_sum" + lab) > 0
+        assert gained("minio_tpu_drive_slow_syncs_total" + lab) >= 0
+        finite = [float(k.split('le="')[1].rstrip('"}'))
+                  for k in series
+                  if k.startswith("minio_tpu_drive_sync_seconds_bucket"
+                                  f'{{kind="{kind}",le="')
+                  and "+Inf" not in k]
+        assert max(finite) >= 30.0 and len(finite) == len(set(finite)) >= 14
+        assert series["minio_tpu_drive_sync_seconds_bucket"
+                      f'{{kind="{kind}",le="+Inf"}}'] == \
+            series["minio_tpu_drive_sync_seconds_count" + lab]
+    modes = sum(gained(f'minio_tpu_drive_streams_total{{mode="{m}"}}')
+                for m in ("direct", "direct_dropped", "buffered"))
+    assert modes == 4
+    # a sync a stream, and the histogram counts what the stage counts
+    assert gained('minio_tpu_stage_entries_total{stage="disk.stream"}') \
+        == gained('minio_tpu_stage_entries_total{stage="disk.stream.sync"}') \
+        == gained('minio_tpu_drive_sync_seconds_count{kind="shard"}') == 4
+    assert gained('minio_tpu_stage_entries_total{stage="disk.meta.sync"}') \
+        == gained('minio_tpu_drive_sync_seconds_count{kind="meta"}')
+
+
+def test_worker_states_sum_into_the_fleet_drive_series():
+    from minio_tpu.s3.metrics import Metrics
+    from minio_tpu.storage import local as local_mod
+    m = Metrics()
+    one = local_mod._StreamStats()
+    one.stream(+1)
+    one.stream(-1, "direct")
+    one.sync_hist["shard"].observe(45.0)
+    one.slow_syncs["shard"] = 2
+    one.syncs_in_flight["meta"] = 3
+    st = {**m.state(), "drive_streams": one.snapshot()}
+    text = m.render(peer_states=[{"metrics": st}, {"metrics": st},
+                                 {"metrics": {**st, "drive_streams": {}}}])
+    assert 'minio_tpu_drive_streams_total{mode="direct"} 2' in text
+    assert 'minio_tpu_drive_slow_syncs_total{kind="shard"} 4' in text
+    assert 'minio_tpu_drive_syncs_in_flight{kind="meta"} 6' in text
+    assert 'minio_tpu_drive_sync_seconds_bucket{kind="shard",le="30"} 0' \
+        in text
+    assert 'minio_tpu_drive_sync_seconds_bucket{kind="shard",le="60"} 2' \
+        in text
+    assert 'minio_tpu_drive_sync_seconds_sum{kind="shard"} 90.0' in text
+
+
 # -- the real profiler -----------------------------------------------------------
 
 def test_program_stages_are_in_a_jax_profiler_trace(tmp_path, monkeypatch,

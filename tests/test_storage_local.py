@@ -228,3 +228,274 @@ def test_read_file_odirect_missing_file_raises(tmp_path):
     d.make_vol("v")
     with pytest.raises(FileNotFoundErr):
         d.read_file("v", "nope", offset=0, length=4 << 20)
+
+
+# -- the shard stream's stages and counters (PR 32) ------------------------------
+
+import errno  # noqa: E402
+import threading  # noqa: E402
+
+from minio_tpu.storage import local as local_mod  # noqa: E402
+from minio_tpu.utils import tracing  # noqa: E402
+
+MiB = 1 << 20
+STREAM = "disk.stream"
+PARTS = ("disk.stream.open", "disk.stream.row_wait", "disk.stream.write",
+         "disk.stream.sync")
+# 2 MiB + 300,133 bytes: two full bounce buffers, an aligned rest, a tail
+CHUNKS = [b"a" * MiB, b"b" * MiB, b"c" * 300_000, b"d" * 133]
+
+
+def stages_since(before: dict) -> dict:
+    """{stage: (wall s, entries)} gained since `before`."""
+    out = {}
+    for name, (wall, _, n) in tracing.stage_totals().items():
+        w0, _, n0 = before.get(name, (0.0, 0.0, 0))
+        if n > n0:
+            out[name] = (wall - w0, n - n0)
+    return out
+
+
+def streams_since(before: dict) -> dict:
+    now = local_mod.STREAM_STATS.snapshot()["streams"]
+    return {m: now[m] - before["streams"][m] for m in now
+            if now[m] != before["streams"][m]}
+
+
+def in_flight() -> tuple:
+    snap = local_mod.STREAM_STATS.snapshot()
+    return snap["streams_open"], snap["syncs_in_flight"]
+
+
+IDLE = (0, {"shard": 0, "meta": 0})
+
+
+class DirectFds:
+    """Stands under `os.open` and `os.write`, passing everything
+    through, so that a test can say what the mount does with O_DIRECT
+    whatever filesystem the suite runs on: `open` never refuses the
+    flag (it is retried without), and the nth `os.write` to a file that
+    was opened with it fails with `fail_with`."""
+
+    def __init__(self, monkeypatch, fail_write: int = 0,
+                 fail_with: int = errno.EIO):
+        self.fds: dict = {}             # fd -> writes so far
+        self.fail_write, self.fail_with = fail_write, fail_with
+        real_open, real_write = os.open, os.write
+
+        def open_(path, flags, *a, **kw):
+            if not flags & os.O_DIRECT:
+                return real_open(path, flags, *a, **kw)
+            try:
+                fd = real_open(path, flags, *a, **kw)
+            except OSError:
+                fd = real_open(path, flags & ~os.O_DIRECT, *a, **kw)
+            self.fds[fd] = 0
+            return fd
+
+        def write(fd, data):
+            if fd in self.fds:
+                self.fds[fd] += 1
+                if self.fds[fd] == self.fail_write:
+                    raise OSError(self.fail_with, "injected")
+            return real_write(fd, data)
+
+        monkeypatch.setattr(os, "open", open_)
+        monkeypatch.setattr(os, "write", write)
+
+
+def expected_writes(mode: str, chunks) -> int:
+    """direct: one write a full bounce buffer, one for the aligned rest,
+    one for the ragged tail; buffered: one a chunk."""
+    if mode == "buffered":
+        return len(chunks)
+    total = sum(map(len, chunks))
+    aligned = total // 4096 * 4096
+    return -(-aligned // MiB) + (1 if total > aligned else 0)
+
+
+@pytest.mark.parametrize("form", ["iterator", "bytes"])
+@pytest.mark.parametrize("o_direct", [True, False], ids=["direct", "buffered"])
+def test_one_stream_enters_each_stage_the_counted_number_of_times(
+        tmp_path, monkeypatch, o_direct, form):
+    monkeypatch.setattr(local_mod, "O_DIRECT_ENABLED", o_direct)
+    d = LocalStorage(str(tmp_path / "d"))
+    d.make_vol("v")
+    chunks = CHUNKS if form == "iterator" else [b"".join(CHUNKS)]
+    before, stats = tracing.stage_totals(), local_mod.STREAM_STATS.snapshot()
+    d.create_file("v", "f", iter(chunks) if form == "iterator" else chunks[0])
+    got = stages_since(before)
+    modes = streams_since(stats)
+    assert sum(modes.values()) == 1
+    (mode,) = modes
+    if not o_direct or form == "bytes":
+        assert mode == "buffered"
+    assert got[STREAM][1] == got["disk.stream.open"][1] == 1
+    assert got["disk.stream.sync"][1] == 1
+    assert got["disk.stream.write"][1] == expected_writes(mode, chunks)
+    assert "disk.stream.row_wait" not in got      # no queue to wait on
+    assert "disk.meta.sync" not in got
+    # one after the other inside the whole: together no longer than it
+    assert sum(got[p][0] for p in PARTS if p in got) <= got[STREAM][0]
+    assert d.read_file("v", "f") == b"".join(chunks)
+    after = local_mod.STREAM_STATS.snapshot()
+    assert after["sync_hist"]["shard"]["count"] \
+        - stats["sync_hist"]["shard"]["count"] == 1
+    assert in_flight() == IDLE
+
+
+@pytest.mark.parametrize("forced", ["direct", "direct_dropped",
+                                    "open_refused", "switched_off"])
+def test_streams_total_says_how_the_file_was_written(tmp_path, monkeypatch,
+                                                     forced):
+    d = LocalStorage(str(tmp_path / "d"))
+    d.make_vol("v")
+    if forced == "direct":
+        DirectFds(monkeypatch)
+    elif forced == "direct_dropped":
+        # the mount takes open(O_DIRECT) and refuses the first write
+        DirectFds(monkeypatch, fail_write=1, fail_with=errno.EINVAL)
+    elif forced == "open_refused":
+        monkeypatch.setattr(LocalStorage, "_open_direct",
+                            staticmethod(lambda dest: None))
+    else:
+        monkeypatch.setattr(local_mod, "O_DIRECT_ENABLED", False)
+    before, stats = tracing.stage_totals(), local_mod.STREAM_STATS.snapshot()
+    d.create_file("v", "f", iter(CHUNKS))
+    want = forced if forced.startswith("direct") else "buffered"
+    assert streams_since(stats) == {want: 1}
+    got = stages_since(before)
+    # a refused write is entered again: one entry more
+    assert got["disk.stream.write"][1] == expected_writes(want, CHUNKS) \
+        + (forced == "direct_dropped")
+    assert got["disk.stream.open"][1] == got["disk.stream.sync"][1] == 1
+    assert d.read_file("v", "f") == b"".join(CHUNKS)
+    assert in_flight() == IDLE
+
+
+def raising_chunks():
+    yield CHUNKS[0]
+    yield CHUNKS[1]
+    raise RuntimeError("the producer died")
+
+
+@pytest.mark.parametrize("fault", ["iterator_raises", "write_fails",
+                                   "sync_fails"])
+@pytest.mark.parametrize("o_direct", [True, False], ids=["direct", "buffered"])
+def test_no_count_leaks_from_a_stream_that_fails(tmp_path, monkeypatch,
+                                                 o_direct, fault):
+    monkeypatch.setattr(local_mod, "O_DIRECT_ENABLED", o_direct)
+    d = LocalStorage(str(tmp_path / "d"))
+    d.make_vol("v")
+    before, stats = tracing.stage_totals(), local_mod.STREAM_STATS.snapshot()
+    chunks, raised = iter(CHUNKS), OSError
+    if fault == "iterator_raises":
+        chunks, raised = raising_chunks(), RuntimeError
+    elif fault == "sync_fails":
+        def fdatasync(fd):
+            assert in_flight()[1]["shard"] == 1
+            raise OSError(errno.EIO, "injected")
+        monkeypatch.setattr(os, "fdatasync", fdatasync)
+    elif o_direct:
+        # the second write: the first has landed, so nothing falls back
+        DirectFds(monkeypatch, fail_write=2)
+    else:
+        # the file object refuses what is no buffer, inside the stage
+        chunks, raised = iter([CHUNKS[0], "not bytes"]), TypeError
+    with pytest.raises(raised):
+        d.create_file("v", "f", chunks)
+    assert in_flight() == IDLE
+    assert streams_since(stats) == {}              # completed streams only
+    got = stages_since(before)
+    assert got[STREAM][1] == got["disk.stream.open"][1] == 1
+    assert ("disk.stream.sync" in got) == (fault == "sync_fails")
+    # and the next stream on the same drive is counted as ever
+    monkeypatch.undo()
+    d.create_file("v", "g", iter(CHUNKS))
+    assert sum(streams_since(stats).values()) == 1
+    assert in_flight() == IDLE
+
+
+@pytest.mark.parametrize("kind", ["shard", "meta"])
+def test_a_sync_over_the_threshold_is_counted_slow(tmp_path, monkeypatch,
+                                                   kind):
+    """The clock is the test's: no second is slept."""
+    d = LocalStorage(str(tmp_path / "d"))
+    d.make_vol("v")
+    clock = [100.0]
+    took = [0.2]
+    monkeypatch.setattr(local_mod, "_now", lambda: clock[0])
+    real = os.fdatasync
+
+    def fdatasync(fd):
+        real(fd)
+        clock[0] += took[0]
+    monkeypatch.setattr(os, "fdatasync", fdatasync)
+
+    def one():
+        if kind == "shard":
+            d.create_file("v", "f", iter(CHUNKS))
+        else:
+            d.write_all("v", "cfg.json", b"{}")
+        snap = local_mod.STREAM_STATS.snapshot()
+        return snap["slow_syncs"], snap["sync_hist"][kind]
+    other = "meta" if kind == "shard" else "shard"
+    slow0, hist0 = one()
+    took[0] = local_mod.SLOW_SYNC_S + 0.5
+    slow1, hist1 = one()
+    assert slow1[kind] == slow0[kind] + 1 and slow1[other] == slow0[other]
+    took[0] = 45.0                  # past the API histograms' 10 s
+    slow2, hist2 = one()
+    assert slow2[kind] == slow1[kind] + 1
+    assert hist2["count"] == hist0["count"] + 2
+    assert hist2["sum"] == pytest.approx(hist0["sum"] + 46.5)
+    le = dict(local_mod.Histogram.cumulative(hist2, local_mod.SYNC_BUCKETS))
+    le0 = dict(local_mod.Histogram.cumulative(hist0, local_mod.SYNC_BUCKETS))
+    assert le["30"] - le0["30"] == 1 and le["60"] - le0["60"] == 2
+    assert le["1"] - le0["1"] == 0
+
+
+def test_a_sync_says_its_company_when_armed(tmp_path, monkeypatch):
+    """The span of a sync carries the bytes it covers and the syncs
+    already in flight, in the process and on its drive."""
+    drives = [LocalStorage(str(tmp_path / f"d{i}")) for i in range(2)]
+    for d in drives:
+        d.make_vol("v")
+    inside, release = threading.Event(), threading.Event()
+    real = os.fdatasync
+    first = [True]
+
+    def fdatasync(fd):
+        if first[0]:
+            first[0] = False
+            inside.set()
+            assert release.wait(30)
+        real(fd)
+    monkeypatch.setattr(os, "fdatasync", fdatasync)
+    ctx = tracing.TraceContext()
+    tracing.arm("test")
+    try:
+        def held():
+            with tracing.bind(ctx):
+                drives[0].create_file("v", "held", iter(CHUNKS))
+        t = threading.Thread(target=held)
+        t.start()
+        assert inside.wait(30)
+        assert in_flight() == (1, {"shard": 1, "meta": 0})
+        with tracing.bind(ctx):
+            drives[0].write_all("v", "a.json", b"{}")       # same drive
+            drives[1].create_file("v", "f", b"x" * 5000)    # another
+        release.set()
+        t.join(30)
+    finally:
+        release.set()
+        tracing.disarm("test")
+    syncs = {s["name"] + str(s["tags"]["bytes"]): s["tags"]
+             for s in ctx.spans if s["name"].endswith(".sync")}
+    total = sum(map(len, CHUNKS))
+    assert syncs[f"disk.stream.sync{total}"]["peers"] == 0
+    assert syncs["disk.meta.sync2"] == {
+        "bytes": 2, "peers": 1, "peers_drive": 1, "direct": False}
+    assert syncs["disk.stream.sync5000"] == {
+        "bytes": 5000, "peers": 1, "peers_drive": 0, "direct": False}
+    assert in_flight() == IDLE

@@ -165,6 +165,10 @@ def traced_set(tmp_path):
     es.close()
 
 
+def _stage_inside_a_drive_call(name):
+    return name.startswith("disk.stream") or name == "disk.meta.sync"
+
+
 def _span_index(ctx):
     return {s["span"]: s for s in ctx.spans}
 
@@ -185,7 +189,12 @@ def test_span_tree_linkage_put_get(traced_set):
                              (ctx_get, "mtpu_get_frame")):
         by_id = _span_index(ctx)
         engine = [s for s in ctx.spans if s["name"] == "engine.op"]
-        disk = [s for s in ctx.spans if s["name"].startswith("disk.")]
+        # `disk.<op>` is a drive call; `disk.stream*` and
+        # `disk.meta.sync` are the stages inside one (storage/local.py)
+        disk = [s for s in ctx.spans if s["name"].startswith("disk.")
+                and not _stage_inside_a_drive_call(s["name"])]
+        inside = [s for s in ctx.spans
+                  if _stage_inside_a_drive_call(s["name"])]
         assert engine and disk, ctx.spans
         # Engine spans hang off the root; every disk op is a child of
         # an engine span on the SAME drive queue, and carries the
@@ -196,6 +205,15 @@ def test_span_tree_linkage_put_get(traced_set):
         for s in disk:
             parent = by_id[s["parent"]]
             assert parent["name"] == "engine.op", s
+        for s in inside:
+            up = by_id[s["parent"]]
+            while up in inside:
+                up = by_id[up["parent"]]
+            assert up in disk, s
+        if ctx is ctx_put:
+            assert {s["name"] for s in inside} >= {
+                "disk.stream", "disk.stream.open", "disk.stream.write",
+                "disk.stream.sync", "disk.meta.sync"}
         if native.load() is not None:
             kernels = [s for s in ctx.spans if s["type"] == "kernel"]
             assert [s["name"] for s in kernels] == [kernel_name]
@@ -482,7 +500,10 @@ def test_admin_trace_internal_types_and_linkage(srv):
         # double-publish a span under the same trace/span id).
         assert len(ids) == len(kids) + 1
         engine_ids = {e["span"] for e in kids if e["api"] == "engine.op"}
-        disk = [e for e in kids if e["api"].startswith("disk.")]
+        # the drive calls; the stages inside one (`disk.stream*`,
+        # `disk.meta.sync`) hang below them
+        disk = [e for e in kids if e["api"].startswith("disk.")
+                and not _stage_inside_a_drive_call(e["api"])]
         assert disk and all(e["parent"] in engine_ids for e in disk)
 
 
