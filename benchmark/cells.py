@@ -4,6 +4,8 @@ a file of its own that this module finds by that name:
 
     workloads[].name "<config>.<traffic>"
         -> benchmark/configs/<config>.json, benchmark/traffic/<traffic>.json
+           (+ configs/<config>.py where the deployment needs code of
+           its own: `after_preload`, `reference_shard_files`)
     end_to_end[].name "<metric>"
         -> benchmark/end_to_end/<metric>.json (which quantity of the
            run's own operation log it is)
@@ -20,6 +22,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import re
 
 from benchmark import traffic
 
@@ -37,6 +40,34 @@ def load_config(name: str) -> dict:
         return json.load(f)
 
 
+def _load_module(kind: str, name: str):
+    """benchmark/<kind>/<name>.py as a module of its own, or None."""
+    path = os.path.join(HERE, kind, f"{name}.py")
+    if not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{kind}_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_config_module(name: str):
+    """The configuration's own code, where it brings any
+    (benchmark/configs/<name>.py), else None. `run.py` calls what the
+    module has of:
+
+        after_preload(srv, cfg, cli)      between the preload and the
+            warm-up ladder, on the live server (`srv`: server.Server,
+            `cli`: its S3 client): where a degraded deployment loses
+            its drives;
+        reference_shard_files(body, cfg) -> the n shard files the
+            drives must hold for `body`, in place of
+            compare.reference_shard_files (an encrypted bucket's, or a
+            geometry whose k does not divide the erasure block)."""
+    return _load_module("configs", name)
+
+
 def load_peaks() -> dict:
     with open(os.path.join(HERE, "peaks.json")) as f:
         return json.load(f)
@@ -49,6 +80,7 @@ def load_cell(name: str, bench: dict | None = None) -> dict:
         raise KeyError(f"no cell {name!r} in BENCHMARK.json "
                        f"(have: {[w['name'] for w in bench['workloads']]})")
     return {"cell": cell, "config": load_config(cell["config"]),
+            "module": load_config_module(cell["config"]),
             "mix": traffic.load_mix(cell["traffic"]), "bench": bench}
 
 
@@ -66,11 +98,7 @@ def load_layer(name: str) -> dict:
     one (benchmark/layers/<name>.py: read(ctx, spec) -> number|None)."""
     with open(os.path.join(HERE, "layers", f"{name}.json")) as f:
         spec = json.load(f)
-    path = os.path.join(HERE, "layers", f"{name}.py")
-    if os.path.exists(path):
-        mod_spec = importlib.util.spec_from_file_location(
-            "benchmark_layer_" + name.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
+    mod = _load_module("layers", name)
+    if mod is not None:
         spec["read"] = mod.read
     return spec

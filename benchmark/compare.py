@@ -57,12 +57,16 @@ def shard_files_on_disk(drive_root: str, drives: int, bucket: str,
 
 
 def check_object_on_disk(drive_root: str, cfg: dict, bucket: str, key: str,
-                         body: bytes, parity_rows=None) -> dict:
+                         body: bytes, parity_rows=None,
+                         reference=None) -> dict:
     """-> {"right": drives holding a right shard, "wrong": files that
-    are no shard of this object or a shard met twice}."""
+    are no shard of this object or a shard met twice}. `reference` is
+    a configuration's own `reference_shard_files(body, cfg)`, where it
+    brings one (benchmark/configs/<name>.py)."""
     k, m = cfg["data_shards"], cfg["parity_shards"]
-    want = reference_shard_files(body, k, m, cfg["erasure_block_bytes"],
-                                 parity_rows)
+    want = reference(body, cfg) if reference is not None else \
+        reference_shard_files(body, k, m, cfg["erasure_block_bytes"],
+                              parity_rows)
     seen: set[int] = set()
     right = wrong = 0
     for path in shard_files_on_disk(drive_root, cfg["drives"], bucket,

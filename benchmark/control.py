@@ -8,6 +8,9 @@ PERF.md.
 
     python3 benchmark/control.py --workload <cell> --seeds 11,12,13 \\
         --seconds 10 --faults none,wrong_matrix,below_quorum
+
+and, for a cell that reads, `flip_get_byte` and `deaf_deframer` among
+the faults.
 """
 
 from __future__ import annotations

@@ -12,7 +12,11 @@ N starts its loop at t_go + w/N of the mix's `stagger_s`, so that the
 workers' operations do not begin and end in step — the stretch up to
 the window is the ramp — and no operation is started after t1; the one
 in flight is finished, however long it takes. The answer is every operation of the run:
-[op, key, t_send, t_done, verdict, bytes, detail].
+[op, key, t_send, t_done, verdict, bytes, detail], and under `cpu` this
+process's own CPU seconds beside the wall seconds between the run's `t0`
+(the window's first instant; `t_go` where none is given) and `t1`: the
+generators receive and compare every byte they asked for on the
+server's cores, and a line has to say when that is what binds.
 
 verdict: "ok" | "wrong" (an answer that says the wrong thing) |
 "refused" (an error status: an honest no) | "never" (no answer).
@@ -42,7 +46,7 @@ class Worker:
         self.buf = bytearray(self.mix["size"])
         self.live: list[str] = []
         self.deleted: list[str] = []
-        self.n_keys = 0
+        self.n_keys = self.n_reads = 0
         self.rng = random.Random(f"{spec['seed']}/{wid}/keys")
         self.sched = traffic.Schedule(self.mix, spec["seed"], wid)
         self.log: list = []
@@ -101,6 +105,16 @@ class Worker:
         raise ValueError(op)
 
     def read_key(self) -> str:
+        """The key of a GET or STAT: drawn uniformly from the preloaded
+        keys and this worker's live ones; or, where the mix says `read`
+        "own_in_order" (speedtest: each thread reads back its own
+        uploads), the preloaded keys this worker wrote, one after the
+        other, round and round."""
+        if self.mix.get("read") == "own_in_order":
+            own = range(self.wid, self.mix["preload"],
+                        self.spec["workers_total"])
+            self.n_reads += 1
+            return traffic.pre_key(own[(self.n_reads - 1) % len(own)])
         n = self.mix["preload"] + len(self.live)
         i = self.rng.randrange(n)
         return traffic.pre_key(i) if i < self.mix["preload"] \
@@ -200,6 +214,15 @@ def _all(workers, fn, args=None) -> None:
         raise RuntimeError("; ".join(errs))
 
 
+def _cpu_between(t0: float, t1: float, out: dict) -> None:
+    """This process's CPU seconds (every thread, user + system) and
+    the wall seconds, from t0 to t1."""
+    time.sleep(max(0.0, t0 - time.monotonic()))
+    c0, w0 = time.process_time(), time.monotonic()
+    time.sleep(max(0.0, t1 - time.monotonic()))
+    out.update(cpu_s=time.process_time() - c0, wall_s=time.monotonic() - w0)
+
+
 def main() -> int:
     spec = json.loads(sys.stdin.readline())
     mix = spec["mix"]
@@ -221,11 +244,18 @@ def main() -> int:
                      [(req["op"], req["n"], req["at"])] * len(workers))
                 reply = {"ready": True}
             elif req["cmd"] == "run":
+                cpu: dict = {}
+                meter = threading.Thread(
+                    target=_cpu_between,
+                    args=(req.get("t0", req["t_go"]), req["t1"], cpu))
+                meter.start()
                 _all(workers, Worker.run,
                      [(req["t_go"], req["t1"])] * len(workers))
                 _all(workers, Worker.after)
+                meter.join()
                 reply = {"ops": [e for w in workers for e in w.log],
-                         "live": {str(w.wid): w.live for w in workers}}
+                         "live": {str(w.wid): w.live for w in workers},
+                         "cpu": cpu}
             elif req["cmd"] == "exit":
                 break
             else:

@@ -153,6 +153,27 @@ def end_to_end(name: str, c: dict) -> float:
 WARM_KEY = "warm/0000"
 MAX_BOOTS = 3
 PROGRAMS = "minio_tpu_batcher_bucket_dispatches_total"
+BODY_OPS = ("PUT", "GET")
+DEMOTED = ("minio_tpu_get_kernel_windows_total", {"path": "demoted"})
+
+
+def plant_rot(srv, cfg: dict, key: str, body: bytes) -> str:
+    """Bitrot on a drive: turn one bit of the first payload byte of
+    DATA shard 0 of `key` where it lies (a healthy read fetches the
+    data shards alone, so rot in a parity shard would be seen by
+    nobody). The file is the one whose first frame, behind its 32
+    digest bytes, holds the body's first erasure_block_bytes / k
+    bytes. -> its path."""
+    piece = cfg["erasure_block_bytes"] // cfg["data_shards"]
+    for path in compare.shard_files_on_disk(
+            srv.drive_root, cfg["drives"], BUCKET, key).values():
+        with open(path, "r+b") as f:
+            f.seek(32)
+            if f.read(piece) == body[:piece]:
+                f.seek(32)
+                f.write(bytes([body[0] ^ 0x01]))
+                return path
+    raise ServerError(f"no drive holds data shard 0 of {key}")
 
 
 def programs_met(scraped: dict) -> set:
@@ -170,20 +191,24 @@ def warm_ladder(cli, gens, mix: dict) -> None:
     arrive is dispatched alone and the rest wait together while the
     lane is busy, so the rungs walk through the batch sizes from one
     window up; the ramp's own start (all workers at once) is the top
-    one. The ladder is the mix's data and knows nothing of the
+    one. A rung is sent once with each operation of the mix's cycle
+    that carries a body (PUT, GET): every route has programs of its
+    own. The ladder is the mix's data and knows nothing of the
     program's sizes; what the window still met first is said in the
     result line (`buckets_first_used_in_window`). Nothing here is
     timed; all of it is judged."""
     seen = programs_met(scrape(cli))
+    ops = [op for op in BODY_OPS if mix["cycle"].get(op)]
     for n in range(1, min(mix["warm_ladder"], mix["workers"]) + 1):
-        gens.send({"cmd": "burst", "op": "PUT", "n": n,
-                   "at": time.monotonic() + 0.3})
-        gens.collect()
-        now = programs_met(scrape(cli))
-        if now - seen:
-            log(f"ladder rung {n}: first use of "
-                f"{sorted(_label(k) for k in now - seen)}")
-        seen |= now
+        for op in ops:
+            gens.send({"cmd": "burst", "op": op, "n": n,
+                       "at": time.monotonic() + 0.3})
+            gens.collect()
+            now = programs_met(scrape(cli))
+            if now - seen:
+                log(f"ladder rung {n}: first use of "
+                    f"{sorted(_label(k) for k in now - seen)}")
+            seen |= now
 
 
 def _label(key) -> str:
@@ -208,23 +233,31 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     """-> (the result line's object, whether a chip was seen). `hooks`
     is for benchmark/control.py and the tests: `allow_platform` (skip
     the look for a chip), `server_env`, `before_disk_check`
-    (fn(server, cfg, sample keys, bodies)), `mix` (overrides)."""
+    (fn(server, cfg, sample keys, bodies)), `mix` (overrides), `proxy`
+    (fn(server address) -> what the generators talk to instead:
+    `.address`, `.arm()` at the window's first instant, `.close()`),
+    `launcher` (the script that runs the server's `main()`, in place of
+    serve_traced.py)."""
     hooks = hooks or {}
     loaded = cells.load_cell(name)
     cell, cfg, bench = loaded["cell"], loaded["config"], loaded["bench"]
+    module = loaded["module"]
     mix = {**loaded["mix"], **hooks.get("mix", {})}
     if not os.path.isfile(os.path.join(ROOT, "minio_tpu", "server.py")):
         raise NoAccelerator("no minio_tpu/ in this directory: nothing to "
                             "measure")
     workdir = tempfile.mkdtemp(prefix="mtpu-bench-")
-    srv = gens = None
+    srv = gens = proxy = None
     t_spawn = time.monotonic()
     try:
         for boots in range(1, MAX_BOOTS + 1):
             srv = Server(cfg["server_argv"], cfg["drives"],
                          os.path.join(workdir, f"boot{boots}"),
-                         hooks.get("server_env"))
-            gens = Generators(srv.address, seed, mix)   # build their bodies
+                         hooks.get("server_env"), hooks.get("launcher"))
+            if "proxy" in hooks:
+                proxy = hooks["proxy"](srv.address)
+            gens = Generators(proxy.address if proxy else srv.address,
+                              seed, mix)            # build their bodies
             srv.wait_ready(900)                     # while the server boots
             log(srv.boot_line)
             cli = srv.client()
@@ -260,6 +293,19 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                 raise ServerError(
                     f"first PUT: HTTP {st} {bytes(data)[:200]!r}")
             dev = settle(cli)
+            quiet_wrong = 0
+            if mix["cycle"].get("GET"):
+                # A mix that reads: the `get` route gets its first
+                # device-sized windows the same way, from one quiet GET
+                # of that object once the `put` route's probe is done,
+                # and the boot-again rule below sees its verdict too.
+                st, _, data = cli.request("GET", f"/{BUCKET}/{WARM_KEY}")
+                if st != 200:
+                    raise ServerError(
+                        f"first GET: HTTP {st} {bytes(data)[:200]!r}")
+                quiet_wrong = int(bytes(data) != bodies.body(WARM_KEY))
+                del data
+                dev = settle(cli)
             on_host = [c.get("route") for c in dev.get("calibration", [])
                        if str(c.get("verdict")).endswith("host")]
             if not on_host or boots == MAX_BOOTS:
@@ -277,11 +323,45 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                 "booting again")
             gens.close()
             gens = None
+            if proxy is not None:
+                proxy.close()
             srv.stop()
         gens.send({"cmd": "preload"})
         gens.collect()
         log(f"preloaded {mix['preload']} objects")
+        # A deployment's drives rot: after the read-back (every object
+        # has been verified once), one bit of a data shard of each
+        # object the mix lists under `rotten` is turned where it lies.
+        # The program has to notice on the next read and serve the
+        # right bytes all the same; DEMOTED counts the windows whose
+        # verify said so. Those objects are first read by the ladder
+        # (the mix picks the rung), so the rebuild path's programs and
+        # the heal that follows are set-up's, not the window's.
+        rotten = [traffic.pre_key(i) for i in mix.get("rotten", [])]
+        before_rot = scrape(cli) if rotten else {}
+        for key in rotten:
+            log("rot planted in " + os.path.relpath(
+                plant_rot(srv, cfg, key, bodies.body(key)), srv.drive_root))
+        if hasattr(module, "after_preload"):
+            module.after_preload(srv, cfg, cli)
         warm_ladder(cli, gens, mix)
+        # the heal that follows a rotten read that was noticed runs in
+        # the background (and, in a checkout's first run, compiles): it
+        # is set-up's too, so the ramp waits for it, a minute at the most
+        healed = "minio_tpu_mrf_healed_total"
+
+        def since_rot(now: dict, series: str, labels=None) -> float:
+            return readers.series_sum(now, series, labels) \
+                - readers.series_sum(before_rot, series, labels)
+        t_heal = time.monotonic()
+        deadline = t_heal + 60.0
+        while rotten and time.monotonic() < deadline:
+            now = scrape(cli)
+            if since_rot(now, healed) >= min(len(rotten),
+                                             since_rot(now, *DEMOTED)):
+                break
+            time.sleep(0.5)
+        heal_wait_s = time.monotonic() - t_heal
         log("calibration: " + json.dumps(
             [{k: c.get(k) for k in ("route", "verdict", "device_ms",
                                     "host_ms")}
@@ -289,8 +369,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         t_go = time.monotonic()
         t0 = t_go + mix["ramp_s"]
         t1 = t0 + seconds
-        gens.send({"cmd": "run", "t_go": t_go, "t1": t1})
+        gens.send({"cmd": "run", "t_go": t_go, "t0": t0, "t1": t1})
         time.sleep(max(0.0, t0 - time.monotonic()))
+        if proxy is not None:
+            proxy.arm()
         ctx = {"scrape_a": scrape(cli)}
         scrape_s = [time.monotonic() - t0]
         if trace:
@@ -345,7 +427,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         # -- the drives, after the clean stop ------------------------------
         in_window = [e[1] for e in ops if e[0] == "PUT" and e[4] == "ok"
                      and t0 <= e[3] <= t1 and e[1] in expected]
-        pool = sorted(in_window) or sorted(expected)
+        pool = sorted(in_window) or sorted(set(expected) - set(rotten))
         sample = random.Random(f"{seed}/disk").sample(
             pool, min(mix["disk_sample"], len(pool)))
         if "before_disk_check" in hooks:
@@ -355,7 +437,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         fewest_right = cfg["drives"]
         for key in sample:
             got = compare.check_object_on_disk(
-                srv.drive_root, cfg, BUCKET, key, bodies.body(key))
+                srv.drive_root, cfg, BUCKET, key, bodies.body(key),
+                reference=getattr(module, "reference_shard_files", None))
             wrong_shards += got["wrong"]
             below_quorum += got["right"] < cfg["write_quorum"]
             fewest_right = min(fewest_right, got["right"])
@@ -370,7 +453,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         dev_windows = readers.series_sum(final, requests, {"path": "device"}) \
             - readers.series_sum(ctx["scrape_a"], requests, {"path": "device"})
         checks = {
-            "wrong_answers": [verdicts["wrong"], 0],
+            "wrong_answers": [verdicts["wrong"] + quiet_wrong, 0],
             "never_answered": [verdicts["never"], 0],
             "listing_diff": [list_diff, 0],
             "disk_wrong_shards": [wrong_shards, 0],
@@ -382,6 +465,22 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             "no_window_on_the_device": [int(dev_windows <= 0), 0],
             "unclean_stop": [int(code != 0 or stamped != cfg["drives"]), 0],
         }
+        # what the mix itself holds a run to: a counter of the
+        # program, by its series' name, may rise by no more than this
+        # between the window's two scrapes
+        for series, lim in mix.get("limits", {}).items():
+            checks[series] = [readers.series_sum(final, series)
+                              - readers.series_sum(ctx["scrape_a"], series),
+                              lim]
+        if rotten:
+            rot = {"planted": len(rotten),
+                   "windows_demoted": since_rot(final, *DEMOTED),
+                   "reconstruct_requests": since_rot(
+                       final, requests, {"route": "reconstruct"}),
+                   "mrf_healed": since_rot(final, healed),
+                   "heal_wait_s": heal_wait_s}
+            checks["rot_not_noticed"] = [
+                max(0, len(rotten) - int(rot["windows_demoted"])), 0]
         correct = all(v <= lim for v, lim in checks.values())
         for e in [e for e in ops if e[4] != "ok"][:10]:
             log(f"not ok: {e[0]} {e[1]} {e[4]} {e[6]}")
@@ -404,6 +503,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                                   "benchmark/peaks.json")
             ctx.update(trace=tr, drives=cfg["drives"], workers=mix["workers"],
                        config=cfg, peaks=peaks,
+                       loadgen={k: sum(r.get("cpu", {}).get(k, 0.0)
+                                       for r in replies)
+                                for k in ("cpu_s", "wall_s")},
                        payload_mib_s={op: _rate(counted, op, t0, t1)
                                       for op in ("PUT", "GET")})
             read = lambda m: readers.read_layer(  # noqa: E731
@@ -446,9 +548,16 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             "boots": boots, "boot_s": srv.boot_s, "stop_s": srv.stop_s,
             "scrape_s_after_t0_and_t1": scrape_s,
             "drained_s": drained_s, "reference_s": time.monotonic() - t_ref,
-            "calibration": {c_["route"]: c_.get("verdict")
+            # by batcher, not by route: the heal that follows a rotten
+            # read brings a second batcher of route `get` (one shard
+            # file a member), probed under load, with a verdict of its own
+            "calibration": {c_.get("name", c_["route"]): c_.get("verdict")
                             for c_ in dev.get("calibration", [])
                             if "route" in c_}}
+        if rotten:
+            result["cell"]["rot"] = rot
+        if proxy is not None:
+            result["cell"]["wire_fault"] = proxy.flipped
         if trace:
             result["cell"]["tracing"] = tracing
             result["cell"]["layer_notes"] = ctx.get("notes", {})
@@ -458,6 +567,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     finally:
         if gens is not None:
             gens.close()
+        if proxy is not None:
+            proxy.close()
         if srv is not None:
             srv.kill()
         shutil.rmtree(workdir, ignore_errors=True)

@@ -30,10 +30,12 @@ class ServerError(Exception):
 
 class Server:
     """One `serve_traced.py <sock> <argv>` subprocess; pipes drained by
-    reader threads (an undrained pipe eventually blocks the server)."""
+    reader threads (an undrained pipe eventually blocks the server).
+    `launcher`: another script with serve_traced.py's arguments (the
+    control's, benchmark/faults.py)."""
 
     def __init__(self, argv: list[str], drives: int, workdir: str,
-                 env_extra: dict | None = None):
+                 env_extra: dict | None = None, launcher: str | None = None):
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
             self.address = f"127.0.0.1:{s.getsockname()[1]}"
@@ -57,7 +59,8 @@ class Server:
         self.boot_s = None
         self.stop_s = None
         self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_traced.py"),
+            [sys.executable,
+             launcher or os.path.join(HERE, "serve_traced.py"),
              self.sock_path, *argv, "--address", self.address,
              os.path.join(self.drive_root, "d{1...%d}" % drives)],
             env=env, cwd=ROOT, stdout=subprocess.PIPE,

@@ -74,7 +74,7 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path, monkeypatch):
         "denominator": [{"series":
                          "minio_tpu_api_request_duration_seconds_count",
                          "labels": {"api": "HEAD:object"}}]}))
-    (tmp_path / "end_to_end" / "get_mib_s.json").write_text(json.dumps(
+    (tmp_path / "end_to_end" / "get_again_mib_s.json").write_text(json.dumps(
         {"quantity": "rate_spread_over_each_operation", "op": "GET"}))
     bench = cells.load_benchmark()
     bench["configs"].append({"name": "ec2p2-4d", "reduced": [],
@@ -90,7 +90,7 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path, monkeypatch):
     assert loaded["mix"]["size"] == 1 << 20
     ops = [["GET", "pre/0000", 1.0, 3.0, "ok", 4 << 20, ""],
            ["PUT", "w00/000000", 1.0, 2.0, "ok", 4 << 20, ""]]
-    assert run.end_to_end("get_mib_s", {"ops": ops, "t0": 2.0, "t1": 4.0}) \
+    assert run.end_to_end("get_again_mib_s", {"ops": ops, "t0": 2.0, "t1": 4.0}) \
         == pytest.approx(1.0)           # half of 4 MiB inside 2 s
     assert run.RATES["rate_of_whole_operations"](ops, "GET", 2.0, 4.0) == \
         pytest.approx(2.0)              # it ended inside: all 4 MiB

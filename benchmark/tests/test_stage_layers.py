@@ -58,7 +58,9 @@ def test_every_new_metric_is_in_benchmark_json_with_its_file():
     for name in STAGE_LAYERS + (UNNAMED,):
         m = by_name[name]
         assert m["moves"] == "put_mib_s"
-        assert m["workloads"] == ["ec8p4-12d.put-64m", "ec4p2-6d.put-64m"]
+        # "contains": later PRs append their cells (PR 28 a third)
+        assert m["workloads"][:2] == ["ec8p4-12d.put-64m",
+                                      "ec4p2-6d.put-64m"]
         spec = cells.load_layer(name)
         assert ("read" in spec) == (name == UNNAMED)
     # appended: what was there keeps its place

@@ -6,7 +6,8 @@ one carries. It knows no mix by name.
 Every answer has exactly one right value, whatever the interleaving:
 
   * preloaded keys (`pre/NNNN`) are written in set-up, read by every
-    worker, and never overwritten or deleted;
+    worker (or, where the mix says `read` "own_in_order", each by the
+    worker that wrote it), and never overwritten or deleted;
   * every other key (`wWW/NNNNNN`) belongs to ONE worker, which sends
     one operation at a time: a key enters that worker's live set when
     its PUT is acknowledged, leaves it before its DELETE is sent, and
@@ -49,6 +50,9 @@ def load_mix(name: str) -> dict:
     if (mix["cycle"].get("GET") or mix["cycle"].get("STAT")) \
             and not mix["preload"]:
         raise ValueError(f"{path}: a mix that reads needs preloaded keys")
+    if mix.get("read") == "own_in_order" and mix["preload"] < mix["workers"]:
+        raise ValueError(f"{path}: own_in_order needs a preloaded key for "
+                         "every worker")
     return mix
 
 

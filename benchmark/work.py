@@ -11,9 +11,13 @@ columns -> B*m; HighwayHash of all k+m pieces: B*(k+m)/k bytes, one
 Operation counts are given for the record; on a v5e the framer is
 bound by bytes (an int8 MAC rate of 393 T/s against 819 GB/s puts the
 ridge at ~480 operations per byte; framing needs m = 2..4 MACs per
-byte plus the hash), so `least_seconds` says which bound it took. A
-later kernel family (the de-framer of the GET side) adds its function
-to `WORK` with the cell that reads it.
+byte plus the hash), so `least_seconds` says which bound it took.
+
+deframe (GET side: a healthy read verifies its k data shards), per
+erasure block: read the k framed pieces (B + 32 k bytes: each piece
+behind its stored digest) and write k verdict bytes; HighwayHash of
+the B payload bytes; no GF work. The payload does not come back from
+the device: the host serves it from the bytes it read off the drives.
 """
 
 from __future__ import annotations
@@ -29,7 +33,13 @@ def frame_work(k: int, m: int, block: int, blocks: float) -> dict:
             "ops": blocks * (block * m + HH_OPS_PER_BYTE * block * n / k)}
 
 
-WORK = {"frame": frame_work}
+def deframe_work(k: int, m: int, block: int, blocks: float) -> dict:
+    del m                        # a healthy read touches no parity
+    return {"bytes": blocks * (block + 32 * k + k),
+            "ops": blocks * HH_OPS_PER_BYTE * block}
+
+
+WORK = {"frame": frame_work, "deframe": deframe_work}
 
 
 def least_seconds(kind: str, cfg: dict, blocks: float, peaks: dict) -> dict:
