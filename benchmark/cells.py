@@ -64,7 +64,11 @@ def load_config_module(name: str):
         reference_shard_files(body, cfg) -> the n shard files the
             drives must hold for `body`, in place of
             compare.reference_shard_files (an encrypted bucket's, or a
-            geometry whose k does not divide the erasure block)."""
+            geometry whose k does not divide the erasure block);
+        cell_notes(cfg, scrape_a, scrape_b, device) -> what the
+            deployment wants said beside the numbers (the window's two
+            scrapes, the admin info's device section): the result
+            line's `cell.config_notes`, reported and never compared."""
     return _load_module("configs", name)
 
 

@@ -10,7 +10,7 @@ PERF.md.
         --seconds 10 --faults none,wrong_matrix,below_quorum
 
 and, for a cell that reads, `flip_get_byte` and `deaf_deframer` among
-the faults.
+the faults; for a configuration with dead drives, `unblocked_roots`.
 """
 
 from __future__ import annotations

@@ -20,10 +20,20 @@ program produces it, and `correct` has to come out false.
 
   deaf_deframer    the server is booted through serve_deaf.py: the `get`
                    route tells every window "all frames verify",
-                   whatever the de-framer found. In a mix that plants
-                   rot (`rotten`) the turned byte is then served: the
+                   whatever the de-framer found, and the rebuild path's
+                   own verify (a window with a shard missing) trusts
+                   every survivor it fetched. In a mix that plants rot
+                   (`rotten`) the turned byte is then served: the
                    program with its bitrot-on-read guarantee switched
                    off, an answer altered where it is produced.
+
+  unblocked_roots  (a configuration with `dead_drives`) the drives'
+                   roots are removed and NOT blocked: the program makes
+                   them again, formats them as fresh drives and heals
+                   the shards back, so within seconds the window
+                   measures healthy reads — a drive replaced, not a
+                   drive dead: the deployment is not the one the
+                   configuration states (`dead_drives_touched`).
 
 The first two alter what a PUT produced where it lies (in a cell that
 only reads: what the preload's PUTs left); the other two are the faults
@@ -197,6 +207,12 @@ class FlipProxy:
             done += n
 
 
+def unblocked_roots(srv, cfg: dict, cli) -> None:
+    del cli
+    for d in cfg["dead_drives"]:
+        srv.kill_drive(d, block=False)
+
+
 FAULTS = {"wrong_matrix": wrong_matrix, "below_quorum": below_quorum}
 
 
@@ -207,4 +223,6 @@ def hooks_for(name: str) -> dict:
     if name == "deaf_deframer":
         return {"launcher": os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "serve_deaf.py")}
+    if name == "unblocked_roots":
+        return {"after_preload": unblocked_roots}
     return {"before_disk_check": FAULTS[name]}

@@ -11,6 +11,9 @@
     ctx["config"], ctx["peaks"]
     ctx["payload_mib_s"]                {op: MiB/s of payload the clients
                                         moved inside the window}
+    ctx["payload_by_key"]               {op: {key: payload bytes of that
+                                        key's operations inside the
+                                        window}}, by the clients' own log
     ctx["notes"]                        what a reader wants said beside
                                         its number (result line, `cell`)
 
@@ -124,7 +127,18 @@ def device_trace(ctx: dict, spec: dict):
     raise ValueError(f"unknown quantity {spec['quantity']!r}")
 
 
-GENERIC = {"prometheus_delta": prometheus_delta, "device_trace": device_trace}
+def prometheus_gauge(ctx: dict, spec: dict):
+    """A gauge as one scrape read it (`at`: "scrape_a" or "scrape_b",
+    the window's two ends), summed over the series whose labels match.
+    A program that does not export the series reports nothing."""
+    scraped = ctx.get(spec["at"])
+    if scraped is None or spec["series"] not in scraped:
+        return None
+    return series_sum(scraped, spec["series"], spec.get("labels"))
+
+
+GENERIC = {"prometheus_delta": prometheus_delta, "device_trace": device_trace,
+           "prometheus_gauge": prometheus_gauge}
 
 
 def read_layer(ctx: dict, spec: dict):

@@ -119,13 +119,20 @@ def _rate(ops, op, t0, t1):
     MiB/s where this read 184-202. The price is an edge bias: a PUT in
     flight at the close ends under the draining, lighter load, so more
     of it is credited to the window than at full load (PERF.md 2)."""
-    total = 0.0
+    return sum(inside_by_key(ops, op, t0, t1).values()) / MiB / (t1 - t0)
+
+
+def inside_by_key(ops, op, t0, t1) -> dict:
+    """{key: payload bytes of its `op`s inside the window}, each
+    operation's bytes spread evenly over its interval."""
+    out: dict = {}
     for e in ops:
         if e[0] == op and e[4] == "ok" and e[3] > e[2]:
             inside = min(e[3], t1) - max(e[2], t0)
             if inside > 0:
-                total += e[5] * inside / (e[3] - e[2])
-    return total / MiB / (t1 - t0)
+                out[e[1]] = out.get(e[1], 0.0) \
+                    + e[5] * inside / (e[3] - e[2])
+    return out
 
 
 def _rate_whole(ops, op, t0, t1):
@@ -154,26 +161,30 @@ WARM_KEY = "warm/0000"
 MAX_BOOTS = 3
 PROGRAMS = "minio_tpu_batcher_bucket_dispatches_total"
 BODY_OPS = ("PUT", "GET")
-DEMOTED = ("minio_tpu_get_kernel_windows_total", {"path": "demoted"})
 
 
 def plant_rot(srv, cfg: dict, key: str, body: bytes) -> str:
-    """Bitrot on a drive: turn one bit of the first payload byte of
-    DATA shard 0 of `key` where it lies (a healthy read fetches the
-    data shards alone, so rot in a parity shard would be seen by
-    nobody). The file is the one whose first frame, behind its 32
-    digest bytes, holds the body's first erasure_block_bytes / k
-    bytes. -> its path."""
+    """Bitrot on a drive: turn one bit of the first payload byte of the
+    first DATA shard of `key` that a drive still holds, where it lies
+    (a read fetches the data shards it can get before any parity, so
+    rot in a parity shard might be seen by nobody). With every drive
+    alive that is data shard 0; where the configuration's drives have
+    died, the first that lay on a living one. The file of shard s is
+    the one whose first frame, behind its 32 digest bytes, holds bytes
+    [s, s + 1) * erasure_block_bytes / k of the body. -> its path."""
     piece = cfg["erasure_block_bytes"] // cfg["data_shards"]
-    for path in compare.shard_files_on_disk(
-            srv.drive_root, cfg["drives"], BUCKET, key).values():
-        with open(path, "r+b") as f:
-            f.seek(32)
-            if f.read(piece) == body[:piece]:
+    files = compare.shard_files_on_disk(
+        srv.drive_root, cfg["drives"], BUCKET, key).values()
+    for s in range(cfg["data_shards"]):
+        want = body[s * piece:(s + 1) * piece]
+        for path in files:
+            with open(path, "r+b") as f:
                 f.seek(32)
-                f.write(bytes([body[0] ^ 0x01]))
-                return path
-    raise ServerError(f"no drive holds data shard 0 of {key}")
+                if f.read(piece) == want:
+                    f.seek(32)
+                    f.write(bytes([want[0] ^ 0x01]))
+                    return path
+    raise ServerError(f"no drive holds a data shard of {key}")
 
 
 def programs_met(scraped: dict) -> set:
@@ -215,6 +226,47 @@ def _label(key) -> str:
     return "/".join(v for _, v in sorted(key))
 
 
+def stop_is_unclean(code: int, stamped: int, cfg: dict) -> int:
+    """The stop is clean when the server exits 0 with every drive that
+    is alive stamped: a dead drive (the configuration's `dead_drives`)
+    cannot be, its stamp would lie under its root."""
+    return int(code != 0 or stamped != drives_alive(cfg))
+
+
+def drives_alive(cfg: dict) -> int:
+    return cfg["drives"] - len(cfg.get("dead_drives", []))
+
+
+def read_back_degraded(cli, bodies, mix: dict) -> int:
+    """A configuration with dead drives: every loss pattern (which of
+    an object's shards lay on the dead drives) has a batcher of route
+    `reconstruct` of its own, with a one-shot probe of its own that
+    starts with the batcher's first device-sized window. So set-up
+    reads every preloaded object back once, ONE GET at a time on an
+    otherwise idle server, and after each waits until no route is
+    probing: each probe times a quiet server, as `put`'s and `get`'s
+    do, and no window starts on an object that was never read
+    degraded. Judged like every GET (length, every byte, ETag).
+    -> how many came back wrong."""
+    wrong = 0
+    for i in range(mix["preload"]):
+        key = traffic.pre_key(i)
+        t_get = time.monotonic()
+        st, hdr, data = cli.request("GET", f"/{BUCKET}/{key}")
+        t_got = time.monotonic()
+        if st != 200:
+            raise ServerError(
+                f"degraded GET of {key}: HTTP {st} {bytes(data)[:200]!r}")
+        wrong += int(bytes(data) != bodies.body(key)
+                     or hdr.get("etag", "").strip('"')
+                     != bodies.parts(key)[3])
+        del data
+        settle(cli)
+        log(f"read back {key} in {t_got - t_get:.2f} s, no route probing "
+            f"{time.monotonic() - t_got:.2f} s later")
+    return wrong
+
+
 def settle(cli, timeout: float = 180.0) -> dict:
     """Wait until no route's calibration probe is running; -> the
     admin info's device section."""
@@ -237,12 +289,17 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     (fn(server address) -> what the generators talk to instead:
     `.address`, `.arm()` at the window's first instant, `.close()`),
     `launcher` (the script that runs the server's `main()`, in place of
-    serve_traced.py)."""
+    serve_traced.py), `after_preload` (in place of the configuration
+    module's own)."""
     hooks = hooks or {}
     loaded = cells.load_cell(name)
     cell, cfg, bench = loaded["cell"], loaded["config"], loaded["bench"]
     module = loaded["module"]
     mix = {**loaded["mix"], **hooks.get("mix", {})}
+    # the drives the configuration says are dead from after the preload
+    # to the stop (killed by its module's `after_preload`)
+    dead = cfg.get("dead_drives", [])
+    alive = drives_alive(cfg)
     if not os.path.isfile(os.path.join(ROOT, "minio_tpu", "server.py")):
         raise NoAccelerator("no minio_tpu/ in this directory: nothing to "
                             "measure")
@@ -329,21 +386,35 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         gens.send({"cmd": "preload"})
         gens.collect()
         log(f"preloaded {mix['preload']} objects")
+        after_preload = hooks.get("after_preload") \
+            or getattr(module, "after_preload", None)
+        if after_preload is not None:
+            after_preload(srv, cfg, cli)
         # A deployment's drives rot: after the read-back (every object
-        # has been verified once), one bit of a data shard of each
-        # object the mix lists under `rotten` is turned where it lies.
-        # The program has to notice on the next read and serve the
-        # right bytes all the same; DEMOTED counts the windows whose
-        # verify said so. Those objects are first read by the ladder
-        # (the mix picks the rung), so the rebuild path's programs and
-        # the heal that follows are set-up's, not the window's.
+        # has been verified once), and after the configuration's drives
+        # have died, one bit of a data shard of each object the mix
+        # lists under `rotten` is turned where it lies on a living
+        # drive. The program has to serve the right bytes all the same
+        # (every GET is compared); where the mix names a counter of
+        # the program's that says it noticed (`rot_noticed_by`), the
+        # run is held to that too. Those objects are first read in
+        # set-up (the serial read-back of a degraded configuration, or
+        # the ladder: the mix picks the rung), so the programs of the
+        # rebuild and the heal that follows are set-up's, not the
+        # window's.
         rotten = [traffic.pre_key(i) for i in mix.get("rotten", [])]
+        noticed_by = mix.get("rot_noticed_by")
         before_rot = scrape(cli) if rotten else {}
         for key in rotten:
             log("rot planted in " + os.path.relpath(
                 plant_rot(srv, cfg, key, bodies.body(key)), srv.drive_root))
-        if hasattr(module, "after_preload"):
-            module.after_preload(srv, cfg, cli)
+        readback_s = None
+        if dead:
+            t_back = time.monotonic()
+            quiet_wrong += read_back_degraded(cli, bodies, mix)
+            readback_s = time.monotonic() - t_back
+            log(f"{mix['preload']} objects read back with drives {dead} "
+                f"dead, one at a time, in {readback_s:.1f} s")
         warm_ladder(cli, gens, mix)
         # the heal that follows a rotten read that was noticed runs in
         # the background (and, in a checkout's first run, compiles): it
@@ -355,10 +426,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                 - readers.series_sum(before_rot, series, labels)
         t_heal = time.monotonic()
         deadline = t_heal + 60.0
-        while rotten and time.monotonic() < deadline:
+        while rotten and noticed_by and time.monotonic() < deadline:
             now = scrape(cli)
-            if since_rot(now, healed) >= min(len(rotten),
-                                             since_rot(now, *DEMOTED)):
+            if since_rot(now, healed) >= min(
+                    len(rotten), since_rot(now, noticed_by["series"],
+                                           noticed_by.get("labels"))):
                 break
             time.sleep(0.5)
         heal_wait_s = time.monotonic() - t_heal
@@ -422,7 +494,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         code = srv.stop()
         stamped = srv.stamped_clean()
         log(f"server stopped in {srv.stop_s:.1f} s, exit {code}, "
-            f"{stamped} of {cfg['drives']} drives stamped clean")
+            f"{stamped} of {alive} living drives stamped clean")
 
         # -- the drives, after the clean stop ------------------------------
         in_window = [e[1] for e in ops if e[0] == "PUT" and e[4] == "ok"
@@ -463,8 +535,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                 len(set(holders) ^ {dev.get("pid")})
                 if not hooks.get("allow_platform") else 0, 0],
             "no_window_on_the_device": [int(dev_windows <= 0), 0],
-            "unclean_stop": [int(code != 0 or stamped != cfg["drives"]), 0],
+            "unclean_stop": [stop_is_unclean(code, stamped, cfg), 0],
         }
+        if dead:
+            # what keeps the deployment the one it says it is: a root
+            # made again, a stamp, a healed shard on a dead drive
+            checks["dead_drives_touched"] = [srv.drives_touched(dead), 0]
         # what the mix itself holds a run to: a counter of the
         # program, by its series' name, may rise by no more than this
         # between the window's two scrapes
@@ -473,14 +549,19 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                               - readers.series_sum(ctx["scrape_a"], series),
                               lim]
         if rotten:
-            rot = {"planted": len(rotten),
-                   "windows_demoted": since_rot(final, *DEMOTED),
-                   "reconstruct_requests": since_rot(
-                       final, requests, {"route": "reconstruct"}),
-                   "mrf_healed": since_rot(final, healed),
-                   "heal_wait_s": heal_wait_s}
-            checks["rot_not_noticed"] = [
-                max(0, len(rotten) - int(rot["windows_demoted"])), 0]
+            rot = {"planted": len(rotten)}
+            if noticed_by:
+                # the mix names a counter of the program's that rises
+                # with every rotten window it noticed, and what to
+                # call it here: the run is held to it
+                rot[noticed_by["name"]] = since_rot(
+                    final, noticed_by["series"], noticed_by.get("labels"))
+                checks["rot_not_noticed"] = [
+                    max(0, len(rotten) - int(rot[noticed_by["name"]])), 0]
+            rot.update(reconstruct_requests=since_rot(
+                           final, requests, {"route": "reconstruct"}),
+                       mrf_healed=since_rot(final, healed),
+                       heal_wait_s=heal_wait_s)
         correct = all(v <= lim for v, lim in checks.values())
         for e in [e for e in ops if e[4] != "ok"][:10]:
             log(f"not ok: {e[0]} {e[1]} {e[4]} {e[6]}")
@@ -501,8 +582,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             if peaks is None:
                 raise ServerError(f"no peaks for device kind {kind!r} in "
                                   "benchmark/peaks.json")
-            ctx.update(trace=tr, drives=cfg["drives"], workers=mix["workers"],
+            ctx.update(trace=tr, drives=alive, workers=mix["workers"],
                        config=cfg, peaks=peaks,
+                       payload_by_key={op: inside_by_key(counted, op, t0, t1)
+                                       for op in ("PUT", "GET")},
                        loadgen={k: sum(r.get("cpu", {}).get(k, 0.0)
                                        for r in replies)
                                 for k in ("cpu_s", "wall_s")},
@@ -556,6 +639,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                             if "route" in c_}}
         if rotten:
             result["cell"]["rot"] = rot
+        if dead:
+            result["cell"]["dead_drives"] = {
+                "dead": dead, "stamped": stamped, "readback_s": readback_s}
+        if hasattr(module, "cell_notes"):
+            # what the configuration wants said of its deployment
+            # beside the numbers: reported, not compared
+            result["cell"]["config_notes"] = module.cell_notes(
+                cfg, ctx["scrape_a"], final, dev)
         if proxy is not None:
             result["cell"]["wire_fault"] = proxy.flipped
         if trace:

@@ -7,7 +7,13 @@ What it costs the load generator, stated once: the payload's SHA-256
 goes into the signature (the caller may pass one it computed ahead);
 a response is read to its last byte before `request` returns; a socket
 that stays silent for `timeout` seconds fails the operation. Nothing is
-retried: an operation that fails, failed.
+retried: an operation that fails, failed. A connection that has lain
+idle for IDLE_S is opened anew before the next request (no retry: the
+request has not been sent): the server reaps a parked connection after
+75 s (`MTPU_HTTP_KEEPALIVE_S`), as any server does, and a request sent
+into a reaped one gets no answer — a set-up that keeps the generators
+waiting that long (PR 34: the serial read-back of a degraded cell) would
+otherwise cost every worker its next operation.
 """
 
 from __future__ import annotations
@@ -16,9 +22,11 @@ import datetime
 import hashlib
 import hmac
 import http.client
+import time
 import urllib.parse
 
 EMPTY_SHA256 = hashlib.sha256(b"").hexdigest()
+IDLE_S = 60.0
 
 
 def _quote(s: str, safe: str) -> str:
@@ -39,6 +47,7 @@ class S3:
         self.region = region
         self.timeout = timeout
         self._conn: http.client.HTTPConnection | None = None
+        self._used = 0.0
 
     def close(self) -> None:
         if self._conn is not None:
@@ -88,6 +97,9 @@ class S3:
         send, url = self._headers(method, path, query or {},
                                   payload_sha256, headers or {})
         send["Content-Length"] = str(sum(len(p) for p in pieces))
+        if self._conn is not None \
+                and time.monotonic() - self._used > IDLE_S:
+            self.close()
         if self._conn is None:
             self._conn = http.client.HTTPConnection(self.address,
                                                     timeout=self.timeout)
@@ -119,6 +131,7 @@ class S3:
                 # the program cuts the connection after any error
                 # answer without saying `Connection: close`
                 self.close()
+            self._used = time.monotonic()
             return resp.status, hdrs, data
         except BaseException:
             self.close()

@@ -11,6 +11,7 @@ import glob
 import json
 import os
 import re
+import shutil
 import signal
 import socket
 import subprocess
@@ -140,6 +141,32 @@ class Server:
     def stamped_clean(self) -> int:
         return len(glob.glob(os.path.join(
             self.drive_root, "d*", ".mtpu.sys", "clean.shutdown")))
+
+    def drive_path(self, d: int) -> str:
+        return os.path.join(self.drive_root, f"d{d}")
+
+    def kill_drive(self, d: int, block: bool = True) -> None:
+        """Drive `d` dies under the live server: its tree is taken away
+        and a regular, empty file put at its path, so that every call
+        on the drive fails with ENOTDIR and nothing can make the root
+        again — a plain file system's nearest thing to a disk that
+        answers EIO. The tree is renamed away first (one instant) and
+        removed afterwards. `block=False` leaves the path free: the
+        control's drive, which the program makes again and heals."""
+        path = self.drive_path(d)
+        gone = os.path.join(os.path.dirname(self.drive_root), f"gone-d{d}")
+        os.rename(path, gone)
+        if block:
+            os.close(os.open(path, os.O_CREAT | os.O_EXCL | os.O_WRONLY))
+        shutil.rmtree(gone)
+
+    def drives_touched(self, dead: list[int]) -> int:
+        """How many of the dead drives' paths are a directory again or
+        hold anything: a root made again, a stamp, a healed shard."""
+        paths = [self.drive_path(d) for d in dead]
+        return sum(os.path.isdir(p) or (os.path.lexists(p)
+                                         and os.path.getsize(p) > 0)
+                   for p in paths)
 
     def kill(self) -> None:
         try:
