@@ -8,9 +8,21 @@ ZERO fresh window buffers (pool hit rate ~100% after warmup — asserted
 in tests/test_io_engine.py).
 
 Design:
-  * size classes — powers of two from 64 KiB to 64 MiB, each class a
+  * size classes — powers of two from 64 KiB to 512 MiB, each class a
     bounded free list (a request larger than the largest class is
-    served unpooled and counted, never refused);
+    served unpooled and counted, never refused). The classes up to
+    64 MiB keep `max_per_class` idle buffers each. The three above
+    (128, 256, 512 MiB) exist for the stripe batcher's staging buffer
+    (ops/batcher._stage: a 256-block dispatch is 256 MiB on routes put
+    and transform, 256 x k x (shard + 32) = 268.5 MB on route get) and
+    keep TWO each: the dispatcher's depth — one batch in the lane, one
+    being staged — is all one loaded batcher ever holds, and sixteen
+    idle mappings of 512 MiB would not do. A mapping is address space;
+    only the pages a holder wrote are resident (a fresh mapping costs
+    a page fault per 4 KiB on first touch — ~0.7 s for 256 MiB on a
+    gVisor host — which is why the staging buffer is kept and not
+    mapped anew), so two kept buffers of route get hold ~0.5 GiB
+    resident whatever `idle_bytes`, which counts capacity, says;
   * leases — a Lease wraps one buffer with a reference count. Writers
     that may outlive the request (a health-wrapped create_file whose
     deadline expired but whose abandoned worker is still writing)
@@ -25,7 +37,8 @@ Alignment: every pooled buffer is backed by mmap pages, so the memory
 side of O_DIRECT's alignment contract holds for any pooled view.
 
 Environment:
-  MTPU_BUFPOOL_MAX_PER_CLASS  buffers kept per size class (default 16)
+  MTPU_BUFPOOL_MAX_PER_CLASS  buffers kept per size class (default 16;
+                              the classes above 64 MiB keep 2 at most)
   MTPU_BUFPOOL_OFF            "1"/"on" disables pooling (every lease
                               is a fresh buffer; leases still work)
 """
@@ -37,13 +50,18 @@ import os
 import threading
 import weakref
 
-# Size classes: 64 KiB .. 64 MiB, powers of two. Matched to the data
+# Size classes: 64 KiB .. 512 MiB, powers of two. Matched to the data
 # path's working sizes: shard windows (~128 KiB at EC 8+4), framed
 # whole-object outputs (~1.5 MiB per 1 MiB object), streaming encode
-# windows (32 MiB) and their framed outputs (48 MiB at EC 8+4).
+# windows (32 MiB), their framed outputs (48 MiB at EC 8+4), and the
+# batcher's coalesced staging buffer (up to 268.5 MB: the 512 MiB class).
 _MIN_CLASS = 16          # 2**16 = 64 KiB
-_MAX_CLASS = 26          # 2**26 = 64 MiB
+_MAX_CLASS = 29          # 2**29 = 512 MiB
 CLASS_SIZES = tuple(1 << p for p in range(_MIN_CLASS, _MAX_CLASS + 1))
+# Classes above this size keep _LARGE_IDLE idle buffers, not
+# max_per_class (module docstring: the batcher's depth is two).
+_LARGE_FROM = 1 << 26    # 64 MiB
+_LARGE_IDLE = 2
 
 
 def _class_for(size: int) -> int:
@@ -149,6 +167,11 @@ class BufferPool:
             enabled = os.environ.get("MTPU_BUFPOOL_OFF", "").lower() \
                 not in ("1", "on", "true")
         self.max_per_class = max(1, max_per_class)
+        # Idle buffers kept per class: the large classes never more
+        # than _LARGE_IDLE, whatever the small ones are allowed.
+        self._keep = tuple(
+            min(self.max_per_class, _LARGE_IDLE) if c > _LARGE_FROM
+            else self.max_per_class for c in CLASS_SIZES)
         self.enabled = enabled
         self._mu = threading.Lock()
         self._free: list[list] = [[] for _ in CLASS_SIZES]
@@ -202,7 +225,7 @@ class BufferPool:
         with self._mu:
             self.outstanding -= 1
             if cls >= 0 and self.enabled \
-                    and len(self._free[cls]) < self.max_per_class:
+                    and len(self._free[cls]) < self._keep[cls]:
                 self._free[cls].append(buf)
                 self.idle_bytes += len(buf)
                 return

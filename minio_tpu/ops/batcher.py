@@ -13,7 +13,12 @@ TPU the accelerator is one big shared mesh, so batching across requests
 is what fills it.
 
 What makes the batch DEVICE-resident (ops/hh_device.make_mesh_framer):
-the coalesced window is staged into ONE pooled bufpool buffer, padded to
+the coalesced window is staged into ONE pooled bufpool buffer — pooled
+for every bucket on every route: io/bufpool's classes reach past the
+largest dispatch (256 blocks: 256 MiB on put, 268.5 MB on get) and keep
+two idle buffers of that size, the dispatcher's depth, so a loaded
+batcher copies into memory that has been written before and never
+page-faults a fresh mapping per dispatch — padded to
 a fixed power-of-two bucket, and dispatched as one jitted step placed
 by ops/device.batch_placement: on one chip as it stands, on several
 with the batch dim cut over the chips (`P("stripe")`) and the staged
@@ -753,8 +758,12 @@ class StripeBatcher:
 
     def _stage(self, live: list[_Pending], bucket: int):
         """(lease, stacked [bucket, k, L]): members copied into ONE
-        pooled staging buffer, zero-padded to the bucket. The lease is
-        held by the caller for the whole dispatch — donation safety:
+        pooled staging buffer, zero-padded to the bucket. Pooled
+        whatever the bucket: the lease's size alone picks the pool's
+        class (io/bufpool keeps two idle buffers of the classes a
+        large dispatch needs), so from its third dispatch on a loaded
+        batcher writes into pages that are already resident. The lease
+        is held by the caller for the whole dispatch — donation safety:
         the buffer the device is still reading can never be recycled
         into a new lease mid-transfer. Returns (None, member array)
         when a lone member already fills the bucket exactly."""
@@ -905,8 +914,9 @@ class StripeBatcher:
                         self._lane_hist.observe(
                             time.perf_counter() - b.t_lane)
                     # Let go of the buffer here too: `_in_lane` keeps
-                    # the batch until the next one is submitted, and a
-                    # mapping of a whole bucket must not live that long.
+                    # the batch until the next one is submitted, and the
+                    # lease goes back to the pool now, for the batch
+                    # after next to stage into.
                     lease, b.lease, b.stacked = b.lease, None, None
                     if lease is not None:
                         lease.release()

@@ -5,34 +5,62 @@ established with events only, never with the clock (ROADMAP C8): a wait
 has a timeout so that a broken batcher fails the test instead of
 hanging it, and nothing is asserted about how long anything took."""
 
+import functools
 import threading
 import time
 
 import numpy as np
 
 from minio_tpu.io.bufpool import BufferPool
-from minio_tpu.object.erasure_object import _host_rows
+from minio_tpu.object.erasure_object import (_get_split, _host_apply_rows,
+                                             _host_deframe, _host_rows)
+from minio_tpu.ops import device, gf256
 from minio_tpu.ops.batcher import StripeBatcher, _Pending
+from minio_tpu.storage import bitrot
 
 K, M, SHARD = 8, 4, 1024
 WAIT_S = 60.0          # a timeout, not an expectation
 
 ROUTES = ("put", "split")
+# The routes that ship, each with its own host function and demux: what
+# a staged dispatch's rows are held to (the kept staging buffer).
+SHIPPED = ("put", "get", "reconstruct")
+# A trailing shape at which a 64-block dispatch is just over 64 MiB,
+# the pool's largest class until PR 35: route get's own frame at EC 8+4.
+BIG_SHARD = 131072 + 32
+# Survivors and losses of the `reconstruct` route: data shards 1 and 4
+# are gone, parity 8 and 9 stand in.
+USE, LOST = (0, 2, 3, 5, 6, 7, 8, 9), (1, 4)
 
 
 def _xor(stacked):
     return stacked ^ np.uint8(0x5A)
 
 
-def window(blocks, seed):
+def window(blocks, seed, shard=SHARD, route="put"):
+    """One member's window in the form its route stacks: [B, K, shard]
+    bytes; on route `get` each piece is a bitrot frame, its digest the
+    right one but for one turned byte in block 0 (a verdict of each
+    kind)."""
     rng = np.random.default_rng(seed)
-    return rng.integers(0, 256, size=(blocks, K, SHARD), dtype=np.uint8)
+    out = rng.integers(0, 256, size=(blocks, K, shard), dtype=np.uint8)
+    if route == "get":
+        out[:, :, :32] = bitrot.hash_blocks_many(
+            bitrot.DEFAULT_ALGORITHM,
+            out[:, :, 32:].reshape(blocks * K, shard - 32)) \
+            .reshape(blocks, K, 32)
+        out[0, 1, 40] ^= 0x01
+    return out
 
 
 def same(route, got, want):
     """`got` is byte for byte what `want` is, in the route's own form."""
-    if route == "split":
+    if route in ("split", "reconstruct"):
         assert np.array_equal(got, want)
+        return
+    if route == "get":
+        assert np.array_equal(got[0], want[0]) and not got[0].all()
+        assert np.array_equal(got[1], want[1])
         return
     assert len(got) == len(want)
     for dg, dw in zip(got, want):
@@ -123,15 +151,32 @@ class Rig:
     host function stays the reference)."""
 
     def __init__(self, route, hold_dev=(), hold_stage=(), fail=(),
-                 device_fn=None):
+                 device_fn=None, dev_after=False, shard=SHARD):
+        slices = {"route": "reconstruct",
+                  "split_fn": lambda out, off, c, _m: out[off:off + c]}
         if route == "split":
-            fn, kw = _xor, {"route": "reconstruct",
-                            "split_fn": lambda out, off, c, _m:
-                            out[off:off + c]}
+            fn, kw = _xor, slices
+        elif route == "reconstruct":
+            rows = np.ascontiguousarray(
+                gf256.decode_matrix(K, M, USE)[list(LOST), :])
+            fn, kw = functools.partial(_host_apply_rows, rows), slices
+        elif route == "get":
+            fn, kw = _host_deframe, {"route": "get", "split_fn": _get_split}
+            # the device de-framer answers with the verdicts alone
+            device_fn = device_fn or (lambda s: _host_deframe(s)[0])
         else:
             fn, kw = (lambda s: _host_rows(K, M, s)), {"route": "put"}
-        self.fn = fn
-        self.dev = Gate(device_fn or fn, hold=hold_dev, fail=fail)
+        self.fn, self.route, self.shard = fn, route, shard
+        # What each device call was handed: (rows in the batch, rows the
+        # batcher called real, whether any padding row held a set bit).
+        self.seen = []
+
+        def seeing(stacked):
+            real = getattr(device._batch, "real", None)
+            self.seen.append((stacked.shape[0], real,
+                              bool(stacked[real:].any())))
+            return (device_fn or fn)(stacked)
+        self.dev = Gate(seeing, hold=hold_dev, fail=fail, after=dev_after)
         self.dev.mesh_devices = getattr(device_fn, "mesh_devices", 1)
         self.pool = BufferPool(max_per_class=4)
         self.leases, self.outstanding = [], []
@@ -156,9 +201,23 @@ class Rig:
 
     def send(self, seed, blocks=None, n=2):
         """n members of one batch, on their threads."""
-        got = [Member(self.sb, window(blocks or self.half, seed + i))
+        got = [Member(self.sb, window(blocks or self.half, seed + i,
+                                      self.shard, self.route))
                for i in range(n)]
         self.members += got
+        return got
+
+    def send_in_order(self, seed, counts):
+        """One batch of members of `counts` blocks, queued in that
+        order: all but the last stay under the fill target together,
+        so the dispatcher takes the batch when the last arrives."""
+        assert sum(counts[:-1]) < self.sb._fill_target() <= sum(counts)
+        got = []
+        for i, c in enumerate(counts):
+            got += self.send(seed + i, blocks=c, n=1)
+            if i < len(counts) - 1:
+                until(lambda: len(self.sb._pending) == i + 1,
+                      f"member {i} queued")
         return got
 
     def synchronous(self, members):

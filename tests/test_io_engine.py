@@ -122,14 +122,92 @@ def test_double_release_counted_not_corrupting():
     c.release()
 
 
-def test_oversized_lease_served_unpooled():
+# The stripe batcher's largest staging buffer: a 256-block dispatch of
+# route get at EC 8+4, 256 x 8 x (128 KiB + 32) bytes (ops/batcher._stage).
+STAGED_GET = 256 * 8 * (131072 + 32)
+
+
+@pytest.mark.parametrize("size,pooled", [
+    ((1 << 26) + 1, True),        # over 64 MiB: the 128 MiB class
+    (1 << 28, True),              # a full dispatch of route put
+    (STAGED_GET, True),           # ... of route get: the 512 MiB class
+    (1 << 29, True),              # the largest class, to the byte
+    ((1 << 29) + 1, False),       # over it: unpooled, counted, served
+])
+def test_oversized_lease_served_unpooled(size, pooled):
+    """A lease over the largest class is a fresh mapping that dies with
+    its release; up to it the buffer is kept and handed out again.
+    Only the pages written are ever resident."""
     pool = BufferPool(max_per_class=2)
-    big = pool.lease((1 << 26) + 1)
-    assert big.size == (1 << 26) + 1
+    big = pool.lease(size)
+    assert big.size == size
     big.view(64)[:] = b"x" * 64
+    big.view(size)[size - 1:] = b"y"
+    raw = big.raw
     big.release()
-    assert pool.stats()["oversized"] == 1
-    assert pool.stats()["outstanding"] == 0
+    st = pool.stats()
+    assert st["oversized"] == (0 if pooled else 1)
+    assert st["outstanding"] == 0
+    assert (st["idle_bytes"] >= size) == pooled
+    again = pool.lease(size)
+    assert (again.raw is raw) == pooled
+    assert pool.stats()["hits"] == (1 if pooled else 0)
+    if pooled:
+        # recycled as it was left: the holder zeroes what it must
+        assert bytes(again.view(64)) == b"x" * 64
+    again.release()
+    pool.drain()
+
+
+def test_staging_sized_lease_is_a_hit_on_the_same_mapping():
+    """What the batcher's dispatcher does at depth two, round and
+    round: two buffers of the staged size alive, each released and
+    leased again — the same two mappings for good, nothing oversized."""
+    pool = BufferPool(max_per_class=16)
+    a, b = pool.lease(STAGED_GET), pool.lease(STAGED_GET)
+    mappings = {id(a.raw), id(b.raw)}
+    assert len(mappings) == 2
+    for _ in range(3):
+        a.release()
+        a = pool.lease(STAGED_GET)
+        b.release()
+        b = pool.lease(STAGED_GET)
+        assert {id(a.raw), id(b.raw)} == mappings
+    st = pool.stats()
+    assert st["misses"] == 2 and st["hits"] == 6 and st["oversized"] == 0
+    a.release()
+    b.release()
+    pool.drain()
+
+
+def test_large_classes_keep_two_idle_buffers_and_drain_closes_them():
+    """The classes above 64 MiB keep two idle buffers whatever
+    max_per_class allows the small ones; the third release unmaps.
+    drain() drops the large ones with the rest."""
+    pool = BufferPool(max_per_class=16)
+    leases = [pool.lease(STAGED_GET) for _ in range(3)]
+    raws = [ls.raw for ls in leases]
+    small = [pool.lease(100_000) for _ in range(3)]
+    for ls in leases + small:
+        ls.release()
+    st = pool.stats()
+    assert st["outstanding"] == 0 and st["oversized"] == 0
+    assert st["idle_bytes"] == 2 * (1 << 29) + 3 * (1 << 17)
+    assert [r.closed for r in raws] == [False, False, True]
+    # a smaller cap still rules everywhere
+    tight = BufferPool(max_per_class=1)
+    pair = [tight.lease(1 << 27), tight.lease(1 << 27)]
+    for ls in pair:
+        ls.release()
+    assert tight.stats()["idle_bytes"] == 1 << 27
+    tight.drain()
+    pool.drain()
+    assert pool.stats()["idle_bytes"] == 0
+    assert all(r.closed for r in raws)
+    fresh = pool.lease(STAGED_GET)
+    assert pool.stats()["misses"] == 3 + 3 + 1
+    fresh.release()
+    pool.drain()
 
 
 # ---------------------------------------------------------------------------

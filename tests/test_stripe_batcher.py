@@ -359,3 +359,51 @@ def test_pipelined_rows_are_the_synchronous_paths_rows(route):
                                                     m.stacked)
                                    for _dig, blk in m.rows[drive])
         assert r.sb.stats()["overlapped"] == 1
+
+
+@pytest.mark.parametrize("route", rig.SHIPPED)
+def test_staged_dispatches_over_64_mib_reuse_two_kept_buffers(route):
+    """(g) The staging buffer is kept, not mapped anew, whatever its
+    size: three dispatches of 64 blocks at a trailing shape that puts
+    the staged copy just over 64 MiB (unpooled until PR 35) take two
+    mappings between them — N+2 is staged into N's, handed back when
+    N's rows were read and not before — with two alive at most; the
+    rows the kept buffer carried are the host route's, and what N left
+    behind in it is zero where N+2 pads."""
+    with rig.Rig(route, hold_dev=[0], dev_after=True,
+                 shard=rig.BIG_SHARD) as r:
+        staged = 64 * rig.K * rig.BIG_SHARD
+        assert staged > 1 << 26
+        n0 = r.send_in_order(seed=100, counts=(16, 40))
+        rig.wait(r.dev.entered[0], "N in the lane")
+        n1 = r.send_in_order(seed=110, counts=(16, 17))
+        rig.wait(r.stage.left[1], "N+1 staged")
+        n2 = r.send_in_order(seed=120, counts=(16, 17))
+        # N's rows exist (its device function has run) and its call has
+        # not returned: its buffer is still its own, N+1 took a second
+        # mapping, and N+2 cannot be staged.
+        assert [ls.size for ls in r.leases] == [staged, staged]
+        assert r.leases[0].refs == 1 and r.leases[1].refs == 1
+        assert r.leases[1].raw is not r.leases[0].raw
+        assert r.pool.stats()["idle_bytes"] == 0
+        assert not r.stage.entered[2].is_set()
+        r.dev.go[0].set()
+        rig.wait(r.stage.left[2], "N+2 staged")
+        assert r.leases[0].refs == 0
+        assert r.leases[2].raw is r.leases[0].raw
+        for m in n0 + n1 + n2:
+            assert m.returned().exc is None
+            rig.same(route, m.rows, r.fn(m.stacked))
+            m.rows = m.stacked = None
+        # 56 real rows, then 33 twice: rows 33..55 of N+2's batch held
+        # N's members until the copy zeroed them.
+        assert r.seen == [(64, 56, False), (64, 33, False),
+                          (64, 33, False)]
+        st = r.pool.stats()
+        assert (st["misses"], st["hits"], st["oversized"]) == (2, 1, 0)
+        assert max(r.outstanding) == 2
+        assert r.sb.stats()["buckets"] == {64: 3}
+    st = r.pool.stats()
+    assert st["outstanding"] == 0 and st["leaks"] == 0
+    assert st["idle_bytes"] == 2 * (1 << 27)
+    r.pool.drain()
