@@ -294,6 +294,96 @@ def test_bitrot_demote_then_device_reconstruct(forced_decode):
     assert es.get_kernel["demoted"] >= 1
 
 
+@pytest.mark.parametrize("path, stacks_a_get, counted", [
+    ("verified", 2, "device"),
+    ("bitrot", 2, "demoted"),
+    ("device_raises", 2, "native"),
+    ("device_raises_required", 1, None),
+])
+def test_get_window_stacks_are_pool_leases(forced_decode, monkeypatch,
+                                           path, stacks_a_get, counted):
+    """A multi-window streamed GET on the device route stacks each
+    window's de-framer member in a lease of the process-wide pool,
+    inside the counted stage `get.stack` (once a window), and gives
+    every lease back: on a verified window, on a bitrot demote, and
+    when the device function raises (absorbed into the native kernel,
+    or raised where the device is required). Once warm, every stack is
+    a pool hit and the answer is byte-identical."""
+    import gc
+    import glob
+    from minio_tpu.io.bufpool import global_pool
+    from minio_tpu.object import erasure_object as eo
+    from minio_tpu.ops import device as device_mod
+    from minio_tpu.utils import tracing
+    es, root = forced_decode
+    # Two 8-block windows a GET (the served path reads 32 MiB windows;
+    # the stack is the same code at any size).
+    monkeypatch.setattr(eo, "GET_WINDOW_BYTES", 8 << 20)
+    # The rot stays planted: no background heal leases beside the GETs.
+    monkeypatch.setattr(es.mrf, "enqueue", lambda *a, **kw: None)
+    rng = np.random.default_rng(24)
+    body = rng.integers(0, 256, size=16 << 20, dtype=np.uint8).tobytes()
+    es.put_object("b", "mw", body)
+    es.fi_cache.enabled = False
+    if path == "bitrot":
+        dist = eo.hash_order("b/mw", 12)
+        files = glob.glob(str(root / f"d{dist.index(1)}" / "b" / "mw"
+                              / "*" / "part.1"))
+        assert files
+        with open(files[0], "r+b") as f:
+            f.seek(2000)
+            f.write(b"\x5a\xa5\x5a\xa5")
+    if path.startswith("device_raises"):
+        def boom(stacked):
+            raise RuntimeError("device fault (planted)")
+        monkeypatch.setattr(eo._get_batcher_for(8, 4), "_device_fn", boom)
+        monkeypatch.setattr(device_mod, "required",
+                            lambda: path == "device_raises_required")
+    pool = global_pool()
+    stack_bytes = 8 * K * (32 + (1 << 20) // K)
+    leased = []
+    lease = pool.lease
+
+    def spy(size):
+        leased.append(size)
+        return lease(size)
+    monkeypatch.setattr(pool, "lease", spy)
+
+    def get():
+        _, chunks = es.get_object_stream("b", "mw")
+        return b"".join(bytes(c) for c in chunks)
+
+    def read():
+        if counted is None:
+            with pytest.raises(RuntimeError, match="planted"):
+                get()
+        else:
+            assert get() == body
+
+    def stacks():
+        return tracing.stage_totals().get("get.stack", [0.0, 0.0, 0])[2]
+
+    before = pool.stats()
+    read()                       # warm: the pool's first leases miss
+    gc.collect()
+    warm = pool.stats()
+    assert warm["outstanding"] == before["outstanding"]
+    n0, kernel0 = stacks(), dict(es.get_kernel)
+    del leased[:]
+    for _ in range(3):
+        read()
+    gc.collect()
+    after = pool.stats()
+    assert stacks() - n0 == 3 * stacks_a_get
+    assert leased.count(stack_bytes) == 3 * stacks_a_get
+    assert after["hits"] - warm["hits"] >= 3 * stacks_a_get
+    assert after["misses"] == warm["misses"]
+    assert after["outstanding"] == before["outstanding"]
+    assert after["leaks"] == before["leaks"]
+    if counted is not None:
+        assert es.get_kernel[counted] - kernel0[counted] >= 3
+
+
 def test_heal_deep_verify_rides_verify_batcher(forced_decode):
     """Deep heal's bitrot verification routes through the k=1 verify
     batcher (one member per drive shard file) and still detects and
