@@ -420,6 +420,12 @@ class ErasureSet:
         # read-modify-write, so a lock keeps the counts honest.
         self.get_kernel = {"native": 0, "numpy": 0, "demoted": 0,
                            "device": 0}
+        # Beside them, under the same lock: what a multi-window GET's
+        # windows waited in `self.pool`'s queue (submit to the read's
+        # first instruction: [seconds, windows]), and the fetched
+        # shards the rebuild path's verify refused for bitrot.
+        self.get_pool_wait = [0.0, 0]
+        self.get_survivors_refused = 0
         self._gk_mu = threading.Lock()
 
     def close(self) -> None:
@@ -1983,10 +1989,30 @@ class ErasureSet:
     def get_object(self, bucket: str, object_: str,
                    opts: Optional[GetOptions] = None) -> tuple[ObjectInfo, bytes]:
         opts = opts or GetOptions()
-        # Namespace read lock: shares with other readers, excludes
-        # put/delete/heal on this key (reference: GetObjectNInfo's NSLock).
-        with self.ns.read(bucket, object_):
-            return self._get_object_locked(bucket, object_, opts)
+        cm, info, fi, fis, offset, length = self._open_get(bucket, object_,
+                                                           opts)
+        try:
+            if fi.size == 0 or length == 0:
+                return info, b""
+            return info, self._read_payload(bucket, object_, fi, fis,
+                                            offset, length)
+        finally:
+            cm.__exit__(None, None, None)
+
+    def _open_get(self, bucket: str, object_: str, opts: GetOptions):
+        """The stage `get.prepare`: the namespace read lock taken
+        (shared with other readers, excludes put/delete/heal on this
+        key — reference: GetObjectNInfo's NSLock) and the GET prepared.
+        Returns (lock, info, fi, fis, offset, length); the caller
+        releases the lock (`lock.__exit__`) when the read is over."""
+        with tracing.stage("get.prepare", type_="storage", cpu=False):
+            cm = self.ns.read(bucket, object_)
+            cm.__enter__()
+            try:
+                return (cm,) + self._prepare_get(bucket, object_, opts)
+            except BaseException:
+                cm.__exit__(None, None, None)
+                raise
 
     def _prepare_get(self, bucket: str, object_: str, opts: GetOptions):
         """Shared GET preamble: quorum fileinfo, delete-marker mapping,
@@ -2020,15 +2046,6 @@ class ErasureSet:
         info.range_start, info.range_length = offset, length
         return info, fi, fis, offset, length
 
-    def _get_object_locked(self, bucket: str, object_: str,
-                           opts: GetOptions) -> tuple[ObjectInfo, bytes]:
-        info, fi, fis, offset, length = self._prepare_get(bucket, object_,
-                                                          opts)
-        if fi.size == 0 or length == 0:
-            return info, b""
-        return info, self._read_payload(bucket, object_, fi, fis,
-                                        offset, length)
-
     def get_object_stream(self, bucket: str, object_: str,
                           opts: Optional[GetOptions] = None):
         """Streaming GET: (ObjectInfo, iterator of plaintext chunks).
@@ -2038,14 +2055,8 @@ class ErasureSet:
         held until the iterator is exhausted or closed (the reference's
         GetObjectNInfo reader-with-unlock-on-close)."""
         opts = opts or GetOptions()
-        cm = self.ns.read(bucket, object_)
-        cm.__enter__()
-        try:
-            info, fi, fis, offset, length = self._prepare_get(
-                bucket, object_, opts)
-        except BaseException:
-            cm.__exit__(None, None, None)
-            raise
+        cm, info, fi, fis, offset, length = self._open_get(bucket, object_,
+                                                           opts)
 
         def gen():
             try:
@@ -2191,23 +2202,34 @@ class ErasureSet:
         dl = deadline_mod.current()
         tctx, tparent = tracing.capture() if tracing.ACTIVE else (None, 0)
 
-        def read_desc(desc):
+        def read_desc(desc, t_submit):
+            waited = _time_mod.perf_counter() - t_submit
+            with self._gk_mu:
+                self.get_pool_wait[0] += waited
+                self.get_pool_wait[1] += 1
             num, psize, rel, step = desc
             with deadline_mod.bind(dl), tracing.bind(tctx, tparent):
                 return self._read_part_window_pooled(
                     bucket, object_, fi, fis, num, psize, rel, step,
                     inline_cache=inline_cache)
 
-        fut = self.pool.submit(read_desc, descs[0])
+        def submit(desc):
+            return self.pool.submit(read_desc, desc,
+                                    _time_mod.perf_counter())
+
+        fut = submit(descs[0])
         lease = None
         try:
             for i in range(len(descs)):
-                chunk, lease = fut.result()
+                # The request waits for its window: the pool's queue,
+                # then the window's own read (stage get.window).
+                with tracing.stage("get.window_wait", type_="storage",
+                                   cpu=False):
+                    chunk, lease = fut.result()
                 # Prefetch the NEXT window before handing this one to
                 # the consumer: its drive reads overlap the socket
                 # sends (and the native decode releases the GIL).
-                fut = self.pool.submit(read_desc, descs[i + 1]) \
-                    if i + 1 < len(descs) else None
+                fut = submit(descs[i + 1]) if i + 1 < len(descs) else None
                 yield chunk
                 if lease is not None:
                     lease.release()
@@ -2293,6 +2315,22 @@ class ErasureSet:
                                  fi: FileInfo, fis: list, part_number: int,
                                  part_size: int, offset: int, length: int,
                                  inline_cache: Optional[dict] = None):
+        """_read_window as the stage `get.window`, inside a nameless
+        `request_root()`: the window's parts (get.fetch, get.stack,
+        get.deframe, get.interleave; on the rebuild path
+        get.survivor_verify, get.rebuild_stack, get.rebuild, get.join)
+        reach the totals with it, so a part over the whole is a ratio
+        of whole windows, whichever thread read them."""
+        with tracing.request_root(), \
+                tracing.stage("get.window", type_="storage", cpu=False):
+            return self._read_window(bucket, object_, fi, fis, part_number,
+                                     part_size, offset, length,
+                                     inline_cache)
+
+    def _read_window(self, bucket: str, object_: str, fi: FileInfo,
+                     fis: list, part_number: int, part_size: int,
+                     offset: int, length: int,
+                     inline_cache: Optional[dict] = None):
         """Gather only the erasure blocks covering the window inside one
         part: verified shard-block slices (k preferred, hedge to all),
         batched reconstruct of missing shards, block-major reassembly.
@@ -2387,8 +2425,19 @@ class ErasureSet:
         use_device = self._device_capable() and device.on_tpu()
 
         def verify(blobs):
-            return bitrot.read_framed_blocks_many(
-                blobs, shard_size, win_len, device=use_device)
+            """The rebuild path's verify of fetched shards (the host's
+            HighwayHash, or the device's for a large enough batch),
+            counting each fetched one it refuses for bitrot."""
+            with tracing.stage("get.survivor_verify", type_="kernel",
+                               cpu=False):
+                out = bitrot.read_framed_blocks_many(
+                    blobs, shard_size, win_len, device=use_device)
+            refused = sum(1 for b, r in zip(blobs, out)
+                          if b is not None and r is None)
+            if refused:
+                with self._gk_mu:
+                    self.get_survivors_refused += refused
+            return out
 
         def fetch_many(shard_idxs):
             """Fetch a set of shards through their holders' per-drive
@@ -2404,7 +2453,8 @@ class ErasureSet:
                     continue
                 pos[s] = di
                 fns[di] = (lambda s=s: fetch_raw(s))
-            results, errs = self._fanout(fns)
+            with tracing.stage("get.fetch", type_="storage", cpu=False):
+                results, errs = self._fanout(fns)
             return ([results[pos[s]] if s in pos else None
                      for s in shard_idxs],
                     [errs[pos[s]] if s in pos else None
@@ -2475,15 +2525,18 @@ class ErasureSet:
 
         # Blocks interleave across shards: reassemble block-major, trimming
         # each block's zero padding (k*shard_size may exceed BLOCK_SIZE).
-        out = bytearray()
-        for b in range(start_b, end_b + 1):
-            lo = (b - start_b) * shard_size
-            hi = min((b - start_b + 1) * shard_size, win_len)
-            chunk = b"".join(shards[s][lo:hi].tobytes() for s in range(k))
-            take = min(BLOCK_SIZE, part_size - b * BLOCK_SIZE)
-            out += chunk[:take]
-        # `out` holds object bytes [start_b*BLOCK_SIZE, ...); cut the range.
-        return bytes(out[skip:skip + length]), None
+        with tracing.stage("get.join", type_="kernel", cpu=False):
+            out = bytearray()
+            for b in range(start_b, end_b + 1):
+                lo = (b - start_b) * shard_size
+                hi = min((b - start_b + 1) * shard_size, win_len)
+                chunk = b"".join(shards[s][lo:hi].tobytes()
+                                 for s in range(k))
+                take = min(BLOCK_SIZE, part_size - b * BLOCK_SIZE)
+                out += chunk[:take]
+            # `out` holds object bytes [start_b*BLOCK_SIZE, ...); cut
+            # the range.
+            return bytes(out[skip:skip + length]), None
 
     def _count_get(self, path: str) -> None:
         with self._gk_mu:
@@ -2567,7 +2620,10 @@ class ErasureSet:
                     stacked[:, i, :] = arr[:full * frame].reshape(full,
                                                                   frame)
             try:
-                ok, data = sb.frame(stacked)
+                # the batcher's queue, its stage, the lane and its finish
+                with tracing.stage("get.deframe", type_="kernel",
+                                   cpu=False):
+                    ok, data = sb.frame(stacked)
             except DeadlineExceeded:
                 raise
             except Exception:  # noqa: BLE001 - device trouble != corruption
@@ -2594,26 +2650,27 @@ class ErasureSet:
                 return None, None, bad, route
             take_last = min(BLOCK_SIZE, part_size - end_b * BLOCK_SIZE)
             out_len = (nb - 1) * BLOCK_SIZE + min(take_last, k * slast)
-            lease = global_pool().lease(out_len)
-            try:
-                out = lease.ndarray((out_len,))
-                pos = 0
-                for b in range(full):
-                    take = min(BLOCK_SIZE, out_len - pos)
-                    out[pos:pos + take] = data[b].reshape(-1)[:take]
-                    pos += take
-                if full < nb:
-                    off = full * frame + hsize
-                    take = out_len - pos
-                    tail = np.empty(k * slast, dtype=np.uint8)
-                    for i, arr in enumerate(blobs):
-                        tail[i * slast:(i + 1) * slast] = \
-                            arr[off:off + slast]
-                    out[pos:pos + take] = tail[:take]
-                    pos += take
-            except BaseException:
-                lease.release()
-                raise
+            with tracing.stage("get.interleave", type_="kernel"):
+                lease = global_pool().lease(out_len)
+                try:
+                    out = lease.ndarray((out_len,))
+                    pos = 0
+                    for b in range(full):
+                        take = min(BLOCK_SIZE, out_len - pos)
+                        out[pos:pos + take] = data[b].reshape(-1)[:take]
+                        pos += take
+                    if full < nb:
+                        off = full * frame + hsize
+                        take = out_len - pos
+                        tail = np.empty(k * slast, dtype=np.uint8)
+                        for i, arr in enumerate(blobs):
+                            tail[i * slast:(i + 1) * slast] = \
+                                arr[off:off + slast]
+                        out[pos:pos + take] = tail[:take]
+                        pos += take
+                except BaseException:
+                    lease.release()
+                    raise
             return lease.view(out_len), lease, 0, route
         finally:
             if stack is not None:
@@ -2631,53 +2688,69 @@ class ErasureSet:
                         if shards[i] is None or shards[i].size == 0]
         if not missing_data:
             return
-        if not (m > 0 and self._wants_device_route("reconstruct")):
-            e.decode_data_blocks(shards)
+        plan = self._device_rebuild_plan(k, m, shards, shard_size,
+                                         missing_data)
+        if plan is None:
+            with tracing.stage("get.rebuild", type_="kernel", cpu=False):
+                e.decode_data_blocks(shards)
             return
+        sb, use, shard_len, full = plan
+        with tracing.stage("get.rebuild_stack", type_="kernel", cpu=False):
+            stacked = np.empty((full, k, shard_size), dtype=np.uint8)
+            for j, i in enumerate(use):
+                stacked[:, j, :] = \
+                    shards[i][:full * shard_size].reshape(full, shard_size)
+        with tracing.stage("get.rebuild", type_="kernel", cpu=False):
+            try:
+                out = sb.frame(stacked)      # [full, r, shard_size]
+            except DeadlineExceeded:
+                raise
+            except Exception:  # noqa: BLE001 - device trouble -> host codec
+                if device.required():
+                    raise
+                e.decode_data_blocks(shards)
+                return
+            tail = shard_len - full * shard_size
+            rebuilt = [np.empty(shard_len, dtype=np.uint8)
+                       for _ in missing_data]
+            for r_i in range(len(missing_data)):
+                rebuilt[r_i][:full * shard_size] = \
+                    out[:, r_i, :].reshape(-1)
+            if tail:
+                from minio_tpu.ops import gf256
+                dec = gf256.decode_matrix(k, m, use)
+                tail_in = np.stack([shards[i][full * shard_size:]
+                                    for i in use])
+                tout = np.asarray(e.backend.apply_matrix(
+                    dec[list(missing_data), :], tail_in))
+                for r_i in range(len(missing_data)):
+                    rebuilt[r_i][full * shard_size:] = tout[r_i]
+            for r_i, i in enumerate(missing_data):
+                shards[i] = rebuilt[r_i]
+
+    def _device_rebuild_plan(self, k: int, m: int, shards, shard_size: int,
+                             missing_data: list):
+        """(batcher, survivors used, shard length, full blocks) when the
+        batched device reconstruct takes this window; None when the
+        host codec does — which also owns every edge shape: too few
+        survivors (surfaces ReconstructError), survivors of unequal
+        length (ShardSizeError), no full block or a lone window under
+        the batcher's threshold."""
+        if not (m > 0 and self._wants_device_route("reconstruct")):
+            return None
         present = [i for i, s in enumerate(shards)
                    if s is not None and s.size > 0]
         if len(present) < k:
-            e.decode_data_blocks(shards)     # surfaces ReconstructError
-            return
+            return None
         use = tuple(present[:k])             # same pick as the codec
         shard_len = shards[use[0]].shape[0]
         if any(shards[i].shape[0] != shard_len for i in use):
-            e.decode_data_blocks(shards)     # surfaces ShardSizeError
-            return
+            return None
         full = shard_len // shard_size
         sb = _reconstruct_batcher_for(k, m, use, tuple(missing_data))
         if full < 1 or not sb.worth_batching(full):
-            e.decode_data_blocks(shards)
-            return
-        stacked = np.empty((full, k, shard_size), dtype=np.uint8)
-        for j, i in enumerate(use):
-            stacked[:, j, :] = \
-                shards[i][:full * shard_size].reshape(full, shard_size)
-        try:
-            out = sb.frame(stacked)          # [full, r, shard_size]
-        except DeadlineExceeded:
-            raise
-        except Exception:  # noqa: BLE001 - device trouble -> host codec
-            if device.required():
-                raise
-            e.decode_data_blocks(shards)
-            return
-        tail = shard_len - full * shard_size
-        rebuilt = [np.empty(shard_len, dtype=np.uint8)
-                   for _ in missing_data]
-        for r_i in range(len(missing_data)):
-            rebuilt[r_i][:full * shard_size] = out[:, r_i, :].reshape(-1)
-        if tail:
-            from minio_tpu.ops import gf256
-            dec = gf256.decode_matrix(k, m, use)
-            tail_in = np.stack([shards[i][full * shard_size:]
-                                for i in use])
-            tout = np.asarray(e.backend.apply_matrix(
-                dec[list(missing_data), :], tail_in))
-            for r_i in range(len(missing_data)):
-                rebuilt[r_i][full * shard_size:] = tout[r_i]
-        for r_i, i in enumerate(missing_data):
-            shards[i] = rebuilt[r_i]
+            return None
+        return sb, use, shard_len, full
 
     def _verify_shard_blob(self, blob, shard_size: int, data_size: int):
         """Verified un-framed data of ONE framed shard blob, or None on

@@ -55,7 +55,8 @@ its own does the third the moment the rows are back. The depth is two
 by construction: one batch in the lane, one being staged; before it
 submits, the dispatcher waits for its previous batch's lane call to
 have returned (the counted stage `batcher.lane_wait`: what it still
-waits for the device). So the cycle of a loaded dispatcher is
+waits for the device); the finish is the counted stage
+`batcher.finish`, on every route. So the cycle of a loaded dispatcher is
 max(stage, lane) instead of their sum, results come back in order, and
 at most two staging buffers are alive. A lone window (frame()'s solo
 path) runs the three steps on its own thread, one after the other.
@@ -898,15 +899,21 @@ class StripeBatcher:
 
     def _finish(self, b: _Batch, fut: Optional[Future]) -> None:
         """Wait for the batch's lane call (`fut`; None on the host
-        route), give the staging buffer back, demultiplex the rows to
-        the members, count, and release them."""
+        route), then, as the stage `batcher.finish` (from the rows'
+        return to `done`: what the dispatcher's next submit waits for
+        beyond the lane), give the staging buffer back, demultiplex
+        the rows to the members, count, and release them."""
         live, total, bucket, route = b.live, b.total, b.bucket, b.route
         counts = [p.count for p in live]
-        try:
-            if fut is not None:
-                try:
-                    rows_all = fut.result()
-                finally:
+        rows_all = failed = None
+        if fut is not None:
+            try:
+                rows_all = fut.result()
+            except BaseException as e:  # noqa: BLE001 - delivered below
+                failed = e
+        with tracing.stage("batcher.finish", type_="kernel", cpu=False):
+            try:
+                if fut is not None:
                     # The dispatch is synchronous through the readback
                     # (the framer returns host numpy), so the staging
                     # buffer is done feeding HBM here — and not before.
@@ -920,8 +927,8 @@ class StripeBatcher:
                     lease, b.lease, b.stacked = b.lease, None, None
                     if lease is not None:
                         lease.release()
-                with tracing.stage("batcher.demux", type_="kernel",
-                                   count=False):
+                    if failed is not None:
+                        raise failed
                     if self._split_fn is not None:
                         # Route-specific demux (get: verdict slices + data
                         # views of the member's OWN window; reconstruct:
@@ -949,50 +956,50 @@ class StripeBatcher:
                                                in enumerate(rows[i])]
                             p.rows = rows
                             off += c
-                with self._stat_mu:
-                    self._dispatches["device"] += 1
-                    self._overlapped += b.overlapped
-                    self._requests["device"] += len(live)
-                    self._bucket_dispatches[bucket] = \
-                        self._bucket_dispatches.get(bucket, 0) + 1
-                    self._batched_blocks += total
-                    self._capacity_blocks += bucket
-                self._adapt_window(total / bucket)
-            else:
+                    with self._stat_mu:
+                        self._dispatches["device"] += 1
+                        self._overlapped += b.overlapped
+                        self._requests["device"] += len(live)
+                        self._bucket_dispatches[bucket] = \
+                            self._bucket_dispatches.get(bucket, 0) + 1
+                        self._batched_blocks += total
+                        self._capacity_blocks += bucket
+                    self._adapt_window(total / bucket)
+                else:
+                    for p in live:
+                        p.rows = self._host_fn(p.stacked)
+                    with self._stat_mu:
+                        self._dispatches["host"] += 1
+                        self._requests["host"] += len(live)
+                    # Host-routed dispatches are the sparse case (total
+                    # below min_device_blocks) — adapt here too, or light
+                    # steady traffic pins _cur_wait at whatever a past
+                    # burst stretched it to and every small PUT pays the
+                    # full window forever.
+                    self._adapt_window(total / bucket)
+            except BaseException as e:  # noqa: BLE001 - deliver to waiters
+                if route == "device" and isinstance(e, Exception):
+                    device.record_fault(f"dispatch:{self.route}", e)
                 for p in live:
-                    p.rows = self._host_fn(p.stacked)
-                with self._stat_mu:
-                    self._dispatches["host"] += 1
-                    self._requests["host"] += len(live)
-                # Host-routed dispatches are the sparse case (total
-                # below min_device_blocks) — adapt here too, or light
-                # steady traffic pins _cur_wait at whatever a past
-                # burst stretched it to and every small PUT pays the
-                # full window forever.
-                self._adapt_window(total / bucket)
-        except BaseException as e:  # noqa: BLE001 - deliver to waiters
-            if route == "device" and isinstance(e, Exception):
-                device.record_fault(f"dispatch:{self.route}", e)
-            for p in live:
-                p.exc = e
-        finally:
-            dur_ms = (time.perf_counter() - b.t0) * 1000.0
-            for p in live:
-                p.route_taken = route
-                wait_s = max(0.0, b.t0 - p.t_enq)
-                self._wait_hist.observe(wait_s)
-                if p.tctx is not None:
-                    # ONE kernel span fanned into each member's tree.
-                    tracing.record_into(
-                        p.tctx, p.tparent, "kernel", "batcher.dispatch",
-                        b.t_wall, dur_ms,
-                        tags={"blocks": p.count, "batch_blocks": total,
-                              "bucket": bucket, "members": len(live),
-                              "route": route,
-                              "mesh_devices": self.mesh_devices,
-                              "wait_ms": round(wait_s * 1000.0, 3)})
-                p.event.set()
-            b.done.set()
+                    p.exc = e
+            finally:
+                dur_ms = (time.perf_counter() - b.t0) * 1000.0
+                for p in live:
+                    p.route_taken = route
+                    wait_s = max(0.0, b.t0 - p.t_enq)
+                    self._wait_hist.observe(wait_s)
+                    if p.tctx is not None:
+                        # ONE kernel span fanned into each member's tree.
+                        tracing.record_into(
+                            p.tctx, p.tparent, "kernel", "batcher.dispatch",
+                            b.t_wall, dur_ms,
+                            tags={"blocks": p.count, "batch_blocks": total,
+                                  "bucket": bucket, "members": len(live),
+                                  "route": route,
+                                  "mesh_devices": self.mesh_devices,
+                                  "wait_ms": round(wait_s * 1000.0, 3)})
+                    p.event.set()
+                b.done.set()
 
     def close(self) -> None:
         """Stop accumulating: what is pending dispatches at once, and

@@ -739,8 +739,8 @@ def framed_digests_eligible(n_blocks: int, shard_size: int) -> bool:
 # ---------------------------------------------------------------------------
 
 def _lane_round_trip(upload, step) -> tuple:
-    """One framer dispatch, cut where the host truly waits, into the
-    kernel lane's three stages (utils/tracing.stage): `lane.upload`
+    """One dispatch of the framer, de-framer or GF matrix, cut where the
+    host waits, into the lane's three stages (tracing.stage): `lane.upload`
     (the host's part of moving the arrays onto the device: JAX returns
     once the transfer is under way), `lane.kernel` (the jitted step
     until its outputs are ready, which also waits out what is left of
@@ -957,14 +957,18 @@ def make_mesh_deframer(k: int, mode: str = "auto", devices=None):
         pchunk = _pick_pchunk(s // 32) if s and s % 32 == 0 else 0
         if on_tpu and f % 4 == 0 and s % 1024 == 0 and pchunk >= 8:
             device.note_kernel("deframe", "pallas")
-            ok = step32(upload(framed.view(np.uint32)),
-                        jnp.asarray(_init_smem_np(MAGIC_KEY)),
-                        pchunk=pchunk)
+            ok, = _lane_round_trip(
+                lambda: (upload(framed.view(np.uint32)),
+                         jnp.asarray(_init_smem_np(MAGIC_KEY))),
+                lambda framed32, init: (step32(framed32, init,
+                                               pchunk=pchunk),))
         else:
             device.note_kernel("deframe", "xla")
-            ok = step8(upload(framed),
-                       jnp.asarray(_init_state_np(MAGIC_KEY)))
-        return np.asarray(ok)
+            ok, = _lane_round_trip(
+                lambda: (upload(framed),
+                         jnp.asarray(_init_state_np(MAGIC_KEY))),
+                lambda framed8, init: (step8(framed8, init),))
+        return ok
 
     run.mesh_devices = ndev
     return run

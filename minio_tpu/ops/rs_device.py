@@ -479,9 +479,13 @@ def make_mesh_matrix(matrix: np.ndarray, mode: str = "auto", devices=None):
     step = jit_body(matrix_apply)
 
     def run(stacked) -> np.ndarray:
+        # the framer's round trip and its three lane stages
+        from minio_tpu.ops.hh_device import _lane_round_trip
         stacked = np.ascontiguousarray(stacked, dtype=np.uint8)
         device.note_kernel("matrix", impl)
-        return np.asarray(step(upload(stacked)))
+        out, = _lane_round_trip(lambda: (upload(stacked),),
+                                lambda data: (step(data),))
+        return out
 
     run.mesh_devices = ndev
     return run

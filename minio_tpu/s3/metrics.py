@@ -1099,6 +1099,7 @@ class Metrics:
                    "stat_hits": 0, "stat_misses": 0, "stat_entries": 0,
                    "stat_evictions": 0}
             gk = {"native": 0, "numpy": 0, "demoted": 0, "device": 0}
+            pool_wait, refused = [0.0, 0], 0
             for s in layer_sets(object_layer):
                 cache = getattr(s, "fi_cache", None)
                 if cache is not None:
@@ -1107,6 +1108,10 @@ class Metrics:
                         fic[key] += st[key]
                 for key in gk:
                     gk[key] += getattr(s, "get_kernel", {}).get(key, 0)
+                waited = getattr(s, "get_pool_wait", (0.0, 0))
+                pool_wait[0] += waited[0]
+                pool_wait[1] += waited[1]
+                refused += getattr(s, "get_survivors_refused", 0)
             for name, help_, type_, key in (
                     ("minio_tpu_fileinfo_cache_hits_total",
                      "GET/HEAD metadata served from the fileinfo cache",
@@ -1142,6 +1147,20 @@ class Metrics:
             metric("minio_tpu_get_kernel_windows_total",
                    "GET windows decoded, by path",
                    "counter", [({"path": p}, v) for p, v in gk.items()])
+            # A multi-window GET reads its windows on the set's pool:
+            # how long each waited there for a worker, and how many
+            # fetched shards the rebuild path's verify refused.
+            metric("minio_tpu_get_window_pool_wait_seconds_sum",
+                   "Seconds GET windows waited in their set's read pool "
+                   "between submission and the read's first instruction",
+                   "counter", [({}, round(pool_wait[0], 6))])
+            metric("minio_tpu_get_window_pool_wait_seconds_count",
+                   "GET windows handed to their set's read pool",
+                   "counter", [({}, pool_wait[1])])
+            metric("minio_tpu_get_survivors_refused_total",
+                   "Fetched shards the rebuild path's bitrot verify "
+                   "refused (a missing shard is not counted)",
+                   "counter", [({}, refused)])
 
         # -- hot-object read tier (object/hotcache.py) ------------------
         # Hits are GETs that never touched the object layer (served

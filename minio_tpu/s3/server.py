@@ -3000,11 +3000,12 @@ def _make_handler(server: S3Server):
                             # Copy BEFORE the send: pooled windows are
                             # recycled when the generator advances.
                             hot_buf += chunk
-                        if head is not None:
-                            self._send_bufs([head, chunk], final=last)
-                            head = None
-                        else:
-                            self._send_bufs([chunk], final=last)
+                        with tracing_mod.stage("get.send", cpu=False):
+                            if head is not None:
+                                self._send_bufs([head, chunk], final=last)
+                                head = None
+                            else:
+                                self._send_bufs([chunk], final=last)
                         sent += len(chunk)
                         self._sent_bytes = getattr(
                             self, "_sent_bytes", 0) + len(chunk)

@@ -411,6 +411,150 @@ def test_heal_deep_verify_rides_verify_batcher(forced_decode):
     assert got == body
 
 
+# -- the read path's stages and counters (utils/tracing.stage) -----------
+
+WINDOW_PARTS = ("get.fetch", "get.stack", "get.deframe", "get.interleave",
+                "get.survivor_verify", "get.rebuild_stack", "get.rebuild",
+                "get.join")
+
+
+def _stages_since(before: dict) -> dict:
+    """{stage: (wall s, entries)} the process-wide accumulator gained
+    since `before`."""
+    from minio_tpu.utils import tracing
+    out = {}
+    for name, (wall, _cpu, n) in tracing.stage_totals().items():
+        w0, _c0, n0 = before.get(name, (0.0, 0.0, 0))
+        if n > n0:
+            out[name] = (wall - w0, n - n0)
+    return out
+
+
+def _entries(got: dict, names) -> dict:
+    return {n: got.get(n, (0.0, 0))[1] for n in names}
+
+
+def test_a_multi_window_get_names_each_stage_of_its_read(forced_decode,
+                                                          monkeypatch):
+    """A warm two-window GET through the S3 server on the device route
+    enters get.prepare once, and get.window_wait and get.send (request
+    thread) and get.window, get.fetch, get.stack, get.deframe and
+    get.interleave (the window's thread) once a window. The window's
+    parts hold no more than the window, the request thread's stages no
+    more than the GET's own seconds, and each window's wait for the
+    set's read pool is counted."""
+    from minio_tpu.object import erasure_object as eo
+    from minio_tpu.s3.server import S3Server
+    from minio_tpu.utils import tracing
+    from tests.batcher_rig import until
+    from tests.s3client import S3Client
+    es, _ = forced_decode
+    monkeypatch.setattr(eo, "GET_WINDOW_BYTES", 8 << 20)
+    monkeypatch.setattr(es.mrf, "enqueue", lambda *a, **kw: None)
+    rng = np.random.default_rng(25)
+    body = rng.integers(0, 256, size=16 << 20, dtype=np.uint8).tobytes()
+    es.put_object("b", "mw", body)
+    srv = S3Server(es, address="127.0.0.1:0")
+    srv.start()
+    try:
+        cli = S3Client(srv.address)
+
+        def gets():
+            return srv.metrics.state()["latency_hist"].get(
+                "GET:object", {"count": 0, "sum": 0.0})
+        for i in (1, 2):                  # the first one warms the route
+            if i == 2:
+                before, get0 = tracing.stage_totals(), gets()
+                waited0 = list(es.get_pool_wait)
+            st, _, got = cli.request("GET", "/b/mw")
+            assert st == 200 and got == body
+            # credited when the server has left `_route`
+            until(lambda: gets()["count"] == i, f"GET {i} is recorded")
+        get_s = gets()["sum"] - get0["sum"]
+    finally:
+        srv.stop()
+    got = _stages_since(before)
+    assert _entries(got, ("get.prepare", "get.window_wait", "get.send",
+                          "get.window") + WINDOW_PARTS) == {
+        "get.prepare": 1, "get.window_wait": 2, "get.send": 2,
+        "get.window": 2, "get.fetch": 2, "get.stack": 2, "get.deframe": 2,
+        "get.interleave": 2, "get.survivor_verify": 0,
+        "get.rebuild_stack": 0, "get.rebuild": 0, "get.join": 0}
+    parts = sum(got[n][0] for n in WINDOW_PARTS if n in got)
+    assert 0 < parts <= got["get.window"][0]
+    request = sum(got[n][0] for n in ("s3.auth", "get.prepare",
+                                      "get.window_wait", "get.send"))
+    assert 0 < request <= get_s + 1e-5   # the sum is rounded to 1 us
+    assert es.get_pool_wait[1] - waited0[1] == 2
+    assert es.get_pool_wait[0] >= waited0[0]
+
+
+def test_a_degraded_get_names_the_rebuild_path(forced_decode, monkeypatch):
+    """A GET with one data shard's drive gone verifies the fetched
+    shards on the host (get.survivor_verify: the data shards, then
+    parity), fetches twice (get.fetch), stacks the survivors and
+    rebuilds on the device route (get.rebuild_stack, get.rebuild) and
+    joins the answer (get.join) — and never enters the healthy
+    window's stack, de-framer or interleave."""
+    from minio_tpu.object.erasure_object import hash_order
+    from minio_tpu.utils import tracing
+    es, root = forced_decode
+    monkeypatch.setattr(es.mrf, "enqueue", lambda *a, **kw: None)
+    rng = np.random.default_rng(26)
+    body = rng.integers(0, 256, size=9 << 20, dtype=np.uint8).tobytes()
+    es.put_object("b", "dg", body)
+    es.fi_cache.enabled = False
+    dist = hash_order("b/dg", 12)
+    shutil.rmtree(str(root / f"d{dist.index(1)}" / "b" / "dg"))
+    es.metacache.bump("b")
+    assert es.get_object("b", "dg")[1] == body   # warm: the pattern's
+    before = tracing.stage_totals()              # program compiles
+    assert es.get_object("b", "dg")[1] == body
+    got = _stages_since(before)
+    assert _entries(got, ("get.prepare", "get.window") + WINDOW_PARTS) \
+        == {"get.prepare": 1, "get.window": 1, "get.fetch": 2,
+            "get.stack": 0, "get.deframe": 0, "get.interleave": 0,
+            "get.survivor_verify": 2, "get.rebuild_stack": 1,
+            "get.rebuild": 1, "get.join": 1}
+    parts = sum(got[n][0] for n in WINDOW_PARTS if n in got)
+    assert 0 < parts <= got["get.window"][0]
+
+
+def test_a_refused_survivor_is_counted_and_a_dead_drives_shard_is_not(
+        forced_decode, monkeypatch):
+    """On the rebuild path, a fetched shard that the verify refuses for
+    bitrot counts once in minio_tpu_get_survivors_refused_total; the
+    shard of a drive that is gone was never fetched and counts 0. Both
+    new series render in /metrics."""
+    from minio_tpu.object.erasure_object import hash_order
+    from minio_tpu.s3.metrics import Metrics
+    es, root = forced_decode
+    monkeypatch.setattr(es.mrf, "enqueue", lambda *a, **kw: None)
+    rng = np.random.default_rng(27)
+    body = rng.integers(0, 256, size=9 << 20, dtype=np.uint8).tobytes()
+    es.put_object("b", "rs", body)
+    es.fi_cache.enabled = False
+    dist = hash_order("b/rs", 12)
+    shutil.rmtree(str(root / f"d{dist.index(1)}" / "b" / "rs"))
+    es.metacache.bump("b")
+    assert es.get_object("b", "rs")[1] == body
+    assert es.get_survivors_refused == 0          # gone, not refused
+    import glob
+    files = glob.glob(str(root / f"d{dist.index(2)}" / "b" / "rs" / "*"
+                          / "part.1"))
+    assert files
+    with open(files[0], "r+b") as f:              # data shard 1 rots
+        f.seek(2000)
+        f.write(b"\x5a\xa5\x5a\xa5")
+    assert es.get_object("b", "rs")[1] == body
+    assert es.get_survivors_refused == 1
+    text = Metrics().render(object_layer=es)
+    assert "\nminio_tpu_get_survivors_refused_total 1\n" in text
+    for name in ("minio_tpu_get_window_pool_wait_seconds_sum",
+                 "minio_tpu_get_window_pool_wait_seconds_count"):
+        assert f"\n{name} " in text, name
+
+
 _MESH_BODY = r"""
 import numpy as np
 import jax

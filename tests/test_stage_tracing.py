@@ -588,6 +588,77 @@ def test_worker_states_sum_into_the_fleet_drive_series():
 
 # -- the real profiler -----------------------------------------------------------
 
+# -- the read side's dispatches (ops/hh_device, ops/rs_device, batcher) ------
+
+LANE = ("lane.upload", "lane.kernel", "lane.readback")
+
+
+def test_decode_dispatches_enter_the_lanes_three_stages():
+    """The de-framer's and the GF matrix's run() go through the framer's
+    round trip: each dispatch enters lane.upload, lane.kernel and
+    lane.readback once (and not lane.rows, the framer's own), with the
+    verdicts and the rebuilt rows what the host computes."""
+    import jax
+
+    from minio_tpu.object.erasure_object import (_host_apply_rows,
+                                                 _host_deframe)
+    from minio_tpu.ops import gf256
+    from minio_tpu.ops.hh_device import make_deframer
+    from minio_tpu.ops.rs_device import make_mesh_matrix
+    from tests import batcher_rig as rig
+    framed = rig.window(8, 31, shard=rig.SHARD + 32, route="get")
+    rows = np.ascontiguousarray(
+        gf256.decode_matrix(rig.K, rig.M, rig.USE)[list(rig.LOST), :])
+    stacked = rig.window(8, 32)
+    for run, arg, want in (
+            (make_deframer(rig.K), framed, _host_deframe(framed)[0]),
+            (make_mesh_matrix(rows, devices=jax.devices()[:1]), stacked,
+             _host_apply_rows(rows, stacked))):
+        before = tracing.stage_totals()
+        assert np.array_equal(run(arg), want)
+        got = totals_since(before)
+        assert {n: got[n][2] for n in LANE} == dict.fromkeys(LANE, 1)
+        assert "lane.rows" not in got
+
+
+@pytest.mark.parametrize("route", ("put", "get", "reconstruct"))
+@pytest.mark.parametrize("path", ("pipelined", "lane", "host"))
+def test_every_dispatch_enters_batcher_finish_once(route, path, annotator):
+    """Each dispatch's finish — from the rows' return to the members'
+    release — is the counted stage batcher.finish, once, on every route
+    and every path: the dispatcher's (its finisher thread), a lone
+    window's through the lane, and the host route's. batcher.demux, the
+    uncounted name it replaced, is entered nowhere."""
+    from minio_tpu.ops.batcher import StripeBatcher, _Pending
+    from tests import batcher_rig as rig
+
+    def finishes():
+        return tracing.stage_totals().get("batcher.finish",
+                                          [0.0, 0.0, 0])[2]
+    n0 = finishes()
+    if path == "pipelined":
+        with rig.Rig(route) as r:
+            members = r.send(40)          # two members, one batch
+            for m in members:
+                assert m.returned().exc is None
+            until(lambda: finishes() == n0 + 1, "the finish is counted")
+    else:
+        r = rig.Rig(route)
+        r.end()
+        sb = StripeBatcher(r.fn, r.fn, probe_fn=lambda: True,
+                           min_device_blocks=8, route=r.sb.route,
+                           split_fn=r.sb._split_fn)
+        sb.force(path == "lane")
+        pend = [_Pending(rig.window(8, 41, route=route), None)]
+        sb._run_batch(pend)
+        assert pend[0].exc is None and pend[0].route_taken == (
+            "device" if path == "lane" else "host")
+        assert finishes() == n0 + 1
+    entered = {e[1] for e in annotator.events}
+    assert "batcher.finish" in entered and "batcher.demux" not in entered
+    assert "batcher.demux" not in tracing.stage_totals()
+
+
 def test_program_stages_are_in_a_jax_profiler_trace(tmp_path, monkeypatch,
                                                     small_windows):
     """With jax.profiler.start_trace on the CPU backend and the
@@ -635,7 +706,9 @@ def test_program_stages_are_in_a_jax_profiler_trace(tmp_path, monkeypatch,
                                 "lane.readback", "lane.rows")]
     assert lane == [2, 2, 2, 2]          # one of each per device window
     assert got["batcher.stage"][2] == lane[0]
-    assert "batcher.demux" not in got    # on the trace's clock, uncounted
+    # the finish is counted, once a dispatch (it holds the demux)
+    assert got["batcher.finish"][2] == lane[0]
+    assert "batcher.demux" not in got
     files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     assert files
@@ -646,7 +719,7 @@ def test_program_stages_are_in_a_jax_profiler_trace(tmp_path, monkeypatch,
             for line in plane.lines:
                 names.update(e.name for e in line.events)
     for want in ("put.body_read", "put.frame", "put.shard_enqueue",
-                 "put.commit", "batcher.stage", "batcher.demux",
+                 "put.commit", "batcher.stage", "batcher.finish",
                  "lane.upload",
                  "lane.kernel", "lane.readback", "lane.rows",
                  "disk.create_file"):
