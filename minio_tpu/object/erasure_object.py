@@ -251,6 +251,46 @@ def _get_split(ok, off, c, member):
     return ok[off:off + c], member[:, :, 32:]
 
 
+def _interleave_window(out, data, out_len: int,
+                       block_size: int) -> tuple[int, int]:
+    """Write a verified window's payload `data` ([full, k, shard], any
+    strides: `_get_split`'s view past each frame's digest) block-major
+    into `out`: the first `block_size` bytes of each block's k x shard,
+    up to `out_len`. The blocks `out` holds whole go in one strided
+    assignment (two where k x shard overshoots the block: the last
+    shard column is cut), so the copy hands the GIL back once or twice a
+    window, not twice a block with a temporary between; a block that
+    `out_len` cuts is copied alone. Returns (blocks placed in bulk,
+    blocks copied alone)."""
+    full, k, shard = data.shape
+    n = min(full, out_len // block_size)
+    if n:
+        rows = out[:n * block_size].reshape(n, block_size)
+        if k * shard == block_size:
+            rows.reshape(n, k, shard)[...] = data[:n]
+        else:
+            head = (k - 1) * shard
+            rows[:, :head].reshape(n, k - 1, shard)[...] = data[:n, :k - 1]
+            rows[:, head:] = data[:n, k - 1, :block_size - head]
+    pos = n * block_size
+    if n == full or pos >= out_len:
+        return n, 0
+    _copy_block(out[pos:out_len], data[n])
+    return n, 1
+
+
+def _copy_block(dst, cols) -> None:
+    """Copy one block's shard columns in order into `dst`, stopping at
+    its end: column by column, no temporary."""
+    pos = 0
+    for col in cols:
+        take = min(len(col), len(dst) - pos)
+        if take <= 0:
+            return
+        dst[pos:pos + take] = col[:take]
+        pos += take
+
+
 def _get_concat(a, b):
     return (np.concatenate([a[0], b[0]]),
             np.concatenate([a[1], b[1]]))
@@ -422,10 +462,13 @@ class ErasureSet:
                            "device": 0}
         # Beside them, under the same lock: what a multi-window GET's
         # windows waited in `self.pool`'s queue (submit to the read's
-        # first instruction: [seconds, windows]), and the fetched
-        # shards the rebuild path's verify refused for bitrot.
+        # first instruction: [seconds, windows]), the fetched shards
+        # the rebuild path's verify refused for bitrot, and the device
+        # windows' blocks interleaved into answers: by the strided
+        # bulk copy, or alone (a cut last block, a ragged tail).
         self.get_pool_wait = [0.0, 0]
         self.get_survivors_refused = 0
+        self.get_interleave_blocks = {"bulk": 0, "block": 0}
         self._gk_mu = threading.Lock()
 
     def close(self) -> None:
@@ -2654,23 +2697,19 @@ class ErasureSet:
                 lease = global_pool().lease(out_len)
                 try:
                     out = lease.ndarray((out_len,))
-                    pos = 0
-                    for b in range(full):
-                        take = min(BLOCK_SIZE, out_len - pos)
-                        out[pos:pos + take] = data[b].reshape(-1)[:take]
-                        pos += take
+                    bulk, alone = _interleave_window(out, data, out_len,
+                                                     BLOCK_SIZE)
                     if full < nb:
                         off = full * frame + hsize
-                        take = out_len - pos
-                        tail = np.empty(k * slast, dtype=np.uint8)
-                        for i, arr in enumerate(blobs):
-                            tail[i * slast:(i + 1) * slast] = \
-                                arr[off:off + slast]
-                        out[pos:pos + take] = tail[:take]
-                        pos += take
+                        _copy_block(out[full * BLOCK_SIZE:],
+                                    [arr[off:off + slast] for arr in blobs])
+                        alone += 1
                 except BaseException:
                     lease.release()
                     raise
+            with self._gk_mu:
+                self.get_interleave_blocks["bulk"] += bulk
+                self.get_interleave_blocks["block"] += alone
             return lease.view(out_len), lease, 0, route
         finally:
             if stack is not None:

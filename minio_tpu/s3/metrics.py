@@ -1100,6 +1100,7 @@ class Metrics:
                    "stat_evictions": 0}
             gk = {"native": 0, "numpy": 0, "demoted": 0, "device": 0}
             pool_wait, refused = [0.0, 0], 0
+            interleaved = {"bulk": 0, "block": 0}
             for s in layer_sets(object_layer):
                 cache = getattr(s, "fi_cache", None)
                 if cache is not None:
@@ -1112,6 +1113,9 @@ class Metrics:
                 pool_wait[0] += waited[0]
                 pool_wait[1] += waited[1]
                 refused += getattr(s, "get_survivors_refused", 0)
+                for key in interleaved:
+                    interleaved[key] += getattr(
+                        s, "get_interleave_blocks", {}).get(key, 0)
             for name, help_, type_, key in (
                     ("minio_tpu_fileinfo_cache_hits_total",
                      "GET/HEAD metadata served from the fileinfo cache",
@@ -1148,8 +1152,9 @@ class Metrics:
                    "GET windows decoded, by path",
                    "counter", [({"path": p}, v) for p, v in gk.items()])
             # A multi-window GET reads its windows on the set's pool:
-            # how long each waited there for a worker, and how many
-            # fetched shards the rebuild path's verify refused.
+            # how long each waited there for a worker, how many fetched
+            # shards the rebuild path's verify refused, and how the
+            # verified device windows' blocks reached the answer.
             metric("minio_tpu_get_window_pool_wait_seconds_sum",
                    "Seconds GET windows waited in their set's read pool "
                    "between submission and the read's first instruction",
@@ -1157,6 +1162,12 @@ class Metrics:
             metric("minio_tpu_get_window_pool_wait_seconds_count",
                    "GET windows handed to their set's read pool",
                    "counter", [({}, pool_wait[1])])
+            metric("minio_tpu_get_interleave_blocks_total",
+                   "Blocks of verified device GET windows interleaved "
+                   "into answers: by one strided copy a window (bulk) "
+                   "or alone (block: a cut last block, a ragged tail)",
+                   "counter",
+                   [({"mode": m}, v) for m, v in interleaved.items()])
             metric("minio_tpu_get_survivors_refused_total",
                    "Fetched shards the rebuild path's bitrot verify "
                    "refused (a missing shard is not counted)",

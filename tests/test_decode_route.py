@@ -360,21 +360,32 @@ def test_get_window_stacks_are_pool_leases(forced_decode, monkeypatch,
         else:
             assert get() == body
 
-    def stacks():
-        return tracing.stage_totals().get("get.stack", [0.0, 0.0, 0])[2]
+    def entries(stage):
+        return tracing.stage_totals().get(stage, [0.0, 0.0, 0])[2]
 
+    def stacks():
+        return entries("get.stack")
+
+    # The windows the device verified are interleaved, 8 whole blocks
+    # each in one strided copy; a demoted or failed one never is.
+    interleaves_a_get = {"verified": 2, "bitrot": 1}.get(path, 0)
     before = pool.stats()
     read()                       # warm: the pool's first leases miss
     gc.collect()
     warm = pool.stats()
     assert warm["outstanding"] == before["outstanding"]
     n0, kernel0 = stacks(), dict(es.get_kernel)
+    i0, blocks0 = entries("get.interleave"), dict(es.get_interleave_blocks)
     del leased[:]
     for _ in range(3):
         read()
     gc.collect()
     after = pool.stats()
     assert stacks() - n0 == 3 * stacks_a_get
+    assert entries("get.interleave") - i0 == 3 * interleaves_a_get
+    assert es.get_interleave_blocks == {
+        "bulk": blocks0["bulk"] + 3 * 8 * interleaves_a_get,
+        "block": blocks0["block"]}
     assert leased.count(stack_bytes) == 3 * stacks_a_get
     assert after["hits"] - warm["hits"] >= 3 * stacks_a_get
     assert after["misses"] == warm["misses"]
@@ -382,6 +393,134 @@ def test_get_window_stacks_are_pool_leases(forced_decode, monkeypatch,
     assert after["leaks"] == before["leaks"]
     if counted is not None:
         assert es.get_kernel[counted] - kernel0[counted] >= 3
+
+
+@pytest.mark.parametrize("window_mib, body_len, bulk, alone", [
+    (8, 16 << 20, 16, 0),                # two windows of whole blocks
+    (8, (16 << 20) - 3, 15, 1),          # the last block cut at out_len
+    (9, (18 << 20) - (200 << 10), 17, 1),  # a ragged tail frame
+], ids=["whole_blocks", "cut_last_block", "ragged_tail"])
+def test_a_multi_window_get_is_interleaved_in_bulk(forced_decode,
+                                                   monkeypatch, window_mib,
+                                                   body_len, bulk, alone):
+    """Each verified window of a two-window device GET is interleaved
+    once (get.interleave), its whole blocks by the strided bulk copy and
+    a cut last block or a ragged tail frame alone, as
+    minio_tpu_get_interleave_blocks_total{mode} counts; the answer is
+    byte-identical and every lease goes back to the pool."""
+    import gc
+    from minio_tpu.io.bufpool import global_pool
+    from minio_tpu.object import erasure_object as eo
+    from minio_tpu.s3.metrics import Metrics
+    from minio_tpu.utils import tracing
+    es, _ = forced_decode
+    monkeypatch.setattr(eo, "GET_WINDOW_BYTES", window_mib << 20)
+    rng = np.random.default_rng(28)
+    body = rng.integers(0, 256, size=body_len, dtype=np.uint8).tobytes()
+    es.put_object("b", "iw", body)
+
+    def get():
+        _, chunks = es.get_object_stream("b", "iw")
+        return b"".join(bytes(c) for c in chunks)
+
+    assert get() == body                  # warm: the programs compile
+    gc.collect()
+    outstanding = global_pool().stats()["outstanding"]
+    before, blocks0 = tracing.stage_totals(), dict(es.get_interleave_blocks)
+    device0 = es.get_kernel["device"]
+    assert get() == body
+    gc.collect()
+    assert _entries(_stages_since(before), ("get.interleave",)) == {
+        "get.interleave": 2}
+    assert es.get_kernel["device"] - device0 == 2
+    assert es.get_interleave_blocks == {"bulk": blocks0["bulk"] + bulk,
+                                        "block": blocks0["block"] + alone}
+    assert global_pool().stats()["outstanding"] == outstanding
+    text = Metrics().render(object_layer=es)
+    for mode, n in es.get_interleave_blocks.items():
+        assert (f'\nminio_tpu_get_interleave_blocks_total{{mode="{mode}"}} '
+                f"{n}\n") in text
+
+
+def test_concurrent_gets_lose_no_interleave_count(forced_decode,
+                                                  monkeypatch):
+    """Twelve threads each GET a two-window object at once, with the
+    interpreter switching threads every 10 us: every answer is
+    byte-identical and minio_tpu_get_interleave_blocks_total{mode="bulk"}
+    rises by exactly 8 blocks a window — no count is lost to a race
+    between the windows' threads."""
+    import sys
+    from minio_tpu.object import erasure_object as eo
+    es, _ = forced_decode
+    monkeypatch.setattr(eo, "GET_WINDOW_BYTES", 8 << 20)
+    rng = np.random.default_rng(29)
+    body = rng.integers(0, 256, size=16 << 20, dtype=np.uint8).tobytes()
+    es.put_object("b", "cc", body)
+    es.fi_cache.enabled = False
+
+    def get():
+        _, chunks = es.get_object_stream("b", "cc")
+        return b"".join(bytes(c) for c in chunks)
+
+    assert get() == body                  # warm: the programs compile
+    blocks0 = dict(es.get_interleave_blocks)
+    answers = [None] * 12
+    threads = [threading.Thread(target=lambda i=i: answers.__setitem__(
+        i, get())) for i in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert all(a == body for a in answers)
+    assert es.get_interleave_blocks == {
+        "bulk": blocks0["bulk"] + 12 * 2 * 8, "block": blocks0["block"]}
+
+
+def _interleave_by_block(data, out_len, block_size):
+    """The per-block reassembly the window used before the strided copy:
+    a temporary of each block's k x shard, cut to the block."""
+    out = np.zeros(out_len, dtype=np.uint8)
+    pos = 0
+    for b in range(data.shape[0]):
+        take = min(block_size, out_len - pos)
+        out[pos:pos + take] = data[b].reshape(-1)[:take]
+        pos += take
+    return out
+
+
+@pytest.mark.parametrize("k, shard", [(8, 131072), (4, 262144),
+                                      (12, 87382), (6, 174763)])
+@pytest.mark.parametrize("end", ["all_blocks", "inside_last",
+                                 "one_byte_into_last"])
+def test_interleave_window_is_the_per_block_copy(k, shard, end):
+    """_interleave_window over a de-framer member's payload view (the
+    32-byte digest before each shard) writes exactly what the per-block
+    loop wrote, for the geometries whose k x shard is the block (one
+    strided copy) and those whose k x shard overshoots it (two: the
+    last column cut), with out_len ending at the last block's end,
+    inside it, and one byte into it; nothing past out_len is touched,
+    and it says how many blocks went in bulk and how many alone."""
+    from minio_tpu.object.erasure_object import (BLOCK_SIZE,
+                                                 _interleave_window)
+    full = 3
+    member = np.random.default_rng(k).integers(
+        0, 256, size=(full, k, 32 + shard), dtype=np.uint8)
+    data = _get_split(np.ones((full, k), bool), 0, full, member)[1]
+    out_len = {"all_blocks": full * BLOCK_SIZE,
+               "inside_last": full * BLOCK_SIZE - 3,
+               "one_byte_into_last": (full - 1) * BLOCK_SIZE + 1}[end]
+    out = np.full(out_len + 64, 0xA5, dtype=np.uint8)
+    counts = _interleave_window(out, data, out_len, BLOCK_SIZE)
+    assert np.array_equal(out[:out_len],
+                          _interleave_by_block(data, out_len, BLOCK_SIZE))
+    assert (out[out_len:] == 0xA5).all()
+    assert counts == ((full, 0) if end == "all_blocks" else (full - 1, 1))
 
 
 def test_heal_deep_verify_rides_verify_batcher(forced_decode):
